@@ -54,14 +54,11 @@ from repro.core import robust as robust_lib
 from repro.kernels import dispatch as kdispatch
 from repro.kernels.gram import gram, gram_batched
 from repro.kernels.mixtrim import mixtrim
+from repro.kernels.target import resolve_interpret as _interp
 
 #: Rules the backend comparison sweeps (mda excluded from pallas timing
 #: rows only because its subset enumeration dwarfs the kernel cost).
 BACKEND_RULES = ("cwtm", "cwmed", "krum", "multikrum", "gm", "average")
-
-
-def _interp() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def structural_summary(n: int = 16, d: int = 8192) -> dict:
@@ -114,8 +111,6 @@ def dist_summary(n: int = 16, d: int = 8192) -> dict:
     ``backend="pallas_sharded"`` and records the sharded run's fallbacks
     and xla parity — the CI gate input for ``BENCH_dist_agg.json``.
     """
-    from repro.launch.mesh import use_mesh
-
     devices = jax.devices()
     if len(devices) < 2:
         raise SystemExit(
@@ -134,7 +129,7 @@ def dist_summary(n: int = 16, d: int = 8192) -> dict:
     per_dc = {}
     for k in counts:
         mesh = jax.sharding.Mesh(np.asarray(devices[:k]), ("shard",))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             wide_s = kdispatch.count_wide_ops(
                 lambda t: robust_lib.robust_aggregate(t, spec_s), tree,
                 n=n, width=d)

@@ -20,12 +20,13 @@ Two execution strategies (DESIGN.md §3):
 Execution is backend-routed (``AggregatorSpec.backend`` through
 :mod:`repro.kernels.dispatch`): the "xla" backend emits the leaf-streamed
 jnp forms below (what the GSPMD distributed path lowers); the "pallas"
-backend flattens the worker stack into ONE contiguous (n, D) buffer and
-runs the blocked ``gram``, streamed ``combine`` and fused ``mixtrim``
-kernels, so the NNM-mixed stack ``Y = M @ X`` never materializes in HBM;
-"pallas_sharded" is the same pipeline shard_map'd along D over a mesh
-axis (per-shard gram + psum'd (n, n) partials, replicated coefficients,
-shard-local combine/mixtrim — :mod:`repro.kernels.shard`).  "auto" =
+backend runs the blocked ``gram``, streamed ``combine`` and fused
+``mixtrim`` kernels over each leaf's (n, d_i) view in place, so neither
+a concatenated copy of the stack nor the NNM-mixed stack ``Y = M @ X``
+materializes in HBM; "pallas_sharded" runs them over ONE contiguous
+(n, D) buffer shard_map'd along D over a mesh axis (per-shard gram +
+psum'd (n, n) partials, replicated coefficients, shard-local
+combine/mixtrim — :mod:`repro.kernels.shard`).  "auto" =
 pallas on a single-device TPU, pallas_sharded on multi-device TPU, xla
 elsewhere; see docs/perf.md.
 
@@ -219,27 +220,29 @@ def _hier_bucket_size(spec: AggregatorSpec, n: int, f, *, dyn: bool) -> int:
 _HIER_S1_NOTE = "s=1: singleton buckets, identity reduction (skipped)"
 
 
-def _hier_reduce_flat(flat: Array, spec: AggregatorSpec, f, *,
-                      key: Optional[Array], dyn: bool, backend: str,
-                      mesh, worker_axis: Optional[str], axis: Optional[str]
-                      ) -> tuple[Array, Any, Optional[Array]]:
-    """The hierarchical pre-reduction on the flattened (n, D) stack.
+def _hier_reduce(segs: list, spec: AggregatorSpec, f, *,
+                 key: Optional[Array], dyn: bool, backend: str,
+                 mesh, worker_axis: Optional[str], axis: Optional[str]
+                 ) -> tuple[list, Any, Optional[Array]]:
+    """The hierarchical pre-reduction of the (n, d) stack segments.
 
-    Returns (reduced stack (ceil(n/s), D), adjusted f, reduced fp32 Gram
+    Returns ([reduced stack (ceil(n/s), D)], adjusted f, reduced fp32 Gram
     or None).  The permutation rides inside the (n_b, n) assignment matrix
     built from ``key`` in-graph, so the compiled kernel is key-independent
     (one compile per fleet shape bucket).  s=1 short-circuits to the
     identity — singleton buckets make the permutation semantically inert,
-    and skipping it keeps hier(s=1) BITWISE equal to the dense pipeline.
+    and skipping it (segments untouched) keeps hier(s=1) BITWISE equal to
+    the dense pipeline.
     """
-    n = flat.shape[0]
+    n = segs[0].shape[0]
     if key is None:
         raise ValueError("hierarchical aggregation requires a PRNG key")
     s = _hier_bucket_size(spec, n, f, dyn=dyn)
     if s == 1:
         kdispatch.record_decision("bucketgram", backend, "skipped",
                                   _HIER_S1_NOTE)
-        return flat, f, None
+        return segs, f, None
+    flat = segs[0] if len(segs) == 1 else jnp.concatenate(segs, axis=1)
     n_b = _num_buckets(n, s)
     bmat = _bucket_matrix(key, n, s, dtype=jnp.float32)
     need_gram = spec.rule in GRAM_RULES or spec.pre == "nnm"
@@ -247,7 +250,7 @@ def _hier_reduce_flat(flat: Array, spec: AggregatorSpec, f, *,
         flat, bmat, backend=backend, with_gram=need_gram, mesh=mesh,
         worker_axis=worker_axis, axis=axis)
     f_adj = _adjusted_f_dyn(f, n_b) if dyn else _adjusted_f(f, n_b)
-    return y, f_adj, g
+    return [y], f_adj, g
 
 
 def _aggregate_flat(work: PyTree, spec: AggregatorSpec, f, *,
@@ -256,9 +259,12 @@ def _aggregate_flat(work: PyTree, spec: AggregatorSpec, f, *,
                     mesh_ctx: Optional[tuple] = None,
                     internals: Optional[dict] = None,
                     hier: bool = False) -> PyTree:
-    """Kernel-backend pipeline: pre-aggregated stack -> one contiguous
-    (n, D) buffer -> blocked gram -> coeff -> streamed combine / fused
-    mixtrim -> aggregated pytree.
+    """Kernel-backend pipeline: pre-aggregated stack -> (n, d) segments ->
+    blocked gram -> coeff -> streamed combine / fused mixtrim ->
+    aggregated pytree.  The mesh backends and the hierarchical stage
+    stream ONE contiguous (n, D) buffer; single-device "pallas" streams
+    each leaf's (n, d_i) view in place and sums the per-leaf Grams, so
+    the stack is never copied (only its bucketed reduction concatenates).
 
     ``backend`` is "pallas" (single device), "pallas_sharded" (the
     shard_map'd form; ``mesh_ctx`` is its resolved (mesh, axis) — the
@@ -271,7 +277,11 @@ def _aggregate_flat(work: PyTree, spec: AggregatorSpec, f, *,
     when ``dyn=True`` (the fleet path; rank-mask kernels keep one compile
     per shape bucket).  Decisions land on ``kdispatch.last_dispatch()``.
     """
-    flat, layout = kdispatch.flatten_worker_stack(work)
+    if backend == "pallas":
+        segs, layout = kdispatch.split_worker_stack(work)
+    else:
+        flat, layout = kdispatch.flatten_worker_stack(work)
+        segs = [flat]
     if backend == "pallas_hier":
         mesh, worker_axis, axis = mesh_ctx
     else:
@@ -283,8 +293,8 @@ def _aggregate_flat(work: PyTree, spec: AggregatorSpec, f, *,
         # The fused reduction emits the reduced stack AND (when a gram
         # consumer follows) its Gram in the same pass — the gram stage
         # below is skipped.
-        flat, f, g = _hier_reduce_flat(
-            flat, spec, f, key=key, dyn=dyn, backend=backend, mesh=mesh,
+        segs, f, g = _hier_reduce(
+            segs, spec, f, key=key, dyn=dyn, backend=backend, mesh=mesh,
             worker_axis=worker_axis, axis=axis)
 
     mix_matrix = None
@@ -298,8 +308,9 @@ def _aggregate_flat(work: PyTree, spec: AggregatorSpec, f, *,
                 "sketch_dim gram runs the leaf-streamed signed sketch")
             g = tree_sketch_gram(work, spec.sketch_dim, key)
         else:
-            g = kdispatch.dispatch_gram(flat, backend=backend,
-                                        mesh=mesh, axis=axis)
+            g = sum(kdispatch.dispatch_gram(x, backend=backend,
+                                            mesh=mesh, axis=axis)
+                    for x in segs)
 
     if spec.pre == "nnm":
         d2 = gramlib.pdist_sq_from_gram(g)
@@ -330,31 +341,35 @@ def _aggregate_flat(work: PyTree, spec: AggregatorSpec, f, *,
                 autogm_lamb=spec.autogm_lamb, autogm_iters=spec.autogm_iters)
         if mix_matrix is not None:
             coeff = coeff @ mix_matrix   # R = c^T (M X) = (c^T M) X
-        vec = kdispatch.dispatch_combine(flat, coeff, backend=backend,
-                                         mesh=mesh, axis=axis)
-        out = kdispatch.unflatten_aggregate(vec, layout)
+        vecs = [kdispatch.dispatch_combine(x, coeff, backend=backend,
+                                           mesh=mesh, axis=axis)
+                for x in segs]
+        out = kdispatch.unflatten_aggregate(vecs, layout)
         return (out, coeff) if return_coeff else out
 
     if spec.rule in COORDINATE_RULES:
+        # No NNM -> m=None: the kernel elides the mix dot instead of
+        # paying an identity matmul per tile.  With NNM, M is cast to the
+        # segment dtype first — the same rounding tree_mix applies — so
+        # bf16-transport runs agree across backends.
+        def mix_for(x):
+            return None if mix_matrix is None else mix_matrix.astype(x.dtype)
+
         if spec.rule == "meamed":
             # No fused kernel: mix (if any) + mean-around-median in jnp —
-            # shard-local under the sharded backend, on the full flat
-            # buffer otherwise.  Recorded so kernel-path callers see it.
-            m = None if mix_matrix is None \
-                else mix_matrix.astype(flat.dtype)
-            vec = kdispatch.dispatch_meamed(flat, m, f, backend=backend,
-                                            dyn=dyn, mesh=mesh, axis=axis)
+            # shard-local under the sharded backend, per segment
+            # otherwise.  Recorded so kernel-path callers see it.
+            vecs = [kdispatch.dispatch_meamed(x, mix_for(x), f,
+                                              backend=backend, dyn=dyn,
+                                              mesh=mesh, axis=axis)
+                    for x in segs]
         else:
             mode = "med" if spec.rule == "cwmed" else "trim"
-            # No NNM -> m=None: the kernel elides the mix dot instead of
-            # paying an identity matmul per tile.  With NNM, M is cast to
-            # the stack dtype first — the same rounding tree_mix applies —
-            # so bf16-transport runs agree across backends.
-            m = None if mix_matrix is None else mix_matrix.astype(flat.dtype)
-            vec = kdispatch.dispatch_mixtrim(flat, m, f, mode=mode,
-                                             backend=backend, dyn=dyn,
-                                             mesh=mesh, axis=axis)
-        out = kdispatch.unflatten_aggregate(vec, layout)
+            vecs = [kdispatch.dispatch_mixtrim(x, mix_for(x), f, mode=mode,
+                                               backend=backend, dyn=dyn,
+                                               mesh=mesh, axis=axis)
+                    for x in segs]
+        out = kdispatch.unflatten_aggregate(vecs, layout)
         return (out, None) if return_coeff else out
 
     raise ValueError(f"unknown rule {spec.rule!r}")

@@ -287,7 +287,7 @@ def _mesh_sig() -> tuple:
     mesh = current_mesh()
     if mesh is not None:
         return (jax.device_count(), tuple(mesh.axis_names),
-                tuple(mesh.devices.shape))
+                tuple(mesh.shape.values()))
     return (jax.device_count(),)
 
 

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 
 from repro.kernels.bucketgram.kernel import bucketgram_pallas
 from repro.kernels.bucketgram.ref import bucket_means_gram_ref
+from repro.kernels.target import resolve_interpret
 
 
 def _ceil_to(v: int, m: int) -> int:
@@ -48,8 +49,7 @@ def bucket_means_gram(x: jax.Array, bmat: jax.Array, *,
     """
     if not use_pallas:
         return bucket_means_gram_ref(x, bmat, with_gram=with_gram)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     n, d = x.shape
     n_b = bmat.shape[0]
     bn = block_n if block_n is not None else pick_block_n(n)

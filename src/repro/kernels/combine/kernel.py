@@ -36,18 +36,17 @@ def combine_pallas(x: jax.Array, coeff: jax.Array, *, block_d: int = 512,
     """R = coeff @ X via the streamed Pallas kernel.
 
     Args:
-      x: (n, d) stack; d must be a multiple of ``block_d`` (ops.py pads).
+      x: (n, d) stack, any d (a ragged last tile's extra columns are
+        never written back).
       coeff: (n,) fp32 combination weights.
-      block_d: VMEM tile width, multiple of 128.
+      block_d: VMEM tile width, a multiple of 128 or d itself.
       interpret: run the kernel body in the Pallas interpreter (CPU).
     Returns: (d,) fp32 combination.
     """
     n, d = x.shape
-    assert d % block_d == 0, (d, block_d)
-    grid = (d // block_d,)
     out = pl.pallas_call(
         _combine_kernel,
-        grid=grid,
+        grid=(pl.cdiv(d, block_d),),
         in_specs=[
             pl.BlockSpec((1, n), lambda i: (0, 0)),
             pl.BlockSpec((n, block_d), lambda i: (0, i)),

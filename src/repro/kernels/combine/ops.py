@@ -1,13 +1,13 @@
-"""Jit'd public wrapper for the streamed combine kernel (padding + dispatch)."""
+"""Jit'd public wrapper for the streamed combine kernel (any d)."""
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels.combine.kernel import combine_pallas
 from repro.kernels.combine.ref import combine_ref
+from repro.kernels.target import resolve_interpret
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "use_pallas",
@@ -17,17 +17,11 @@ def combine(x: jax.Array, coeff: jax.Array, *, block_d: int = 512,
             interpret: bool | None = None) -> jax.Array:
     """Linear combination coeff @ X of a (n, d) stack.
 
-    Pads d to a multiple of ``block_d`` (zero columns combine to an exact
-    zero tail which is sliced off) and dispatches to the Pallas kernel, or
-    to the jnp oracle when ``use_pallas=False``.
+    Runs the Pallas kernel over any d (per-column math: a ragged last tile
+    needs no mask, its out-of-range columns are never written), or the jnp
+    oracle when ``use_pallas=False``.
     """
     if not use_pallas:
         return combine_ref(x, coeff)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    _, d = x.shape
-    pad = (-d) % block_d
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad)))
-    out = combine_pallas(x, coeff, block_d=block_d, interpret=interpret)
-    return out[:d]
+    return combine_pallas(x, coeff, block_d=min(block_d, x.shape[1]),
+                          interpret=resolve_interpret(interpret))

@@ -7,8 +7,11 @@ ONE contiguous ``(n, D)`` view of the worker-stacked pytree:
 
 * **flatten** — :func:`flatten_worker_stack` concatenates every leaf's
   ``(n, ...)`` stack into a single ``(n, D)`` buffer plus static
-  leaf-segment metadata, so the kernels stream one buffer instead of
-  dispatching per leaf;
+  leaf-segment metadata, for the mesh backends that shard one buffer
+  along D; on a single device :func:`split_worker_stack` instead hands
+  the kernels each leaf's ``(n, d_i)`` view in place, so the stack is
+  never copied (at SmolLM-360M width with n=4 that copy is 5.8 GB, more
+  than a 16 GB chip has left beside the train state);
 * **gram** — the blocked Pallas kernel (``kernels/gram``), one (n, BLK_D)
   tile per grid step accumulating the tiny (n, n) Gram matrix;
 * **combine** — the streamed coefficient kernel (``kernels/combine``)
@@ -70,6 +73,7 @@ from repro.kernels.gram import gram as _gram_op
 from repro.kernels.gram import gram_batched as _gram_batched_op
 from repro.kernels.mixtrim import mixtrim as _mixtrim_op
 from repro.kernels.mixtrim import mixtrim_dyn as _mixtrim_dyn_op
+from repro.kernels.target import on_tpu, resolve_interpret
 
 Array = jax.Array
 PyTree = Any
@@ -109,7 +113,7 @@ def resolve_backend(requested: str, *, hier: bool = False) -> str:
         raise ValueError(
             f"unknown backend {requested!r}; expected one of {BACKENDS}")
     if requested == "auto":
-        if jax.default_backend() == "tpu":
+        if on_tpu():
             if jax.device_count() == 1:
                 return "pallas"
             return "pallas_hier" if hier else "pallas_sharded"
@@ -267,9 +271,12 @@ def open_record(*, requested: str, backend: str, rule: str,
 
 def record_decision(primitive: str, requested: str, used: str,
                     reason: str = "") -> None:
+    """Append a decision to the open record (once: the per-leaf calls of a
+    split stack repeat the same decision)."""
     if _HISTORY:
-        _HISTORY[-1].decisions.append(KernelDecision(primitive, requested,
-                                                     used, reason))
+        dec = KernelDecision(primitive, requested, used, reason)
+        if dec not in _HISTORY[-1].decisions:
+            _HISTORY[-1].decisions.append(dec)
 
 
 def _pallas_used(interpret: bool, sharded: bool = False) -> tuple[str, str]:
@@ -281,10 +288,10 @@ def _pallas_used(interpret: bool, sharded: bool = False) -> tuple[str, str]:
 
 def _pad_note(n: int) -> str:
     """Observability note for the sentinel-padded bitonic sort."""
-    from repro.kernels.mixtrim.kernel import next_pow2
-    if n & (n - 1) == 0:
+    from repro.kernels.mixtrim.kernel import sort_height
+    if sort_height(n) == n:
         return ""
-    return f"n={n} padded to {next_pow2(n)} with sort sentinels"
+    return f"n={n} padded to {sort_height(n)} with sort sentinels"
 
 
 # ---------------------------------------------------------------------------
@@ -300,13 +307,10 @@ class StackLayout:
     width: int              # total feature width D
 
 
-def flatten_worker_stack(tree: PyTree) -> tuple[Array, StackLayout]:
-    """Concatenate a worker-stacked pytree into one contiguous (n, D) view.
-
-    Every leaf carries a leading worker axis n; the result is a single
-    buffer the kernels can stream without per-leaf dispatch.  Mixed leaf
-    dtypes promote under concatenation (uniform fp32 / bf16 stacks — the
-    only cases the pipeline produces — keep their dtype)."""
+def split_worker_stack(tree: PyTree) -> tuple[list, StackLayout]:
+    """Every leaf's ``(n, d_i)`` view (a reshape: no copy) plus the same
+    layout :func:`flatten_worker_stack` gives, for kernels that run leaf
+    by leaf."""
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     n = leaves[0].shape[0]
     segs, flats, off = [], [], 0
@@ -315,15 +319,33 @@ def flatten_worker_stack(tree: PyTree) -> tuple[Array, StackLayout]:
         segs.append((off, flat.shape[1], tuple(leaf.shape[1:])))
         flats.append(flat)
         off += flat.shape[1]
+    return flats, StackLayout(treedef, tuple(segs), n, off)
+
+
+def flatten_worker_stack(tree: PyTree) -> tuple[Array, StackLayout]:
+    """Concatenate a worker-stacked pytree into one contiguous (n, D) view.
+
+    Every leaf carries a leading worker axis n; the result is a single
+    buffer the kernels can stream without per-leaf dispatch.  Mixed leaf
+    dtypes promote under concatenation (uniform fp32 / bf16 stacks — the
+    only cases the pipeline produces — keep their dtype)."""
+    flats, layout = split_worker_stack(tree)
     buf = flats[0] if len(flats) == 1 else jnp.concatenate(flats, axis=1)
-    return buf, StackLayout(treedef, tuple(segs), n, off)
+    return buf, layout
 
 
-def unflatten_aggregate(vec: Array, layout: StackLayout) -> PyTree:
-    """Rebuild the aggregated pytree (worker axis removed) from a (D,)
-    combined vector."""
-    leaves = [jax.lax.slice_in_dim(vec, off, off + size, axis=0).reshape(shape)
-              for off, size, shape in layout.segments]
+def unflatten_aggregate(vecs, layout: StackLayout) -> PyTree:
+    """Rebuild the aggregated pytree (worker axis removed) from the (D,)
+    aggregate of a flattened stack, or from the list of per-leaf (d_i,)
+    aggregates of a split one."""
+    if isinstance(vecs, (list, tuple)) and len(vecs) == len(layout.segments):
+        leaves = [v.reshape(shape)
+                  for v, (_, _, shape) in zip(vecs, layout.segments)]
+    else:
+        vec = vecs[0] if isinstance(vecs, (list, tuple)) else vecs
+        leaves = [jax.lax.slice_in_dim(vec, off, off + size,
+                                       axis=0).reshape(shape)
+                  for off, size, shape in layout.segments]
     return jax.tree_util.tree_unflatten(layout.treedef, leaves)
 
 
@@ -331,15 +353,18 @@ def unflatten_aggregate(vec: Array, layout: StackLayout) -> PyTree:
 # Primitive dispatchers.
 # ---------------------------------------------------------------------------
 
-def count_wide_ops(fn, *example_args, n: int, width: int) -> int:
-    """Structural fusion check: count dot_general / sort equations anywhere
-    in ``fn``'s jaxpr producing a full-width (n, width) value.
+def count_wide_ops(fn, *example_args, n: int, width: int,
+                   primitives: tuple = ("dot_general", "sort")) -> int:
+    """Structural fusion check: count ``primitives`` equations anywhere in
+    ``fn``'s jaxpr producing a full-width (n, width) value.
 
-    That shape signature is exactly the materialized NNM-mixed stack (the
-    ``Y = M @ X`` dot and the full-width sort): the XLA coordinate path has
-    them, the fused mixtrim path must not — its Pallas kernel jaxpr only
-    ever holds (n, BLK_D) tiles.  Used by ``benchmarks/bench_agg_cost.py``
-    and the perf gate to keep the elimination from regressing.
+    With the default primitives that shape signature is exactly the
+    materialized NNM-mixed stack (the ``Y = M @ X`` dot and the full-width
+    sort): the XLA coordinate path has them, the fused mixtrim path must
+    not — its Pallas kernel jaxpr only ever holds (n, BLK_D) tiles.  Used
+    by ``benchmarks/bench_agg_cost.py`` and the perf gate to keep the
+    elimination from regressing; ``("concatenate",)`` finds a flattened
+    copy of the whole stack.
     """
     closed = jax.make_jaxpr(fn)(*example_args)
 
@@ -355,7 +380,7 @@ def count_wide_ops(fn, *example_args, n: int, width: int) -> int:
     def count(jaxpr) -> int:
         c = 0
         for eqn in jaxpr.eqns:
-            if eqn.primitive.name in ("dot_general", "sort"):
+            if eqn.primitive.name in primitives:
                 for var in eqn.outvars:
                     if tuple(getattr(var.aval, "shape", ())) == (n, width):
                         c += 1
@@ -374,13 +399,13 @@ def dispatch_gram(x: Array, *, backend: str, block_d: Optional[int] = None,
     ``backend="pallas_sharded"`` needs the resolved (mesh, axis): the
     blocked kernel runs per D-shard and the tiny partial Grams psum."""
     if backend in _SHARDED_BACKENDS:
-        interpret = jax.default_backend() != "tpu"
+        interpret = resolve_interpret()
         used, why = _pallas_used(interpret, sharded=True)
         record_decision("gram", backend, used, why)
         return shardlib.sharded_gram(x, mesh=mesh, axis=axis,
                                      block_d=block_d, interpret=interpret)
     if backend == "pallas":
-        interpret = jax.default_backend() != "tpu"
+        interpret = resolve_interpret()
         used, why = _pallas_used(interpret)
         record_decision("gram", "pallas", used, why)
         bd = block_d if block_d is not None else pick_block_d(x.shape[1])
@@ -394,7 +419,7 @@ def dispatch_gram_batched(x: Array, *, backend: str,
     """(B, n, D) -> (B, n, n): the lane-batched Gram pass, one launch for a
     whole fleet shape bucket (grid = lanes x d-blocks)."""
     if backend == "pallas":
-        interpret = jax.default_backend() != "tpu"
+        interpret = resolve_interpret()
         used, why = _pallas_used(interpret)
         record_decision("gram_batched", "pallas", used, why)
         bd = block_d if block_d is not None else pick_block_d(x.shape[2])
@@ -423,7 +448,7 @@ def dispatch_bucketgram(x: Array, bmat: Array, *, backend: str,
     else runs the jnp oracle (RECORDED)."""
     from repro.kernels.bucketgram import bucket_means_gram as _bucketgram_op
     if backend == "pallas_hier":
-        interpret = jax.default_backend() != "tpu"
+        interpret = resolve_interpret()
         used, why = _pallas_used(interpret, sharded=True)
         w = f"workers={worker_axis}" if worker_axis else "D-sharded only"
         record_decision("bucketgram", backend, used,
@@ -433,7 +458,7 @@ def dispatch_bucketgram(x: Array, bmat: Array, *, backend: str,
             with_gram=with_gram, block_n=block_n, block_d=block_d,
             interpret=interpret)
     if backend == "pallas_sharded":
-        interpret = jax.default_backend() != "tpu"
+        interpret = resolve_interpret()
         used, why = _pallas_used(interpret, sharded=True)
         record_decision("bucketgram", backend, used, why)
         return shardlib.sharded_bucketgram(
@@ -441,7 +466,7 @@ def dispatch_bucketgram(x: Array, bmat: Array, *, backend: str,
             with_gram=with_gram, block_n=block_n, block_d=block_d,
             interpret=interpret)
     if backend == "pallas":
-        interpret = jax.default_backend() != "tpu"
+        interpret = resolve_interpret()
         used, why = _pallas_used(interpret)
         record_decision("bucketgram", "pallas", used, why)
         return _bucketgram_op(x, bmat, with_gram=with_gram, block_n=block_n,
@@ -456,13 +481,13 @@ def dispatch_combine(x: Array, coeff: Array, *, backend: str,
                      axis: Optional[str] = None) -> Array:
     """(n, D), (n,) -> (D,): streamed linear combination."""
     if backend in _SHARDED_BACKENDS:
-        interpret = jax.default_backend() != "tpu"
+        interpret = resolve_interpret()
         used, why = _pallas_used(interpret, sharded=True)
         record_decision("combine", backend, used, why)
         return shardlib.sharded_combine(x, coeff, mesh=mesh, axis=axis,
                                         block_d=block_d, interpret=interpret)
     if backend == "pallas":
-        interpret = jax.default_backend() != "tpu"
+        interpret = resolve_interpret()
         used, why = _pallas_used(interpret)
         record_decision("combine", "pallas", used, why)
         bd = block_d if block_d is not None else pick_block_d(x.shape[1])
@@ -491,14 +516,14 @@ def dispatch_mixtrim(x: Array, m: Optional[Array], f, *, mode: str,
         return f"{why}; {pad}" if why and pad else (pad or why)
 
     if backend in _SHARDED_BACKENDS:
-        interpret = jax.default_backend() != "tpu"
+        interpret = resolve_interpret()
         used, why = _pallas_used(interpret, sharded=True)
         record_decision("mixtrim", backend, used, _note(why))
         return shardlib.sharded_mixtrim(x, m, f, mode=mode, mesh=mesh,
                                         axis=axis, dyn=dyn, block_d=block_d,
                                         interpret=interpret)
     if backend == "pallas":
-        interpret = jax.default_backend() != "tpu"
+        interpret = resolve_interpret()
         used, why = _pallas_used(interpret)
         record_decision("mixtrim", "pallas", used, _note(why))
         bd = block_d if block_d is not None else pick_block_d(x.shape[1])

@@ -11,11 +11,11 @@ tile so Y never leaves VMEM:
     out : trimmed mean / median of Y_blk  ->  (1, BLK_D)
 
 The sort is a static bitonic network (log^2 n compare-exchange stages built
-from reshape + min/max + select), because dynamic gathers along the sublane
-dimension do not map to the TPU vector unit.  The network needs a
-power-of-two height; when n is not one (the common federated case, e.g.
-the paper's n=17), the worker dim is padded up to the next power of two
-with fp32-max sentinel rows.  Ascending sort parks every sentinel above
+from sublane rotations + min/max + select), because dynamic gathers along
+the sublane dimension do not map to the TPU vector unit.  The network needs a
+power-of-two height of at least one sublane tile (8 rows); when n is not
+one (the common federated case, e.g. the paper's n=17, or n=4), the
+worker dim is padded up to it in VMEM with fp32-max sentinel rows.  Ascending sort parks every sentinel above
 every finite value, so the real rows occupy sorted positions 0..n-1
 exactly as in the unpadded sort and the trim/median ranks simply ignore
 the sentinel tail — no jnp-oracle fallback, the fused kernel runs for
@@ -30,9 +30,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 #: Sentinel for the padded sort: sorts above every finite worker value.
 _SENTINEL = float(np.finfo(np.float32).max)
+
+#: Rows of one fp32 vreg tile.
+_SUBLANES = 8
 
 
 def next_pow2(n: int) -> int:
@@ -40,28 +44,43 @@ def next_pow2(n: int) -> int:
     return 1 << (n - 1).bit_length()
 
 
-def _compare_swap(y: jax.Array, j: int, dirs: jax.Array) -> jax.Array:
-    """One bitonic compare-exchange with partner i XOR j (static reshape)."""
+def _compare_swap(y: jax.Array, j: int, lower: jax.Array,
+                  keep_min: jax.Array) -> jax.Array:
+    """One bitonic compare-exchange of every row i with its partner i XOR j.
+
+    The partner row comes from two sublane rotations (``pltpu.roll``
+    follows ``jnp.roll``: ``roll(y, s)[i] = y[i - s]``) picked by whether
+    bit j of i is clear — static shapes and shifts only, which Mosaic
+    lowers (a reversed or 4-D reshaped view does not)."""
     n = y.shape[0]
-    y4 = y.reshape(n // (2 * j), 2, j, y.shape[-1])
-    yp = y4[:, ::-1].reshape(n, y.shape[-1])
-    lower = (jnp.arange(n) % (2 * j)) < j          # lower index of each pair
-    keep_min = lower == dirs                        # ascending keeps min low
-    return jnp.where(keep_min[:, None], jnp.minimum(y, yp), jnp.maximum(y, yp))
+    above = pltpu.roll(y, n - j, 0)        # y[i + j]
+    below = pltpu.roll(y, j, 0)            # y[i - j]
+    partner = jnp.where(lower, above, below)
+    return jnp.where(keep_min, jnp.minimum(y, partner),
+                     jnp.maximum(y, partner))
 
 
 def _bitonic_sort(y: jax.Array) -> jax.Array:
     """Sort (n, blk) along axis 0 ascending; n must be a power of two."""
     n = y.shape[0]
+    # >=2-D iota: 1-D iota does not lower on TPU.
+    row = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
     k = 2
     while k <= n:
-        dirs = (jnp.arange(n) & k) == 0
+        ascending = (row & k) == 0
         j = k // 2
         while j >= 1:
-            y = _compare_swap(y, j, dirs)
+            lower = (row & j) == 0          # i is the low index of its pair
+            y = _compare_swap(y, j, lower, lower == ascending)
             j //= 2
         k *= 2
     return y
+
+
+def sort_height(n: int) -> int:
+    """Rows of the bitonic network for n workers: a power of two, and at
+    least one full sublane tile (8 rows) so every rotation is whole-tile."""
+    return max(_SUBLANES, next_pow2(n))
 
 
 def _with_sentinels(y: jax.Array, n_real: int) -> jax.Array:
@@ -73,24 +92,56 @@ def _with_sentinels(y: jax.Array, n_real: int) -> jax.Array:
     IN-KERNEL — cheaper than a host-side (n_pad, D) zero-padded copy of
     the whole stack, which would re-materialize exactly the wide HBM
     intermediate this kernel exists to avoid."""
-    n_pad = next_pow2(n_real)
+    n_pad = sort_height(n_real)
     if n_pad == n_real:
         return y
     if y.shape[0] == n_real:
-        tail = jnp.full((n_pad - n_real, y.shape[1]), _SENTINEL,
-                        jnp.float32)
-        return jnp.concatenate([y, tail])
-    # >=2-D iota: 1-D iota does not lower on TPU.
+        tail = jnp.zeros((n_pad - n_real, y.shape[1]), jnp.float32)
+        y = jnp.concatenate([y, tail])
     i = jax.lax.broadcasted_iota(jnp.int32, (n_pad, 1), 0)
     return jnp.where(i < n_real, y, _SENTINEL)
 
 
-def _make_kernel(f: int, mode: str, mix: bool, n_real: int):
-    """Kernel body; ``mix=False`` drops the M operand and the MXU dot
-    entirely (plain CWTM/CWMed).  ``n_real`` is the true worker count; the
-    sort height is the (power-of-two) row count of the operand — any pad
-    rows become sentinels before the network runs."""
+def _reduce_sorted(ys: jax.Array, f, mode: str, n_real: int) -> jax.Array:
+    """(n_pad, blk) ascending columns -> (1, blk) trimmed mean / median.
+
+    Trimming sums the kept ranks one row at a time, in rank order: the
+    order of the oracles' column sums, and one that no tile width can
+    change (a ``sum(axis=0)`` lets the compiler reassociate), so interpret
+    mode matches ``mixtrim_ref`` / ``mixtrim_dyn_ref`` bit for bit and a
+    D-sharded run matches a single-device one.  A python-int ``f`` sums
+    ranks f..n_real-f-1; a traced scalar ``f`` (the dynamic kernel) weights
+    every real rank by ``f <= r < n_real - f``.  No sentinel row (rank >=
+    n_real) is ever read."""
+    if mode == "med":
+        mid = n_real // 2
+        if n_real % 2:
+            return ys[mid:mid + 1]
+        return 0.5 * (ys[mid - 1:mid] + ys[mid:mid + 1])
+    if mode != "trim":
+        raise ValueError(mode)
+    static = isinstance(f, int)
+    total = jnp.full((1, ys.shape[1]), jnp.nan, jnp.float32)  # nothing kept
+    for i, r in enumerate(range(f, n_real - f) if static else range(n_real)):
+        row = ys[r:r + 1]
+        if not static:
+            row = row * ((r >= f) & (r < n_real - f)).astype(jnp.float32)
+        total = row if i == 0 else total + row
+    if static:
+        return total / (n_real - 2 * f)
+    return total / jnp.maximum((n_real - 2 * f).astype(jnp.float32), 1.0)
+
+
+def _make_kernel(mode: str, mix: bool, n_real: int, f=None):
+    """Kernel body.  ``f=None`` reads the trim count from a leading (1,)
+    int32 SMEM operand (the dynamic kernel: one compile serves every
+    Byzantine budget of a fleet shape bucket); ``mode="med"`` ignores f.
+    ``mix=False`` drops the M operand and the MXU dot entirely (plain
+    CWTM/CWMed).  ``n_real`` is the true worker count; any pad rows of
+    the sort become sentinels before the network runs."""
     def kernel(*refs):
+        refs = list(refs)
+        f_val = f if f is not None else refs.pop(0)[0]
         if mix:
             m_ref, x_ref, o_ref = refs
         else:
@@ -106,70 +157,40 @@ def _make_kernel(f: int, mode: str, mix: bool, n_real: int):
         else:
             y = x
         ys = _bitonic_sort(_with_sentinels(y, n_real))
-        if mode == "trim":
-            kept = ys[f: n_real - f] if f else ys[:n_real]
-            o_ref[...] = kept.mean(axis=0, keepdims=True)
-        elif mode == "med":
-            if n_real % 2 == 1:
-                o_ref[...] = ys[n_real // 2][None]
-            else:
-                o_ref[...] = (0.5 * (ys[n_real // 2 - 1]
-                                     + ys[n_real // 2]))[None]
-        else:
-            raise ValueError(mode)
+        o_ref[...] = _reduce_sorted(ys, f_val, mode, n_real)
     return kernel
 
 
-def _make_dyn_kernel(mode: str, mix: bool, n_real: int):
-    """Kernel body with f as a RUNTIME (1, 1) int32 operand.
-
-    Trimming selects through a rank mask over the bitonically sorted stack
-    instead of the static ``ys[f : n - f]`` slice, mirroring
-    ``repro.core.robust._tree_coordinate_rule_dyn`` — so one compile serves
-    every Byzantine budget of a fleet shape bucket.  Sentinel pad rows sort
-    above every real value, so their ranks (>= n_real) never enter the
-    keep mask.  ``mode="med"`` ignores f (kept in the signature for
-    call-site uniformity); ``mix=False`` drops the M operand and the MXU
-    dot entirely.
-    """
-    def kernel(*refs):
-        if mix:
-            f_ref, m_ref, x_ref, o_ref = refs
-        else:
-            f_ref, x_ref, o_ref = refs
-        f = f_ref[0, 0]
-        x = x_ref[...].astype(jnp.float32)
-        if mix:
-            m = m_ref[...].astype(jnp.float32)
-            y = jax.lax.dot_general(
-                m, x, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        else:
-            y = x
-        ys = _bitonic_sort(_with_sentinels(y, n_real))
-        if mode == "trim":
-            i = jax.lax.broadcasted_iota(jnp.int32, (ys.shape[0], 1), 0)
-            keep = ((i >= f) & (i < n_real - f)).astype(jnp.float32)
-            denom = jnp.maximum((n_real - 2 * f).astype(jnp.float32), 1.0)
-            o_ref[...] = ((ys * keep).sum(axis=0) / denom)[None]
-        elif mode == "med":
-            if n_real % 2 == 1:
-                o_ref[...] = ys[n_real // 2][None]
-            else:
-                o_ref[...] = (0.5 * (ys[n_real // 2 - 1]
-                                     + ys[n_real // 2]))[None]
-        else:
-            raise ValueError(mode)
-    return kernel
-
-
-def _pad_mix_matrix(m, n: int, n_pad: int):
-    """Zero-row-pad M to (n_pad, n): the mix dot then produces the taller
-    stack directly.  X is never padded host-side — the no-mix path appends
-    its sentinel rows in-kernel (see _with_sentinels)."""
-    if m is not None and n_pad != n:
+def _mixtrim_call(x, m, f, *, mode: str, block_d: int, interpret: bool):
+    """The pallas_call behind both entry points: grid over the d tiles
+    (a ragged last tile is masked by the pipeline, never padded in HBM);
+    M, zero-row-padded to the sort height, is broadcast to every grid
+    step; a traced ``f`` rides in scalar memory."""
+    n, d = x.shape
+    n_pad = sort_height(n)
+    in_specs = [pl.BlockSpec((n, block_d), lambda i: (0, i))]
+    operands = [x]
+    if m is not None:
+        # Zero pad rows: the mix dot then produces the taller stack
+        # directly (their values are overwritten by sentinels in-kernel).
         m = jnp.pad(m, ((0, n_pad - n), (0, 0)))
-    return m
+        in_specs.insert(0, pl.BlockSpec((n_pad, n), lambda i: (0, 0)))
+        operands.insert(0, m)
+    static_f = None
+    if isinstance(f, int):
+        static_f = f
+    else:
+        in_specs.insert(0, pl.BlockSpec(memory_space=pltpu.SMEM))
+        operands.insert(0, jnp.asarray(f, jnp.int32).reshape(1))
+    out = pl.pallas_call(
+        _make_kernel(mode, m is not None, n, static_f),
+        grid=(pl.cdiv(d, block_d),),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, block_d), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
+        interpret=interpret,
+    )(*operands)
+    return out[0]
 
 
 @functools.partial(jax.jit,
@@ -179,34 +200,17 @@ def mixtrim_pallas(x: jax.Array, m: jax.Array, *, f: int, mode: str = "trim",
     """Fused (M @ X -> sort -> trim/median) over d tiles.
 
     Args:
-      x: (n, d) worker stack, any n >= 1, d a multiple of block_d.  Non-
-        power-of-two n runs the padded sentinel sort (see module docs).
+      x: (n, d) worker stack, any n >= 1, any d (block_d a multiple of
+        128).  Non-power-of-two n runs the padded sentinel sort (see
+        module docs).
       m: (n, n) mixing matrix, or None for plain CWTM/CWMed (the mix dot
         is elided entirely — no identity matmul).
       f: trim count (ignored for mode="med").
       mode: "trim" or "med".
     Returns: (d,) fp32 aggregate.
     """
-    n, d = x.shape
-    assert d % block_d == 0, (d, block_d)
-    grid = (d // block_d,)
-    mix = m is not None
-    n_pad = next_pow2(n)
-    m = _pad_mix_matrix(m, n, n_pad)
-    in_specs = [pl.BlockSpec((n, block_d), lambda i: (0, i))]
-    operands = (x,)
-    if mix:
-        in_specs.insert(0, pl.BlockSpec((n_pad, n), lambda i: (0, 0)))
-        operands = (m, x)
-    out = pl.pallas_call(
-        _make_kernel(f, mode, mix, n),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_d), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
-        interpret=interpret,
-    )(*operands)
-    return out[0]
+    return _mixtrim_call(x, m, int(f), mode=mode, block_d=block_d,
+                         interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("mode", "block_d", "interpret"))
@@ -216,31 +220,11 @@ def mixtrim_dyn_pallas(x: jax.Array, m: jax.Array, f: jax.Array, *,
     """Fused mix+trim with a TRACED Byzantine count.
 
     Same tiling as :func:`mixtrim_pallas` (including the padded sentinel
-    sort for non-power-of-two n); ``f`` rides along as a tiny (1, 1) int32
-    operand broadcast to every grid step, and trimming goes through a rank
-    mask.  Under ``jax.vmap`` (the fleet's lane axis) the pallas batching
-    rule prepends a lane grid dimension, so a whole shape bucket still
-    costs one compile.
+    sort for non-power-of-two n); ``f`` rides along as a (1,) int32
+    scalar-memory operand, and trimming goes through a rank mask.  Under
+    ``jax.vmap`` (the fleet's lane axis) the pallas batching rule prepends
+    a lane grid dimension, so a whole shape bucket still costs one
+    compile.
     """
-    n, d = x.shape
-    assert d % block_d == 0, (d, block_d)
-    f = jnp.asarray(f, jnp.int32).reshape(1, 1)
-    grid = (d // block_d,)
-    mix = m is not None
-    n_pad = next_pow2(n)
-    m = _pad_mix_matrix(m, n, n_pad)
-    in_specs = [pl.BlockSpec((1, 1), lambda i: (0, 0)),
-                pl.BlockSpec((n, block_d), lambda i: (0, i))]
-    operands = (f, x)
-    if mix:
-        in_specs.insert(1, pl.BlockSpec((n_pad, n), lambda i: (0, 0)))
-        operands = (f, m, x)
-    out = pl.pallas_call(
-        _make_dyn_kernel(mode, mix, n),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_d), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
-        interpret=interpret,
-    )(*operands)
-    return out[0]
+    return _mixtrim_call(x, m, jnp.asarray(f, jnp.int32), mode=mode,
+                         block_d=block_d, interpret=interpret)

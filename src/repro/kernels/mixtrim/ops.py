@@ -4,8 +4,8 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 
+from repro.kernels.target import resolve_interpret
 from repro.kernels.mixtrim.kernel import mixtrim_dyn_pallas, mixtrim_pallas
 from repro.kernels.mixtrim.ref import mixtrim_dyn_ref, mixtrim_ref
 
@@ -17,23 +17,18 @@ def mixtrim(x: jax.Array, m: jax.Array, *, f: int, mode: str = "trim",
             interpret: bool | None = None) -> jax.Array:
     """Fused NNM-mix + coordinate-wise trim/median of a (n, d) stack.
 
-    ``m=None`` elides the mix dot entirely (plain CWTM/CWMed).  Pads d to
-    a multiple of ``block_d`` (zero columns mix/sort/trim to an exact zero
-    tail which is sliced off).  Non-power-of-two n runs the padded
-    sentinel bitonic sort (see kernel.py) — the jnp oracle is used only
-    when ``use_pallas=False``.
+    ``m=None`` elides the mix dot entirely (plain CWTM/CWMed).  Any d: the
+    kernel's ragged last tile is masked in the pipeline, so the stack is
+    never copied to a padded width (a d below ``block_d`` runs as one
+    full-width tile).  Non-power-of-two n runs the padded sentinel bitonic
+    sort (see kernel.py) — the jnp oracle is used only when
+    ``use_pallas=False``.
     """
-    n, d = x.shape
     if not use_pallas:
         return mixtrim_ref(x, m, f, mode)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    pad = (-d) % block_d
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad)))
-    out = mixtrim_pallas(x, m, f=f, mode=mode, block_d=block_d,
-                         interpret=interpret)
-    return out[:d]
+    return mixtrim_pallas(x, m, f=f, mode=mode,
+                          block_d=min(block_d, x.shape[1]),
+                          interpret=resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("mode", "block_d", "use_pallas",
@@ -46,17 +41,11 @@ def mixtrim_dyn(x: jax.Array, m: jax.Array, f: jax.Array, *,
 
     One compile serves every f of a shape bucket: ``f`` is an int32 scalar
     operand (possibly a vmap lane tracer), trimming is a rank mask over the
-    sorted stack.  Same ``m=None`` / d-padding / sentinel-padded-sort
-    contract as :func:`mixtrim`.
+    sorted stack.  Same ``m=None`` / any-d / sentinel-padded-sort contract
+    as :func:`mixtrim`.
     """
-    n, d = x.shape
     if not use_pallas:
         return mixtrim_dyn_ref(x, m, f, mode)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    pad = (-d) % block_d
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad)))
-    out = mixtrim_dyn_pallas(x, m, f, mode=mode, block_d=block_d,
-                             interpret=interpret)
-    return out[:d]
+    return mixtrim_dyn_pallas(x, m, f, mode=mode,
+                              block_d=min(block_d, x.shape[1]),
+                              interpret=resolve_interpret(interpret))

@@ -25,7 +25,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.bucketgram import bucket_means_gram as _bucketgram_op
@@ -34,13 +33,14 @@ from repro.kernels.combine import combine as _combine_op
 from repro.kernels.gram import gram as _gram_op
 from repro.kernels.mixtrim import mixtrim as _mixtrim_op
 from repro.kernels.mixtrim import mixtrim_dyn as _mixtrim_dyn_op
+from repro.kernels.target import resolve_interpret
 
 Array = jax.Array
 
 
-def axis_size(mesh: jax.sharding.Mesh, axis: str) -> int:
-    """Device count along one named mesh axis."""
-    return dict(zip(mesh.axis_names, mesh.devices.shape))[axis]
+def axis_size(mesh, axis: str) -> int:
+    """Device count along one named axis of a concrete or abstract mesh."""
+    return mesh.shape[axis]
 
 
 def _resolve(mesh, axis, d, block_d, interpret):
@@ -49,9 +49,7 @@ def _resolve(mesh, axis, d, block_d, interpret):
     k = axis_size(mesh, axis)
     pad = (-d) % k
     bd = block_d if block_d is not None else pick_block_d((d + pad) // k)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return k, pad, bd, interpret
+    return k, pad, bd, resolve_interpret(interpret)
 
 
 def _pad_cols(x: Array, pad: int) -> Array:
@@ -71,8 +69,8 @@ def sharded_gram(x: Array, *, mesh: jax.sharding.Mesh, axis: str,
         g = _gram_op(xl, block_d=bd, use_pallas=True, interpret=interpret)
         return jax.lax.psum(g, axis)
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P(None, axis),),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(None, axis),),
+                       out_specs=P(), check_vma=False)
     return fn(_pad_cols(x, pad))
 
 
@@ -90,8 +88,8 @@ def sharded_combine(x: Array, coeff: Array, *, mesh: jax.sharding.Mesh,
         return _combine_op(xl, cl, block_d=bd, use_pallas=True,
                            interpret=interpret)
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P(None, axis), P()),
-                   out_specs=P(axis), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(None, axis), P()),
+                       out_specs=P(axis), check_vma=False)
     return fn(_pad_cols(x, pad), coeff)[:d]
 
 
@@ -128,8 +126,8 @@ def sharded_mixtrim(x: Array, m: Optional[Array], f, *, mode: str,
     if dyn and mode == "trim":
         operands.append(jnp.asarray(f, jnp.int32))
         in_specs.append(P())
-    fn = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                   out_specs=P(axis), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                       out_specs=P(axis), check_vma=False)
     return fn(*operands)[:d]
 
 
@@ -164,8 +162,7 @@ def sharded_bucketgram(x: Array, bmat: Array, *, mesh: jax.sharding.Mesh,
     kw = axis_size(mesh, worker_axis) if worker_axis is not None else 1
     pad_d = (-d) % kd
     pad_n = (-n) % kw
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     from repro.kernels.dispatch import pick_block_d
     bd = block_d if block_d is not None else pick_block_d((d + pad_d) // kd)
     bn = block_n if block_n is not None else _pick_block_n((n + pad_n) // kw)
@@ -184,11 +181,11 @@ def sharded_bucketgram(x: Array, bmat: Array, *, mesh: jax.sharding.Mesh,
                 return (y,)
             return y, jax.lax.psum(g, model_axis)
 
-        fn = shard_map(body1, mesh=mesh,
-                       in_specs=(P(None, model_axis), P()),
-                       out_specs=((P(None, model_axis), P()) if with_gram
-                                  else (P(None, model_axis),)),
-                       check_rep=False)
+        fn = jax.shard_map(body1, mesh=mesh,
+                           in_specs=(P(None, model_axis), P()),
+                           out_specs=((P(None, model_axis), P()) if with_gram
+                                      else (P(None, model_axis),)),
+                           check_vma=False)
         out = fn(xw, bmat)
         y = out[0][:, :d]
         return (y, out[1]) if with_gram else (y, None)
@@ -205,12 +202,12 @@ def sharded_bucketgram(x: Array, bmat: Array, *, mesh: jax.sharding.Mesh,
         g = _gram_op(y, block_d=bd, use_pallas=True, interpret=interpret)
         return y, jax.lax.psum(g, model_axis)
 
-    fn = shard_map(body2, mesh=mesh,
-                   in_specs=(P(worker_axis, model_axis),
-                             P(None, worker_axis)),
-                   out_specs=((P(None, model_axis), P()) if with_gram
-                              else (P(None, model_axis),)),
-                   check_rep=False)
+    fn = jax.shard_map(body2, mesh=mesh,
+                       in_specs=(P(worker_axis, model_axis),
+                                 P(None, worker_axis)),
+                       out_specs=((P(None, model_axis), P()) if with_gram
+                                  else (P(None, model_axis),)),
+                       check_vma=False)
     out = fn(xw, bmat)
     y = out[0][:, :d]
     return (y, out[1]) if with_gram else (y, None)
@@ -252,6 +249,6 @@ def sharded_meamed(x: Array, m: Optional[Array], f, *,
     if dyn:
         operands.append(jnp.asarray(f, jnp.int32))
         in_specs.append(P())
-    fn = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                   out_specs=P(axis), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                       out_specs=P(axis), check_vma=False)
     return fn(*operands)[:d]

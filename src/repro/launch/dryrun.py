@@ -211,7 +211,7 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
                           "pad_kv": pad_kv, "seq_par": seq_par,
                           "gqa_einsum": gqa_einsum}}
     t0 = time.time()
-    with meshlib.use_mesh(mesh), mesh_axes_scope(axes):
+    with jax.set_mesh(mesh), mesh_axes_scope(axes):
         model = build_model(cfg)
         if shape.kind == "train":
             jitted, args = build_train_target(

@@ -5,7 +5,6 @@ state (required: the dry-run sets XLA_FLAGS before any jax init).
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Any, Optional
 
 import jax
@@ -23,49 +22,24 @@ DATA_PAR = 16
 PODS = 2
 
 
-_ACTIVE_MESH: Optional[jax.sharding.Mesh] = None
+def current_mesh():
+    """The (abstract) mesh of the innermost ``jax.set_mesh`` scope, or None.
 
-
-@contextlib.contextmanager
-def use_mesh(mesh: jax.sharding.Mesh):
-    """Version-compatible mesh context.
-
-    jax renamed/moved the context-mesh API across releases: new versions
-    expose ``jax.set_mesh`` (and before that ``jax.sharding.use_mesh``);
-    older ones only have the ``Mesh`` resource-env context manager.  Code
-    should pair this with :func:`as_shardings` so ``jit(in_shardings=...)``
-    receives concrete ``NamedSharding``s, which every version accepts
-    (old jit rejects raw ``PartitionSpec``s outside ``set_mesh``).
-    """
-    global _ACTIVE_MESH
-    prev = _ACTIVE_MESH
-    _ACTIVE_MESH = mesh
-    try:
-        if hasattr(jax, "set_mesh"):
-            with jax.set_mesh(mesh):
-                yield mesh
-        elif hasattr(jax.sharding, "use_mesh"):
-            with jax.sharding.use_mesh(mesh):
-                yield mesh
-        else:
-            with mesh:
-                yield mesh
-    finally:
-        _ACTIVE_MESH = prev
-
-
-def current_mesh() -> Optional[jax.sharding.Mesh]:
-    """The mesh of the innermost active :func:`use_mesh` scope (or None)."""
-    return _ACTIVE_MESH
+    Abstract, so it can be read while tracing; the aggregation shard_maps
+    and axis-size lookups need nothing more."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def as_shardings(specs: Any, mesh: Optional[jax.sharding.Mesh] = None) -> Any:
     """Pytree of PartitionSpec -> NamedSharding over ``mesh`` (defaults to
-    the active use_mesh scope).  Existing Sharding leaves pass through."""
+    the concrete mesh of the active ``jax.set_mesh`` scope).  Existing
+    Sharding leaves pass through."""
     if mesh is None:
-        mesh = _ACTIVE_MESH
-    if mesh is None:
-        raise ValueError("as_shardings needs a mesh or an active use_mesh()")
+        mesh = jax.sharding.get_mesh()
+    if mesh.empty:
+        raise ValueError("as_shardings needs a mesh or an active "
+                         "jax.set_mesh()")
 
     def conv(s):
         if isinstance(s, jax.sharding.Sharding):
@@ -85,12 +59,12 @@ def as_shardings(specs: Any, mesh: Optional[jax.sharding.Mesh] = None) -> Any:
 AGG_AXIS_PREFERENCE = ("model", "shard")
 
 
-def aggregation_axis(mesh: jax.sharding.Mesh) -> Optional[str]:
+def aggregation_axis(mesh) -> Optional[str]:
     """The mesh axis the aggregation stage shards D over, or None.
 
     Prefers the axes in :data:`AGG_AXIS_PREFERENCE` (size > 1), else the
     largest axis; None when every axis has size 1 (nothing to shard)."""
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    sizes = dict(mesh.shape)
     for name in AGG_AXIS_PREFERENCE:
         if sizes.get(name, 1) > 1:
             return name
@@ -103,7 +77,7 @@ def aggregation_axis(mesh: jax.sharding.Mesh) -> Optional[str]:
 def aggregation_mesh() -> Optional[tuple[jax.sharding.Mesh, str]]:
     """(mesh, axis) the ``pallas_sharded`` backend should run over, or None.
 
-    The innermost active :func:`use_mesh` scope wins (sharding D along its
+    The innermost ``jax.set_mesh`` scope wins (sharding D along its
     :func:`aggregation_axis`); with no active mesh, a host with more than
     one visible device gets an ad-hoc 1-D mesh over all of them.  None
     means "no multi-device mesh" — the dispatcher records the degrade to
@@ -126,14 +100,14 @@ def aggregation_mesh() -> Optional[tuple[jax.sharding.Mesh, str]]:
 AGG_WORKER_AXIS_PREFERENCE = ("workers", "data", "pod")
 
 
-def aggregation_worker_axis(mesh: jax.sharding.Mesh,
+def aggregation_worker_axis(mesh,
                             model_axis: Optional[str]) -> Optional[str]:
     """The mesh axis hierarchical aggregation shards the worker dim over,
     or None (1-D hier: D-sharded only).
 
     Prefers :data:`AGG_WORKER_AXIS_PREFERENCE` (size > 1, distinct from the
     D axis), else the largest remaining axis."""
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    sizes = dict(mesh.shape)
     for name in AGG_WORKER_AXIS_PREFERENCE:
         if name != model_axis and sizes.get(name, 1) > 1:
             return name
@@ -148,7 +122,7 @@ def hier_aggregation_mesh() -> Optional[
     """(mesh, worker_axis | None, model_axis) for ``backend="pallas_hier"``,
     or None when the host has no multi-device mesh.
 
-    The innermost active :func:`use_mesh` scope wins: D shards along its
+    The innermost ``jax.set_mesh`` scope wins: D shards along its
     :func:`aggregation_axis` and the worker dim along
     :func:`aggregation_worker_axis` (None on 1-D meshes — the stack stays
     worker-replicated and only D shards).  With no active mesh, >= 4
@@ -174,21 +148,30 @@ def hier_aggregation_mesh() -> Optional[
     return None
 
 
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with Auto axes: sharding constraints inside the
+    model (``models.common.constrain``) then guide the partitioner, where
+    the Explicit axes ``jax.make_mesh`` defaults to would make them
+    assertions."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_hier_mesh(workers: int, model: int):
-    """Explicit 2-D mesh for hierarchical aggregation: the (n, D) stack
-    lives sharded along BOTH axes (worker shards x D shards)."""
-    return jax.make_mesh((workers, model), ("workers", "model"))
+    """2-D mesh for hierarchical aggregation: the (n, D) stack lives
+    sharded along BOTH axes (worker shards x D shards)."""
+    return make_mesh((workers, model), ("workers", "model"))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (PODS, DATA_PAR, MODEL_PAR) if multi_pod else (DATA_PAR, MODEL_PAR)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 2, model: int = 2):
     """Small mesh for CPU integration tests (host-device override)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def mesh_axes_for(cfg: ModelConfig, *, multi_pod: bool = False,

@@ -215,7 +215,9 @@ def build_train_step(loss_fn: Callable, optimizer: Optimizer,
             "direction_norm": global_norm(direction),
         }
         if cfg.track_kappa_hat:
-            metrics["kappa_hat"] = tree_kappa_hat(robust_dir, attacked,
+            # The honest rows of `stack` are those of `attacked`; reading
+            # the stack lets the attacked copy die with the aggregation.
+            metrics["kappa_hat"] = tree_kappa_hat(robust_dir, stack,
                                                   n_honest,
                                                   internals=tap_internals)
         if cfg.taps:

@@ -300,3 +300,38 @@ def test_fused_mixtrim_eliminates_mixed_stack():
 
     assert counts("xla") >= 2
     assert counts("pallas") == 0
+
+
+def test_single_device_pallas_streams_leaves_in_place():
+    """backend="pallas" runs the kernels on each leaf's (n, d_i) view: no
+    concatenated (n, D) copy of the stack (at full model width that copy
+    does not fit one chip), each primitive recorded once, and the result
+    equal to xla's.  The mesh backends still stream one flat buffer."""
+    tree = _tree(19)
+    width = 37 + 15 + 1
+    for rule in ("cwtm", "gm"):
+        spec = AggregatorSpec(rule=rule, f=3, pre="nnm", backend="pallas")
+        assert kdispatch.count_wide_ops(
+            lambda t: robust_lib.robust_aggregate(t, spec), tree,
+            n=16, width=width, primitives=("concatenate",)) == 0
+        got = robust_lib.robust_aggregate(tree, spec)
+        prims = [d.primitive for d in kdispatch.last_dispatch().decisions]
+        assert sorted(prims) == sorted(set(prims)), prims
+        ref = robust_lib.robust_aggregate(
+            tree, AggregatorSpec(rule=rule, f=3, pre="nnm", backend="xla"))
+        _assert_trees_close(got, ref, rtol=1e-5, atol=1e-5, err_msg=rule)
+
+
+def test_split_worker_stack_views_every_leaf():
+    tree = _tree(20, n=5)
+    segs, layout = kdispatch.split_worker_stack(tree)
+    flat, flat_layout = kdispatch.flatten_worker_stack(tree)
+    assert layout == flat_layout
+    assert [s.shape for s in segs] == [(5, size)
+                                       for _, size, _ in layout.segments]
+    np.testing.assert_array_equal(np.concatenate(segs, axis=1), flat)
+    # per-leaf aggregates rebuild the same tree as the flat one
+    per_leaf = kdispatch.unflatten_aggregate([s[2] for s in segs], layout)
+    _assert_trees_close(per_leaf,
+                        kdispatch.unflatten_aggregate(flat[2], layout),
+                        rtol=0, atol=0)

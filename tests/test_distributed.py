@@ -55,11 +55,11 @@ from repro.models import build_model, mesh_axes_scope, partition_specs, abstract
 from repro.models.common import MeshAxes
 from repro.optim import sgd
 from repro.optim.schedules import constant
-from repro.launch.mesh import as_shardings, use_mesh
+from repro.launch.mesh import as_shardings, make_debug_mesh
 from repro.training import ByzantineConfig, TrainerConfig, build_train_step, init_state
 
 W, B, S = 4, 2, 16
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_debug_mesh(4, 2)
 cfg = reduced_config("qwen2-7b")
 axes = MeshAxes(data=("data",), model="model", model_par=2, shard_kv=True,
                 workers_on_data=True)
@@ -86,7 +86,7 @@ def run(distributed):
                 params=pspecs, opt_state=(), step=P(),
                 momentum=[P(("data",)) for _ in state["momentum"]])
             batch_specs = {k: P(("data",)) for k in batch}
-            with use_mesh(mesh):
+            with jax.set_mesh(mesh):
                 step_j = jax.jit(step, in_shardings=as_shardings(
                     (state_specs, batch_specs, P()), mesh))
                 state2, metrics = step_j(state, batch, jax.random.PRNGKey(2))

@@ -86,16 +86,16 @@ import sys; sys.path.insert(0, %r)
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.configs import reduced_config
-from repro.launch.mesh import use_mesh
+from repro.launch.mesh import make_debug_mesh
 from repro.models import build_model, mesh_axes_scope, partition_specs
 from repro.models.common import MeshAxes
 cfg = reduced_config("minitron-8b")
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_debug_mesh(2, 2)
 axes = MeshAxes(data=("data",), model="model", model_par=2,
                 shard_kv=True, pad_kv_to_mesh=True)
 key = jax.random.PRNGKey(0)
 tokens = jax.random.randint(key, (2, 16), 0, cfg.vocab_size)
-with use_mesh(mesh), mesh_axes_scope(axes):
+with jax.set_mesh(mesh), mesh_axes_scope(axes):
     model = build_model(cfg)
     params = model.init(key)
     logits = model.forward(params, {"tokens": tokens})

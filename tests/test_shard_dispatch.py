@@ -264,12 +264,12 @@ def test_bucket_key_includes_mesh_signature():
     bucket key must change when the mesh does (compile-cache hygiene)."""
     from repro.fleet import bucket_key
     from repro.fleet.runner import _mesh_sig
-    from repro.launch.mesh import make_debug_mesh, use_mesh
+    from repro.launch.mesh import make_debug_mesh
     job = _shard_job()
     base = bucket_key(job)
     assert _mesh_sig() in base
     if jax.device_count() >= 4:
-        with use_mesh(make_debug_mesh(2, 2)):
+        with jax.set_mesh(make_debug_mesh(2, 2)):
             assert bucket_key(job) != base
     else:
         # single-device host: the signature is the bare device count
