@@ -1,0 +1,13 @@
+"""The benchmark's tests run on the CPU: harness pieces, the yardstick's
+arithmetic, a tiny cell end to end with the Pallas kernels interpreted,
+the control and planted faults."""
+import os
+import sys
+from pathlib import Path
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+BENCH = Path(__file__).resolve().parents[1]
+for p in (str(BENCH.parent / "src"), str(BENCH)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
