@@ -34,6 +34,7 @@ Both paths do ranking-sensitive arithmetic in fp32.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import jax
@@ -51,6 +52,7 @@ from repro.core.bucketing import (
 from repro.core import gram as gramlib
 from repro.core.types import AggregatorSpec, COORDINATE_RULES, GRAM_RULES
 from repro.kernels import dispatch as kdispatch
+from repro.obs import stages
 
 Array = jax.Array
 PyTree = Any
@@ -423,6 +425,17 @@ def _open_routed_record(spec: AggregatorSpec, *, dyn: bool
     return backend, mesh_ctx
 
 
+def _aggregate_stage(fn):
+    """Trace ``fn`` as the ``aggregate`` stage of the robust step
+    (:mod:`repro.obs.stages`); the stage is looked up at every call."""
+    @functools.wraps(fn)
+    def staged(*args, **kwargs):
+        with stages.stage("aggregate"):
+            return fn(*args, **kwargs)
+    return staged
+
+
+@_aggregate_stage
 def robust_aggregate(tree: PyTree, spec: AggregatorSpec, *,
                      key: Optional[Array] = None,
                      return_coeff: bool = False,
@@ -592,6 +605,7 @@ def _tree_bucket_dyn(tree: PyTree, f: Array, key: Array,
     return jax.tree_util.tree_map(bucket, tree), _adjusted_f_dyn(f, n_buckets)
 
 
+@_aggregate_stage
 def robust_aggregate_dyn(tree: PyTree, spec: AggregatorSpec, f: Array, *,
                          key: Optional[Array] = None,
                          internals: Optional[dict] = None) -> PyTree:

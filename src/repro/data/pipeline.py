@@ -14,6 +14,7 @@ from typing import Iterator, Optional
 import numpy as np
 
 from repro.data.dirichlet import partition_by_class
+from repro.obs import runtime as obs_runtime
 
 
 @dataclasses.dataclass
@@ -71,18 +72,22 @@ def worker_batches(ds: WorkerDataset, batch_size: int, *, seed: int = 0,
 
     ``flip_labels_for`` = f: the LAST f workers receive flipped labels
     (l -> C-1-l), implementing the LF attack through honest computation.
+    Sampling and stacking each batch is one ``data.batch`` span of
+    :mod:`repro.obs.runtime`, closed before the batch is yielded.
     """
     rng = np.random.default_rng(seed)
     n = ds.n_workers
     if n_classes is None:
         n_classes = infer_n_classes(ds, labels_key)
     while True:
-        rows = [sample_worker_batch(ds, w, batch_size, rng,
-                                    flip=w >= n - flip_labels_for,
-                                    labels_key=labels_key,
-                                    n_classes=n_classes)
-                for w in range(n)]
-        yield {k: np.stack([r[k] for r in rows]) for k in ds.arrays}
+        with obs_runtime.span("data.batch"):
+            rows = [sample_worker_batch(ds, w, batch_size, rng,
+                                        flip=w >= n - flip_labels_for,
+                                        labels_key=labels_key,
+                                        n_classes=n_classes)
+                    for w in range(n)]
+            batch = {k: np.stack([r[k] for r in rows]) for k in ds.arrays}
+        yield batch
 
 
 def full_batches(ds: WorkerDataset, *, flip_labels_for: int = 0,

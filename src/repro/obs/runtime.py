@@ -16,10 +16,21 @@ Design constraints:
 * **host-side only** — emission happens in Python (at trace time for
   anything inside jit, per the dispatch-record semantics), never inside
   compiled programs; the compiled hot path is untouched;
+* **on the profiler's clock too** — every :func:`span` also enters a
+  ``jax.profiler.TraceAnnotation`` of its name, so under
+  ``jax.profiler.trace`` it lands on the profile's host plane beside the
+  device ops (``span_at`` is retroactive and stays ring-only);
 * **bounded** — the ring holds the most recent ``capacity`` events
   (default 4096); counters are plain monotone floats;
-* **no hard deps** — stdlib only; numpy / dataclass payloads are
-  sanitized lazily at snapshot/export time, so emitting is cheap.
+* **no hard deps** — stdlib only at import (JAX is imported lazily, for
+  the profiler annotation and :func:`watch_compiles`); numpy / dataclass
+  payloads are sanitized lazily at snapshot/export time, so emitting is
+  cheap.
+
+:func:`watch_compiles` (called once on import of :mod:`repro.obs`) feeds
+JAX's own compile events into the counters ``jax.lower_s``,
+``jax.compile_s``, ``jax.cache_hits`` and ``jax.cache_misses`` and one
+``jax.compile`` event per backend compile.
 
 The kernel dispatch ring (:class:`DispatchRecord`, history, head) is
 re-exported here at the bottom: ``obs.runtime`` is the one-stop querying
@@ -34,11 +45,27 @@ import os
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Iterator, Optional
 
 #: Default ring capacity (events, not bytes).
 DEFAULT_CAPACITY = 4096
+
+#: ``jax.profiler.TraceAnnotation`` once looked up; False without JAX.
+_annotation_cls: Any = None
+
+
+def _profiler_annotation(name: str):
+    """A context that marks ``name`` on the JAX profiler's host plane
+    (a no-op when no profile is being taken, or without JAX)."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = False
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls(name) if _annotation_cls else nullcontext()
 
 
 def _sanitize(value: Any) -> Any:
@@ -98,12 +125,14 @@ class Runtime:
     @contextmanager
     def span(self, name: str, **args: Any) -> Iterator[dict]:
         """Record a wall-clock span around a ``with`` block.  The event is
-        appended at EXIT (so ``dur`` is final); ``ts`` is the entry time."""
+        appended at EXIT (so ``dur`` is final); ``ts`` is the entry time.
+        The block is also a ``jax.profiler.TraceAnnotation`` of ``name``."""
         t0 = self._now()
         ev = {"name": name, "kind": "span", "ts": t0, "dur": None,
               "args": args}
         try:
-            yield ev
+            with _profiler_annotation(name):
+                yield ev
         finally:
             ev["dur"] = self._now() - t0
             with self._lock:
@@ -277,6 +306,80 @@ def export_chrome_trace(path: str) -> int:
 
 
 # ---------------------------------------------------------------------------
+# JAX's compile events -> counters + one ring event per backend compile.
+# ---------------------------------------------------------------------------
+
+#: JAX monitoring events timed into ``jax.lower_s``: tracing to a jaxpr
+#: and lowering it to an MLIR module (``jax._src.dispatch``).
+_LOWER_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                 "/jax/core/compile/jaxpr_to_mlir_module_duration")
+#: Timed into ``jax.compile_s``: one XLA compile, or one load from the
+#: persistent compilation cache.
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: The persistent cache's answers: (answer, counter).
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": ("hit", "jax.cache_hits"),
+    "/jax/compilation_cache/cache_misses": ("miss", "jax.cache_misses")}
+_watch_lock = threading.Lock()
+_watching = False
+
+
+class _CompileWatch(threading.local):
+    """Per-thread state of the compile listeners: how deep in nested
+    lowering events the thread is (a jit traced inside another's trace
+    is counted once, in the outer one), and the persistent cache's answer
+    for the backend compile in progress."""
+    depth = 0
+    cache: Optional[str] = None
+
+
+def watch_compiles() -> None:
+    """Register listeners on ``jax.monitoring``, once per process.
+
+    They feed the counters ``jax.lower_s`` (seconds tracing and lowering
+    top-level jits), ``jax.compile_s`` (seconds in backend compiles,
+    cache loads included), ``jax.cache_hits`` and ``jax.cache_misses``
+    (the persistent compilation cache's answers; a miss is counted when
+    the compiled program is written), and record one ``jax.compile``
+    event per backend compile with its ``fun_name``, ``seconds`` and
+    ``cache`` ("hit", "miss", or None where the persistent cache is off).
+    """
+    global _watching
+    with _watch_lock:
+        if _watching:
+            return
+        _watching = True
+    from jax import monitoring
+
+    watch = _CompileWatch()
+
+    def on_enter(event: str, value: float, **kwargs: Any) -> None:
+        if event in _LOWER_EVENTS:
+            watch.depth += 1
+        elif event == _COMPILE_EVENT:
+            watch.cache = None
+
+    def on_duration(event: str, duration: float, **kwargs: Any) -> None:
+        if event in _LOWER_EVENTS:
+            watch.depth = max(watch.depth - 1, 0)
+            if watch.depth == 0:
+                inc("jax.lower_s", duration)
+        elif event == _COMPILE_EVENT:
+            inc("jax.compile_s", duration)
+            _RUNTIME.event("jax.compile", fun_name=kwargs.get("fun_name"),
+                           seconds=duration, cache=watch.cache)
+
+    def on_event(event: str, **kwargs: Any) -> None:
+        if event in _CACHE_EVENTS:
+            watch.cache, counter = _CACHE_EVENTS[event]
+            inc(counter)
+
+    monitoring.register_scalar_listener(on_enter)
+    monitoring.register_event_duration_secs_listener(on_duration)
+    monitoring.register_event_listener(on_event)
+
+
+# ---------------------------------------------------------------------------
 # Kernel dispatch ring re-exports: obs.runtime is the query surface, the
 # ring itself lives with its owner (repro.kernels.dispatch), which imports
 # THIS module lazily — strictly one-way at import time, no cycle.
@@ -291,7 +394,7 @@ __all__ = [
     "DEFAULT_CAPACITY", "Runtime", "get_runtime",
     "event", "span", "span_at", "now", "inc", "history", "counters",
     "snapshot", "reset",
-    "export_jsonl", "export_chrome_trace", "import_jsonl",
+    "export_jsonl", "export_chrome_trace", "import_jsonl", "watch_compiles",
     "DispatchRecord", "KernelDecision", "dispatch_count",
     "dispatch_history", "last_dispatch",
 ]

@@ -13,6 +13,10 @@ Structure of one step (DESIGN.md §3):
   4. robust aggregation over the worker axis (gram path or coordinate path)
      -> direction R_t, plus the kappa-hat diagnostic of paper Eq. (26).
   5. server optimizer applies R_t.
+
+Each stage is traced under its :func:`repro.obs.stages.stage` tag
+(backward, momentum, attack, aggregate, kappa, optimizer, taps), so a
+device trace splits the step's time by stage.
 """
 from __future__ import annotations
 
@@ -26,6 +30,7 @@ from repro.core import robust as robust_lib
 from repro.core.attacks import apply_attack_tree
 from repro.core.theory import tree_kappa_hat
 from repro.core.types import AggregatorSpec
+from repro.obs import stages
 from repro.optim import Optimizer, global_norm
 from repro.rounds.options import RoundOptions, resolve_options
 
@@ -158,9 +163,9 @@ def build_train_step(loss_fn: Callable, optimizer: Optimizer,
                 rp, fp, wbatch)
             return l, g
 
-        losses, grads = jax.vmap(grad_a, in_axes=(None, None, 0), **vmap_kw)(
-            robust_p, fsdp_p, batch)
-        grads = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32), grads)
+        with stages.stage("backward"):
+            losses, grads = jax.vmap(grad_a, in_axes=(None, None, 0),
+                                     **vmap_kw)(robust_p, fsdp_p, batch)
         n_workers = losses.shape[0]
         n_honest = n_workers - cfg.byz.f
 
@@ -172,35 +177,41 @@ def build_train_step(loss_fn: Callable, optimizer: Optimizer,
                 ls, _ = jax.vmap(lambda wb: loss_of(rp, fp, wb),
                                  **vmap_kw)(b)
                 return ls.mean()
-            fsdp_grads = jax.grad(mean_loss)(fsdp_p, robust_p, batch)
+            with stages.stage("backward"):
+                fsdp_grads = jax.grad(mean_loss)(fsdp_p, robust_p, batch)
         else:
             fsdp_grads = []
 
-        if cfg.algorithm == "dshb":
-            beta = jnp.asarray(cfg.beta, jnp.float32)
-            stack = jax.tree_util.tree_map(
-                lambda m, g: beta * m + (1 - beta) * g,
-                state["momentum"], grads)
-            new_momentum = stack
-        else:
-            stack = grads
-            new_momentum = None
+        with stages.stage("momentum"):
+            grads = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32),
+                                           grads)
+            if cfg.algorithm == "dshb":
+                beta = jnp.asarray(cfg.beta, jnp.float32)
+                stack = jax.tree_util.tree_map(
+                    lambda m, g: beta * m + (1 - beta) * g,
+                    state["momentum"], grads)
+                new_momentum = stack
+            else:
+                stack = grads
+                new_momentum = None
 
         # Byzantine simulation: overwrite the last f rows.
         agg_key, key = jax.random.split(key)
         closure = (lambda t: robust_lib.robust_aggregate(t, spec, key=agg_key)) \
             if cfg.byz.attack.endswith("_opt") else None
-        attacked = apply_attack_tree(cfg.byz.attack, stack, cfg.byz.f,
-                                     eta=cfg.byz.eta, agg_closure=closure)
+        with stages.stage("attack"):
+            attacked = apply_attack_tree(cfg.byz.attack, stack, cfg.byz.f,
+                                         eta=cfg.byz.eta, agg_closure=closure)
 
         tap_internals = {} if cfg.taps else None
         robust_dir = robust_lib.robust_aggregate(attacked, spec, key=agg_key,
                                                  internals=tap_internals)
-        direction = merge_params(robust_dir, list(fsdp_grads), treedef, is_fsdp)
-
-        lr = lr_schedule(state["step"])
-        new_params, new_opt = optimizer.update(direction, state["opt_state"],
-                                               params, lr)
+        with stages.stage("optimizer"):
+            direction = merge_params(robust_dir, list(fsdp_grads), treedef,
+                                     is_fsdp)
+            lr = lr_schedule(state["step"])
+            new_params, new_opt = optimizer.update(
+                direction, state["opt_state"], params, lr)
         new_state = dict(params=new_params, opt_state=new_opt,
                          step=state["step"] + 1)
         if new_momentum is not None:
@@ -209,23 +220,24 @@ def build_train_step(loss_fn: Callable, optimizer: Optimizer,
             # matching the simulation protocol of the paper's code.
             new_state["momentum"] = new_momentum
 
-        metrics = {
-            "loss": losses[:n_honest].mean(),
-            "lr": lr,
-            "direction_norm": global_norm(direction),
-        }
+        with stages.stage("optimizer"):
+            metrics = {
+                "loss": losses[:n_honest].mean(),
+                "lr": lr,
+                "direction_norm": global_norm(direction),
+            }
         if cfg.track_kappa_hat:
             # The honest rows of `stack` are those of `attacked`; reading
             # the stack lets the attacked copy die with the aggregation.
-            metrics["kappa_hat"] = tree_kappa_hat(robust_dir, stack,
-                                                  n_honest,
-                                                  internals=tap_internals)
+            with stages.stage("kappa"):
+                metrics["kappa_hat"] = tree_kappa_hat(
+                    robust_dir, stack, n_honest, internals=tap_internals)
         if cfg.taps:
             from repro.obs import health_taps
-            metrics["taps"] = health_taps(attacked, robust_dir,
-                                          n_honest=n_honest, f=spec.f,
-                                          rule=spec.rule, pre=spec.pre,
-                                          internals=tap_internals)
+            with stages.stage("taps"):
+                metrics["taps"] = health_taps(
+                    attacked, robust_dir, n_honest=n_honest, f=spec.f,
+                    rule=spec.rule, pre=spec.pre, internals=tap_internals)
         return new_state, metrics
 
     return step

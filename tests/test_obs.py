@@ -14,9 +14,20 @@ VERIFIES
   re-export being the same objects;
 * FedHistory alignment: NaN kappa placeholders + nanmean summary + taps
   columns; and one fleet-service drain exported END TO END (compiles,
-  segments, dispatch decisions all visible with timestamps).
+  segments, dispatch decisions all visible with timestamps);
+* STAGE TAGS: the robust D-SHB step at tiny SmolLM sizes carries every
+  stage as a ``robust_stage`` frontend attribute (the Pallas custom calls
+  ``aggregate``), and the tags change no instruction of the compiled
+  program;
+* the PROFILER CLOCK: a runtime span lands on the profile's host plane;
+  the compile counters fed by ``jax.monitoring``; one ``data.batch`` span
+  per batch of the input pipeline.
 """
+import contextlib
+import functools
+import glob
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +37,7 @@ import pytest
 from repro import obs
 from repro.core import AggregatorSpec
 from repro.core.robust import robust_aggregate
+from repro.data.pipeline import WorkerDataset, worker_batches
 from repro.fed import (
     ClientConfig, FedConfig, FedServer, constant_attack, run_rounds,
 )
@@ -34,6 +46,7 @@ from repro.fed.schedules import AttackPhase, AttackSchedule
 from repro.fleet import FleetJob, FleetRunner
 from repro.kernels import dispatch as kdispatch
 from repro.obs import runtime as obs_runtime
+from repro.obs import stages
 from repro.optim import sgd
 from repro.optim.schedules import constant
 from repro.serving.engine import FleetService
@@ -443,3 +456,162 @@ def test_fleet_drain_export_end_to_end(tmp_path):
     svc.submit(_fleet_job(True, 1, 10))
     svc.drain()
     assert svc.last_dispatch is None
+
+
+# ---------------------------------------------------------------------------
+# Stage tags on the robust step.
+# ---------------------------------------------------------------------------
+
+#: SmolLM's architecture at toy widths.
+_TINY_LM = dict(num_layers=2, d_model=64, num_heads=4, num_kv_heads=2,
+                d_ff=128, vocab_size=256, head_dim=16)
+_STEP_STAGES = ("backward", "momentum", "attack", "aggregate", "kappa",
+                "optimizer")
+#: What a compiled program's text says beside its instructions.
+_ANNOTATION = re.compile(
+    r",? ?(metadata|frontend_attributes)=\{[^{}]*(\{[^{}]*\}[^{}]*)*\}")
+
+
+def _tiny_step(backend):
+    """The D-SHB step (n=4, f=1 ALIE, NNM+CWTM) of a tiny SmolLM, jitted
+    with the state donated, and its argument shapes."""
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.training import build_train_step, init_state
+
+    model = build_model(get_config("smollm-360m").replace(**_TINY_LM))
+    n, f = 4, 1
+    cfg = TrainerConfig(
+        algorithm="dshb", beta=0.9,
+        agg=AggregatorSpec(rule="cwtm", pre="nnm", f=f, backend=backend),
+        byz=ByzantineConfig(f=f, attack="alie"))
+    opt = sgd(clip=2.0)
+    step = jax.jit(build_train_step(model.loss, opt, cfg, constant(0.05)),
+                   donate_argnums=0)
+    state = jax.eval_shape(lambda k: init_state(model.init(k), opt, n, cfg),
+                           jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((n, 2, 16), jnp.int32)
+    return step, (state, {"tokens": tokens, "labels": tokens},
+                  jax.random.PRNGKey(0))
+
+
+@functools.lru_cache(maxsize=None)
+def _compiled_step_text(backend):
+    step, args = _tiny_step(backend)
+    return step.lower(*args).compile().as_text()
+
+
+def _instructions(text):
+    """The compiled program without its annotations: the header and the
+    computations, with ``metadata`` and ``frontend_attributes`` removed
+    (the source-location tables before the first computation go too)."""
+    lines = text.splitlines()
+    first = next(i for i, l in enumerate(lines)
+                 if l.startswith(("%", "ENTRY")))
+    return _ANNOTATION.sub("", "\n".join(lines[:1] + lines[first:]))
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_step_carries_every_stage_tag(backend):
+    text = _compiled_step_text(backend)
+    found = set(re.findall(r'robust_stage="(\w+)"', text))
+    assert set(_STEP_STAGES) <= found <= set(stages.STAGES), found
+
+
+def test_step_kernels_carry_aggregate_tag(monkeypatch):
+    """Lowered for a TPU (Mosaic kernels, no chip needed), every Pallas
+    custom call of the step carries the ``aggregate`` stage beside its
+    own attributes."""
+    from repro.kernels import target
+
+    monkeypatch.setattr(target, "on_tpu", lambda: True)
+    step, args = _tiny_step("pallas")
+    text = step.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    calls = [l for l in text.splitlines() if "@tpu_custom_call" in l]
+    assert len(calls) >= 2
+    for line in calls:
+        assert "mhlo.frontend_attributes" in line
+        assert 'robust_stage = "aggregate"' in line
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_stage_tags_change_no_instruction(backend, monkeypatch):
+    tagged = _compiled_step_text(backend)
+    monkeypatch.setattr(stages, "stage",
+                        lambda name: contextlib.nullcontext())
+    step, args = _tiny_step(backend)
+    plain = step.lower(*args).compile().as_text()
+    assert "robust_stage" not in plain
+    assert _instructions(tagged) == _instructions(plain)
+
+
+def test_stage_names_are_closed_and_nested_stage_wins():
+    with pytest.raises(ValueError, match="unknown stage"):
+        with stages.stage("forward"):
+            pass
+
+    def fn(x):
+        with stages.stage("attack"):
+            y = jnp.sin(x)
+            with stages.stage("aggregate"):
+                z = jnp.cos(y)
+        return y, z
+
+    text = jax.jit(fn).lower(jnp.ones(3)).as_text()
+    sin = [l for l in text.splitlines() if "stablehlo.sine" in l]
+    cos = [l for l in text.splitlines() if "stablehlo.cosine" in l]
+    assert sin and 'robust_stage = "attack"' in sin[0]
+    assert cos and 'robust_stage = "aggregate"' in cos[0]
+
+
+# ---------------------------------------------------------------------------
+# Program spans on the profiler's clock; compile counters; data.batch.
+# ---------------------------------------------------------------------------
+
+def test_runtime_span_lands_on_profiler_host_plane(tmp_path):
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        with obs_runtime.span("obs.test_span"):
+            jnp.ones(4).block_until_ready()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    planes = [plane.name for plane in ProfileData.from_file(path).planes
+              if any(ev.name == "obs.test_span"
+                     for line in plane.lines for ev in line.events)]
+    assert planes and all(p.startswith("/host:") for p in planes)
+    assert obs_runtime.history(name="obs.test_span")[-1]["dur"] >= 0.0
+
+
+def test_compile_counters_count_fresh_compiles_only():
+    counted = ("jax.compile_s", "jax.lower_s")
+    fn = jax.jit(lambda x: jnp.tanh(x) * 3.0 - 1.0)
+    x = jnp.ones(5)
+    before = obs_runtime.counters()
+    fn(x).block_until_ready()
+    after = obs_runtime.counters()
+    for name in counted:
+        assert after.get(name, 0.0) > before.get(name, 0.0), name
+    ev = obs_runtime.history(name="jax.compile")[-1]
+    assert ev["args"]["fun_name"] == "jit(<lambda>)"
+    assert ev["args"]["seconds"] >= 0.0
+    fn(x).block_until_ready()
+    again = obs_runtime.counters()
+    for name in counted:
+        assert again.get(name) == after.get(name), name
+
+
+def test_worker_batches_records_one_span_per_batch():
+    ds = WorkerDataset({"x": np.arange(40.0).reshape(20, 2),
+                        "y": np.arange(20) % 4},
+                       [np.arange(0, 10), np.arange(10, 20)])
+    feed = worker_batches(ds, 3, seed=1)
+    mark = obs_runtime.event("obs.test_mark")["seq"]
+    for i in range(1, 6):
+        batch = next(feed)
+        assert batch["x"].shape == (2, 3, 2)
+        # The batch's span is closed and recorded before it is handed out.
+        spans = [e for e in obs_runtime.history(name="data.batch",
+                                                kind="span")
+                 if e["seq"] > mark]
+        assert len(spans) == i and spans[-1]["dur"] >= 0.0
