@@ -12,7 +12,7 @@ from repro.kernels.target import resolve_interpret
 
 @functools.partial(jax.jit, static_argnames=("block_d", "use_pallas",
                                              "interpret"))
-def combine(x: jax.Array, coeff: jax.Array, *, block_d: int = 512,
+def combine(x: jax.Array, coeff: jax.Array, *, block_d: int | None = None,
             use_pallas: bool = True,
             interpret: bool | None = None) -> jax.Array:
     """Linear combination coeff @ X of a (n, d) stack.
@@ -23,5 +23,5 @@ def combine(x: jax.Array, coeff: jax.Array, *, block_d: int = 512,
     """
     if not use_pallas:
         return combine_ref(x, coeff)
-    return combine_pallas(x, coeff, block_d=min(block_d, x.shape[1]),
+    return combine_pallas(x, coeff, block_d=block_d,
                           interpret=resolve_interpret(interpret))

@@ -12,8 +12,9 @@ ONE contiguous ``(n, D)`` view of the worker-stacked pytree:
   the kernels each leaf's ``(n, d_i)`` view in place, so the stack is
   never copied (at SmolLM-360M width with n=4 that copy is 5.8 GB, more
   than a 16 GB chip has left beside the train state);
-* **gram** — the blocked Pallas kernel (``kernels/gram``), one (n, BLK_D)
-  tile per grid step accumulating the tiny (n, n) Gram matrix;
+* **gram** — the blocked Pallas kernel (``kernels/gram``), one wide
+  (n, W) tile per grid step accumulating the tiny (n, n) Gram matrix
+  (W from :func:`pick_block_d`, recorded on each decision);
 * **combine** — the streamed coefficient kernel (``kernels/combine``)
   applying the gram-rule weights without re-materializing anything;
 * **mixtrim** — the fused NNM-mix + coordinate trim/median kernel
@@ -25,7 +26,7 @@ Under a multi-device mesh, ``backend="pallas_sharded"`` runs the same
 pipeline shard_map'd along D (:mod:`repro.kernels.shard`): per-shard
 blocked gram + an O(n^2)-byte psum, replicated coefficient math,
 shard-local combine/mixtrim — the memory bound per device drops from
-n x largest-leaf-shard to the (n, BLK_D) VMEM tile.
+n x largest-leaf-shard to the (n, W) VMEM tile.
 
 ``backend="pallas_hier"`` is the hierarchical form for large worker
 counts (``AggregatorSpec.hier``): the fused bucketed-gram kernel
@@ -74,6 +75,9 @@ from repro.kernels.gram import gram_batched as _gram_batched_op
 from repro.kernels.mixtrim import mixtrim as _mixtrim_op
 from repro.kernels.mixtrim import mixtrim_dyn as _mixtrim_dyn_op
 from repro.kernels.target import on_tpu, resolve_interpret
+from repro.kernels.tiling import (  # noqa: F401  (pick_block_d: re-export)
+    block_width, grid_steps, pick_block_d, sort_height,
+)
 
 Array = jax.Array
 PyTree = Any
@@ -86,9 +90,6 @@ _PALLAS_BACKENDS = ("pallas", "pallas_sharded", "pallas_hier")
 
 #: Backends whose downstream primitives run the shard_map'd kernel forms.
 _SHARDED_BACKENDS = ("pallas_sharded", "pallas_hier")
-
-#: Default VMEM tile-width cap (lane-dim multiple of 128, MXU-sized).
-DEFAULT_BLOCK_D = 512
 
 
 def resolve_backend(requested: str, *, hier: bool = False) -> str:
@@ -138,15 +139,6 @@ def resolve_hier_mesh() -> Optional[
     return hier_aggregation_mesh()
 
 
-def pick_block_d(d: int, cap: int = DEFAULT_BLOCK_D) -> int:
-    """VMEM tile width for a D-wide stream: a multiple of 128 (lane/MXU
-    tiling), the smallest covering d for narrow stacks, capped for wide
-    ones so the (n, BLK_D) tile stays comfortably inside VMEM."""
-    if d >= cap:
-        return cap
-    return max(128, -(-d // 128) * 128)
-
-
 # ---------------------------------------------------------------------------
 # Decision record.
 # ---------------------------------------------------------------------------
@@ -162,6 +154,10 @@ class KernelDecision:
     requested: str          # backend asked for at this call site
     used: str               # "pallas[-sharded][-interpret]" | "xla"
     reason: str = ""        # why `used` differs from the pallas kernel path
+    #: Grid tile width W the kernel streamed with (per shard under the
+    #: sharded backends) and its number of grid steps; None off-kernel.
+    block_d: Optional[int] = None
+    grid_steps: Optional[int] = None
 
     @property
     def fell_back(self) -> bool:
@@ -208,7 +204,9 @@ class DispatchRecord:
                  f"pre={self.pre or 'none'} dyn={self.dyn}{hier}{mesh}"]
         for d in self.decisions:
             why = f" ({d.reason})" if d.reason else ""
-            parts.append(f"  {d.primitive}: {d.used}{why}")
+            tile = (f" W={d.block_d} steps={d.grid_steps}"
+                    if d.block_d is not None else "")
+            parts.append(f"  {d.primitive}: {d.used}{tile}{why}")
         return "\n".join(parts)
 
 
@@ -270,11 +268,13 @@ def open_record(*, requested: str, backend: str, rule: str,
 
 
 def record_decision(primitive: str, requested: str, used: str,
-                    reason: str = "") -> None:
+                    reason: str = "", tile: Optional[tuple] = None) -> None:
     """Append a decision to the open record (once: the per-leaf calls of a
-    split stack repeat the same decision)."""
+    split stack repeat the same decision for leaves of one tile).
+    ``tile`` is the kernel's (grid tile width, grid steps)."""
     if _HISTORY:
-        dec = KernelDecision(primitive, requested, used, reason)
+        dec = KernelDecision(primitive, requested, used, reason,
+                             *(tile or (None, None)))
         if dec not in _HISTORY[-1].decisions:
             _HISTORY[-1].decisions.append(dec)
 
@@ -286,9 +286,20 @@ def _pallas_used(interpret: bool, sharded: bool = False) -> tuple[str, str]:
     return base, ""
 
 
+def _tile(x: Array, block_d: Optional[int], mesh=None,
+          axis: Optional[str] = None) -> tuple[int, int]:
+    """(grid tile width, grid steps) a kernel streams the (..., n, d)
+    stack ``x`` with: ``block_d`` as given, else the widest that fits
+    VMEM; under a mesh, for one shard's columns."""
+    n, d = x.shape[-2:]
+    if mesh is not None:
+        d = shardlib.local_width(d, mesh, axis)
+    w = block_width(d, block_d, n, x.dtype)
+    return w, grid_steps(d, w)
+
+
 def _pad_note(n: int) -> str:
     """Observability note for the sentinel-padded bitonic sort."""
-    from repro.kernels.mixtrim.kernel import sort_height
     if sort_height(n) == n:
         return ""
     return f"n={n} padded to {sort_height(n)} with sort sentinels"
@@ -361,7 +372,7 @@ def count_wide_ops(fn, *example_args, n: int, width: int,
     With the default primitives that shape signature is exactly the
     materialized NNM-mixed stack (the ``Y = M @ X`` dot and the full-width
     sort): the XLA coordinate path has them, the fused mixtrim path must
-    not — its Pallas kernel jaxpr only ever holds (n, BLK_D) tiles.  Used
+    not — its Pallas kernel jaxpr only ever holds (n, W) tiles.  Used
     by ``benchmarks/bench_agg_cost.py`` and the perf gate to keep the
     elimination from regressing; ``("concatenate",)`` finds a flattened
     copy of the whole stack.
@@ -401,15 +412,16 @@ def dispatch_gram(x: Array, *, backend: str, block_d: Optional[int] = None,
     if backend in _SHARDED_BACKENDS:
         interpret = resolve_interpret()
         used, why = _pallas_used(interpret, sharded=True)
-        record_decision("gram", backend, used, why)
+        tile = _tile(x, block_d, mesh, axis)
+        record_decision("gram", backend, used, why, tile)
         return shardlib.sharded_gram(x, mesh=mesh, axis=axis,
-                                     block_d=block_d, interpret=interpret)
+                                     block_d=tile[0], interpret=interpret)
     if backend == "pallas":
         interpret = resolve_interpret()
         used, why = _pallas_used(interpret)
-        record_decision("gram", "pallas", used, why)
-        bd = block_d if block_d is not None else pick_block_d(x.shape[1])
-        return _gram_op(x, block_d=bd, interpret=interpret)
+        tile = _tile(x, block_d)
+        record_decision("gram", "pallas", used, why, tile)
+        return _gram_op(x, block_d=tile[0], interpret=interpret)
     record_decision("gram", backend, "xla")
     return _gram_op(x, use_pallas=False)
 
@@ -421,9 +433,9 @@ def dispatch_gram_batched(x: Array, *, backend: str,
     if backend == "pallas":
         interpret = resolve_interpret()
         used, why = _pallas_used(interpret)
-        record_decision("gram_batched", "pallas", used, why)
-        bd = block_d if block_d is not None else pick_block_d(x.shape[2])
-        return _gram_batched_op(x, block_d=bd, interpret=interpret)
+        tile = _tile(x, block_d)
+        record_decision("gram_batched", "pallas", used, why, tile)
+        return _gram_batched_op(x, block_d=tile[0], interpret=interpret)
     record_decision("gram_batched", backend, "xla")
     return _gram_batched_op(x, use_pallas=False)
 
@@ -483,15 +495,16 @@ def dispatch_combine(x: Array, coeff: Array, *, backend: str,
     if backend in _SHARDED_BACKENDS:
         interpret = resolve_interpret()
         used, why = _pallas_used(interpret, sharded=True)
-        record_decision("combine", backend, used, why)
+        tile = _tile(x, block_d, mesh, axis)
+        record_decision("combine", backend, used, why, tile)
         return shardlib.sharded_combine(x, coeff, mesh=mesh, axis=axis,
-                                        block_d=block_d, interpret=interpret)
+                                        block_d=tile[0], interpret=interpret)
     if backend == "pallas":
         interpret = resolve_interpret()
         used, why = _pallas_used(interpret)
-        record_decision("combine", "pallas", used, why)
-        bd = block_d if block_d is not None else pick_block_d(x.shape[1])
-        return _combine_op(x, coeff, block_d=bd, interpret=interpret)
+        tile = _tile(x, block_d)
+        record_decision("combine", "pallas", used, why, tile)
+        return _combine_op(x, coeff, block_d=tile[0], interpret=interpret)
     record_decision("combine", backend, "xla")
     return _combine_op(x, coeff, use_pallas=False)
 
@@ -518,15 +531,17 @@ def dispatch_mixtrim(x: Array, m: Optional[Array], f, *, mode: str,
     if backend in _SHARDED_BACKENDS:
         interpret = resolve_interpret()
         used, why = _pallas_used(interpret, sharded=True)
-        record_decision("mixtrim", backend, used, _note(why))
+        tile = _tile(x, block_d, mesh, axis)
+        record_decision("mixtrim", backend, used, _note(why), tile)
         return shardlib.sharded_mixtrim(x, m, f, mode=mode, mesh=mesh,
-                                        axis=axis, dyn=dyn, block_d=block_d,
+                                        axis=axis, dyn=dyn, block_d=tile[0],
                                         interpret=interpret)
     if backend == "pallas":
         interpret = resolve_interpret()
         used, why = _pallas_used(interpret)
-        record_decision("mixtrim", "pallas", used, _note(why))
-        bd = block_d if block_d is not None else pick_block_d(x.shape[1])
+        tile = _tile(x, block_d)
+        record_decision("mixtrim", "pallas", used, _note(why), tile)
+        bd = tile[0]
         if dyn and mode == "trim":
             return _mixtrim_dyn_op(x, m, f, mode=mode, block_d=bd,
                                    interpret=interpret)

@@ -11,24 +11,26 @@ from repro.kernels.target import resolve_interpret
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "use_pallas", "interpret"))
-def gram(x: jax.Array, *, block_d: int = 512, use_pallas: bool = True,
-         interpret: bool | None = None) -> jax.Array:
+def gram(x: jax.Array, *, block_d: int | None = None,
+         use_pallas: bool = True, interpret: bool | None = None) -> jax.Array:
     """Gram matrix of a (n, d) stack.
 
-    Runs the Pallas kernel over any d (a ragged last tile is masked
-    in-kernel; a d below ``block_d`` is one full-width tile, so it is the
+    Runs the Pallas kernel over any d in ``block_d``-wide grid tiles
+    (None: the widest that fits VMEM, ``tiling.pick_block_d``; a ragged
+    last chunk is masked in-kernel; a d of one chunk or less is the
     oracle's contraction verbatim), or the jnp oracle when
     ``use_pallas=False``.  ``interpret=None`` resolves to True off-TPU so
     the same call site works everywhere.
     """
     if not use_pallas:
         return gram_ref(x)
-    return gram_pallas(x, block_d=min(block_d, x.shape[1]),
+    return gram_pallas(x, block_d=block_d,
                        interpret=resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "use_pallas", "interpret"))
-def gram_batched(x: jax.Array, *, block_d: int = 512, use_pallas: bool = True,
+def gram_batched(x: jax.Array, *, block_d: int | None = None,
+                 use_pallas: bool = True,
                  interpret: bool | None = None) -> jax.Array:
     """Per-lane Gram matrices of a (B, n, d) lane-batched stack.
 
@@ -37,5 +39,5 @@ def gram_batched(x: jax.Array, *, block_d: int = 512, use_pallas: bool = True,
     """
     if not use_pallas:
         return gram_batched_ref(x)
-    return gram_batched_pallas(x, block_d=min(block_d, x.shape[2]),
+    return gram_batched_pallas(x, block_d=block_d,
                                interpret=resolve_interpret(interpret))

@@ -5,10 +5,14 @@ materializes the mixed stack Y = M @ X in HBM (n x |shard| extra bytes) and
 reads it back for the sort.  This kernel fuses the three stages per VMEM
 tile so Y never leaves VMEM:
 
-    VMEM: X_blk (n, BLK_D), M (n, n)
-    MXU : Y_blk = M @ X_blk
+    VMEM: X_blk (n, W), M (n, n)       --- one wide tile per grid step
+    MXU : Y_c = M @ X_c                --- per (n, C) chunk of X_blk
     VPU : bitonic sort network along the (small) worker dim
-    out : trimmed mean / median of Y_blk  ->  (1, BLK_D)
+    out : trimmed mean / median of Y_c  ->  its (1, C) output slice
+
+The grid tile W is as wide as VMEM allows and the chunk C holds about 32
+vregs of the sort network (``repro.kernels.tiling``: 4096 lanes at n <= 8,
+narrower for more workers).
 
 The sort is a static bitonic network (log^2 n compare-exchange stages built
 from sublane rotations + min/max + select), because dynamic gathers along
@@ -32,16 +36,11 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import tiling
+from repro.kernels.tiling import next_pow2, sort_height
+
 #: Sentinel for the padded sort: sorts above every finite worker value.
 _SENTINEL = float(np.finfo(np.float32).max)
-
-#: Rows of one fp32 vreg tile.
-_SUBLANES = 8
-
-
-def next_pow2(n: int) -> int:
-    """Smallest power of two >= n (the bitonic network height)."""
-    return 1 << (n - 1).bit_length()
 
 
 def _compare_swap(y: jax.Array, j: int, lower: jax.Array,
@@ -60,13 +59,17 @@ def _compare_swap(y: jax.Array, j: int, lower: jax.Array,
                      jnp.maximum(y, partner))
 
 
-def _bitonic_sort(y: jax.Array) -> jax.Array:
-    """Sort (n, blk) along axis 0 ascending; n must be a power of two."""
+def _bitonic_sort(y: jax.Array, n_real: int) -> jax.Array:
+    """Sort the first next_pow2(n_real) rows of (n, blk) along axis 0
+    ascending; n is a power of two, at least that height.  The rows from
+    that height on are neither read nor ordered: with n_real <= n / 2 the
+    network's last merge stages would only compare real rows with
+    sentinels, and change nothing."""
     n = y.shape[0]
     # >=2-D iota: 1-D iota does not lower on TPU.
     row = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
     k = 2
-    while k <= n:
+    while k <= next_pow2(n_real):
         ascending = (row & k) == 0
         j = k // 2
         while j >= 1:
@@ -75,12 +78,6 @@ def _bitonic_sort(y: jax.Array) -> jax.Array:
             j //= 2
         k *= 2
     return y
-
-
-def sort_height(n: int) -> int:
-    """Rows of the bitonic network for n workers: a power of two, and at
-    least one full sublane tile (8 rows) so every rotation is whole-tile."""
-    return max(_SUBLANES, next_pow2(n))
 
 
 def _with_sentinels(y: jax.Array, n_real: int) -> jax.Array:
@@ -132,43 +129,53 @@ def _reduce_sorted(ys: jax.Array, f, mode: str, n_real: int) -> jax.Array:
     return total / jnp.maximum((n_real - 2 * f).astype(jnp.float32), 1.0)
 
 
-def _make_kernel(mode: str, mix: bool, n_real: int, f=None):
+def _make_kernel(mode: str, mix: bool, n_real: int, f, *, d: int,
+                 width: int):
     """Kernel body.  ``f=None`` reads the trim count from a leading (1,)
     int32 SMEM operand (the dynamic kernel: one compile serves every
     Byzantine budget of a fleet shape bucket); ``mode="med"`` ignores f.
     ``mix=False`` drops the M operand and the MXU dot entirely (plain
     CWTM/CWMed).  ``n_real`` is the true worker count; any pad rows of
-    the sort become sentinels before the network runs."""
+    the sort become sentinels before the network runs.  The (n, width)
+    grid tile is mixed, sorted and reduced one chunk at a time
+    (``tiling.walk_chunks``), each chunk's columns written to its slice
+    of the output row; columns past d are computed but never written
+    back, so nothing is masked."""
     def kernel(*refs):
         refs = list(refs)
         f_val = f if f is not None else refs.pop(0)[0]
         if mix:
             m_ref, x_ref, o_ref = refs
-        else:
-            x_ref, o_ref = refs
-        x = x_ref[...].astype(jnp.float32)
-        if mix:
             # M is (n_pad, n_real): zero pad rows, so Y's pad rows are 0
             # until the sentinel mask overwrites them.
             m = m_ref[...].astype(jnp.float32)
+        else:
+            x_ref, o_ref = refs
+
+        def chunk(carry, off, w, valid):
+            x = x_ref[:, pl.ds(off, w)].astype(jnp.float32)
             y = jax.lax.dot_general(
                 m, x, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        else:
-            y = x
-        ys = _bitonic_sort(_with_sentinels(y, n_real))
-        o_ref[...] = _reduce_sorted(ys, f_val, mode, n_real)
+                preferred_element_type=jnp.float32) if mix else x
+            ys = _bitonic_sort(_with_sentinels(y, n_real), n_real)
+            o_ref[:, pl.ds(off, w)] = _reduce_sorted(ys, f_val, mode, n_real)
+            return carry
+
+        tiling.walk_chunks(pl.program_id(0), d=d, width=width, body=chunk,
+                           chunk=tiling.chunk_lanes(sort_height(n_real)))
     return kernel
 
 
-def _mixtrim_call(x, m, f, *, mode: str, block_d: int, interpret: bool):
-    """The pallas_call behind both entry points: grid over the d tiles
-    (a ragged last tile is masked by the pipeline, never padded in HBM);
-    M, zero-row-padded to the sort height, is broadcast to every grid
-    step; a traced ``f`` rides in scalar memory."""
+def _mixtrim_call(x, m, f, *, mode: str, block_d: int | None,
+                  interpret: bool):
+    """The pallas_call behind both entry points: grid over wide d tiles
+    (a ragged last tile is never padded in HBM); M, zero-row-padded to
+    the sort height, is broadcast to every grid step; a traced ``f``
+    rides in scalar memory."""
     n, d = x.shape
     n_pad = sort_height(n)
-    in_specs = [pl.BlockSpec((n, block_d), lambda i: (0, i))]
+    w = tiling.block_width(d, block_d, n, x.dtype)
+    in_specs = [pl.BlockSpec((n, w), lambda i: (0, i))]
     operands = [x]
     if m is not None:
         # Zero pad rows: the mix dot then produces the taller stack
@@ -183,10 +190,10 @@ def _mixtrim_call(x, m, f, *, mode: str, block_d: int, interpret: bool):
         in_specs.insert(0, pl.BlockSpec(memory_space=pltpu.SMEM))
         operands.insert(0, jnp.asarray(f, jnp.int32).reshape(1))
     out = pl.pallas_call(
-        _make_kernel(mode, m is not None, n, static_f),
-        grid=(pl.cdiv(d, block_d),),
+        _make_kernel(mode, m is not None, n, static_f, d=d, width=w),
+        grid=(tiling.grid_steps(d, w),),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_d), lambda i: (0, i)),
+        out_specs=pl.BlockSpec((1, w), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         interpret=interpret,
     )(*operands)
@@ -196,13 +203,15 @@ def _mixtrim_call(x, m, f, *, mode: str, block_d: int, interpret: bool):
 @functools.partial(jax.jit,
                    static_argnames=("f", "mode", "block_d", "interpret"))
 def mixtrim_pallas(x: jax.Array, m: jax.Array, *, f: int, mode: str = "trim",
-                   block_d: int = 512, interpret: bool = False) -> jax.Array:
+                   block_d: int | None = None, interpret: bool = False
+                   ) -> jax.Array:
     """Fused (M @ X -> sort -> trim/median) over d tiles.
 
     Args:
       x: (n, d) worker stack, any n >= 1, any d (block_d a multiple of
-        128).  Non-power-of-two n runs the padded sentinel sort (see
-        module docs).
+        128, or None to pick it from x's shape and dtype).
+        Non-power-of-two n runs the padded sentinel sort (see module
+        docs).
       m: (n, n) mixing matrix, or None for plain CWTM/CWMed (the mix dot
         is elided entirely — no identity matmul).
       f: trim count (ignored for mode="med").
@@ -215,7 +224,7 @@ def mixtrim_pallas(x: jax.Array, m: jax.Array, *, f: int, mode: str = "trim",
 
 @functools.partial(jax.jit, static_argnames=("mode", "block_d", "interpret"))
 def mixtrim_dyn_pallas(x: jax.Array, m: jax.Array, f: jax.Array, *,
-                       mode: str = "trim", block_d: int = 512,
+                       mode: str = "trim", block_d: int | None = None,
                        interpret: bool = False) -> jax.Array:
     """Fused mix+trim with a TRACED Byzantine count.
 

@@ -13,28 +13,27 @@ from repro.kernels.mixtrim.ref import mixtrim_dyn_ref, mixtrim_ref
 @functools.partial(jax.jit, static_argnames=("f", "mode", "block_d",
                                              "use_pallas", "interpret"))
 def mixtrim(x: jax.Array, m: jax.Array, *, f: int, mode: str = "trim",
-            block_d: int = 512, use_pallas: bool = True,
+            block_d: int | None = None, use_pallas: bool = True,
             interpret: bool | None = None) -> jax.Array:
     """Fused NNM-mix + coordinate-wise trim/median of a (n, d) stack.
 
-    ``m=None`` elides the mix dot entirely (plain CWTM/CWMed).  Any d: the
-    kernel's ragged last tile is masked in the pipeline, so the stack is
-    never copied to a padded width (a d below ``block_d`` runs as one
-    full-width tile).  Non-power-of-two n runs the padded sentinel bitonic
-    sort (see kernel.py) — the jnp oracle is used only when
-    ``use_pallas=False``.
+    ``m=None`` elides the mix dot entirely (plain CWTM/CWMed).  Any d, in
+    ``block_d``-wide grid tiles (None: the widest that fits VMEM,
+    ``tiling.pick_block_d``): the columns of a ragged last tile past d are
+    never written back, so the stack is never copied to a padded width.
+    Non-power-of-two n runs the padded sentinel bitonic sort (see
+    kernel.py) — the jnp oracle is used only when ``use_pallas=False``.
     """
     if not use_pallas:
         return mixtrim_ref(x, m, f, mode)
-    return mixtrim_pallas(x, m, f=f, mode=mode,
-                          block_d=min(block_d, x.shape[1]),
+    return mixtrim_pallas(x, m, f=f, mode=mode, block_d=block_d,
                           interpret=resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("mode", "block_d", "use_pallas",
                                              "interpret"))
 def mixtrim_dyn(x: jax.Array, m: jax.Array, f: jax.Array, *,
-                mode: str = "trim", block_d: int = 512,
+                mode: str = "trim", block_d: int | None = None,
                 use_pallas: bool = True,
                 interpret: bool | None = None) -> jax.Array:
     """Fused mix+trim with a TRACED trim count (fleet dynamic-f path).
@@ -46,6 +45,5 @@ def mixtrim_dyn(x: jax.Array, m: jax.Array, f: jax.Array, *,
     """
     if not use_pallas:
         return mixtrim_dyn_ref(x, m, f, mode)
-    return mixtrim_dyn_pallas(x, m, f, mode=mode,
-                              block_d=min(block_d, x.shape[1]),
+    return mixtrim_dyn_pallas(x, m, f, mode=mode, block_d=block_d,
                               interpret=resolve_interpret(interpret))

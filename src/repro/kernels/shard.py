@@ -33,6 +33,7 @@ from repro.kernels.combine import combine as _combine_op
 from repro.kernels.gram import gram as _gram_op
 from repro.kernels.mixtrim import mixtrim as _mixtrim_op
 from repro.kernels.mixtrim import mixtrim_dyn as _mixtrim_dyn_op
+from repro.kernels import tiling
 from repro.kernels.target import resolve_interpret
 
 Array = jax.Array
@@ -43,13 +44,19 @@ def axis_size(mesh, axis: str) -> int:
     return mesh.shape[axis]
 
 
-def _resolve(mesh, axis, d, block_d, interpret):
-    """Common per-call plumbing: shard count, local tile width, interpret."""
-    from repro.kernels.dispatch import pick_block_d
-    k = axis_size(mesh, axis)
-    pad = (-d) % k
-    bd = block_d if block_d is not None else pick_block_d((d + pad) // k)
-    return k, pad, bd, resolve_interpret(interpret)
+def local_width(d: int, mesh, axis: str) -> int:
+    """Columns of one shard of a d-wide stack split over ``axis`` (d is
+    zero-padded to a multiple of the shard count)."""
+    return -(-d // axis_size(mesh, axis))
+
+
+def _resolve(mesh, axis, x, block_d, interpret):
+    """Common per-call plumbing: column padding, local tile width,
+    interpret."""
+    n, d = x.shape
+    pad = (-d) % axis_size(mesh, axis)
+    bd = tiling.block_width(local_width(d, mesh, axis), block_d, n, x.dtype)
+    return pad, bd, resolve_interpret(interpret)
 
 
 def _pad_cols(x: Array, pad: int) -> Array:
@@ -62,8 +69,7 @@ def sharded_gram(x: Array, *, mesh: jax.sharding.Mesh, axis: str,
                  block_d: Optional[int] = None,
                  interpret: Optional[bool] = None) -> Array:
     """(n, D) -> replicated (n, n) fp32 Gram via per-shard kernels + psum."""
-    _, pad, bd, interpret = _resolve(mesh, axis, x.shape[1], block_d,
-                                     interpret)
+    pad, bd, interpret = _resolve(mesh, axis, x, block_d, interpret)
 
     def body(xl):
         g = _gram_op(xl, block_d=bd, use_pallas=True, interpret=interpret)
@@ -82,7 +88,7 @@ def sharded_combine(x: Array, coeff: Array, *, mesh: jax.sharding.Mesh,
     Per-column math: each shard's slice of the output is exactly what the
     single-device combine kernel computes for those columns."""
     d = x.shape[1]
-    _, pad, bd, interpret = _resolve(mesh, axis, d, block_d, interpret)
+    pad, bd, interpret = _resolve(mesh, axis, x, block_d, interpret)
 
     def body(xl, cl):
         return _combine_op(xl, cl, block_d=bd, use_pallas=True,
@@ -102,9 +108,9 @@ def sharded_mixtrim(x: Array, m: Optional[Array], f, *, mode: str,
     ``m`` (replicated) and the traced ``f`` (dyn=True) ride into the
     shard_map as replicated operands; the padded sentinel bitonic sort
     inside the kernel handles any n.  The mixed stack only ever exists as
-    (n, BLK_D) VMEM tiles on each device."""
+    (n, W) VMEM tiles on each device."""
     d = x.shape[1]
-    _, pad, bd, interpret = _resolve(mesh, axis, d, block_d, interpret)
+    pad, bd, interpret = _resolve(mesh, axis, x, block_d, interpret)
     has_m = m is not None
     f_static = 0 if mode == "med" else (f if not dyn else None)
 
@@ -163,8 +169,6 @@ def sharded_bucketgram(x: Array, bmat: Array, *, mesh: jax.sharding.Mesh,
     pad_d = (-d) % kd
     pad_n = (-n) % kw
     interpret = resolve_interpret(interpret)
-    from repro.kernels.dispatch import pick_block_d
-    bd = block_d if block_d is not None else pick_block_d((d + pad_d) // kd)
     bn = block_n if block_n is not None else _pick_block_n((n + pad_n) // kw)
     xw = _pad_cols(x, pad_d)
     if pad_n:
@@ -176,7 +180,7 @@ def sharded_bucketgram(x: Array, bmat: Array, *, mesh: jax.sharding.Mesh,
     if worker_axis is None:
         def body1(xl, bl):
             y, g = _bucketgram_op(xl, bl, with_gram=with_gram, block_n=bn,
-                                  block_d=bd, interpret=interpret)
+                                  block_d=block_d, interpret=interpret)
             if not with_gram:
                 return (y,)
             return y, jax.lax.psum(g, model_axis)
@@ -195,11 +199,12 @@ def sharded_bucketgram(x: Array, bmat: Array, *, mesh: jax.sharding.Mesh,
         # over the worker shards completes every bucket regardless of how
         # the permutation scattered its members across devices.
         y_part, _ = _bucketgram_op(xl, bl, with_gram=False, block_n=bn,
-                                   block_d=bd, interpret=interpret)
+                                   block_d=block_d, interpret=interpret)
         y = jax.lax.psum(y_part, worker_axis)
         if not with_gram:
             return (y,)
-        g = _gram_op(y, block_d=bd, use_pallas=True, interpret=interpret)
+        g = _gram_op(y, block_d=block_d, use_pallas=True,
+                     interpret=interpret)
         return y, jax.lax.psum(g, model_axis)
 
     fn = jax.shard_map(body2, mesh=mesh,

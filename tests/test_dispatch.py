@@ -19,6 +19,7 @@ import pytest
 from repro.core import AggregatorSpec
 from repro.core import robust as robust_lib
 from repro.kernels import dispatch as kdispatch
+from repro.kernels import tiling
 
 ALL_RULES = ("average", "krum", "multikrum", "gm", "mda",
              "cwtm", "cwmed", "meamed")
@@ -273,12 +274,62 @@ def test_flatten_roundtrip_preserves_layout():
         rtol=1e-6, atol=1e-6)
 
 
-def test_pick_block_d():
-    assert kdispatch.pick_block_d(8192) == 512      # wide: capped
-    assert kdispatch.pick_block_d(512) == 512
-    assert kdispatch.pick_block_d(100) == 128       # narrow: one 128 tile
-    assert kdispatch.pick_block_d(300) == 384       # round up to 128x
-    assert kdispatch.pick_block_d(1) == 128
+#: Narrow (one tile below a chunk), ragged (several chunks, d not a
+#: multiple of 128) and wide (a large SmolLM-360M leaf: many grid steps).
+PICK_WIDTHS = (300, 5000, 78_643_200)
+
+
+@pytest.mark.parametrize("n", [4, 17, 256])
+@pytest.mark.parametrize("d", PICK_WIDTHS)
+def test_pick_block_d(n, d):
+    """The grid tile comes from (n, d, dtype) and the VMEM budget alone:
+    a multiple of 128, at most d rounded up to 128 lanes, inside the
+    budget, a multiple of the chunk whenever d takes several grid steps,
+    and never narrower for fewer workers."""
+    w = kdispatch.pick_block_d(n, d)
+    assert w % 128 == 0 and 128 <= w <= -(-d // 128) * 128
+    assert tiling.vmem_bytes(n, w) <= tiling.VMEM_BUDGET
+    if w < d:
+        assert w % tiling.CHUNK == 0
+    for fewer in (m for m in (4, 17, 256) if m < n):
+        assert kdispatch.pick_block_d(fewer, d) >= w
+    if d == PICK_WIDTHS[-1]:
+        # a wide stack gets a wide tile, shrinking as n grows: no longer
+        # the one 512-lane cap for every n
+        assert w > tiling.CHUNK
+        for fewer in (m for m in (4, 17, 256) if m < n):
+            assert kdispatch.pick_block_d(fewer, d) > w
+    # the wide tile of a narrow dtype is never narrower
+    assert kdispatch.pick_block_d(n, d, jnp.bfloat16) >= w
+
+
+def test_dispatch_history_records_tile_and_grid_steps():
+    """Each kernel decision carries the grid tile W its leaf streamed with
+    and the number of grid steps: leaves of one tile share a decision,
+    leaves of another tile get their own."""
+    n = 4
+    tree = {"wide": jnp.ones((n, 2600), jnp.float32),
+            "narrow": jnp.ones((n, 300), jnp.float32)}
+    spec = AggregatorSpec(rule="cwtm", f=1, pre="nnm", backend="pallas")
+    robust_lib.robust_aggregate(tree, spec)
+    rec = kdispatch.dispatch_history(1)[0]
+    tiles = {(d.primitive, d.block_d, d.grid_steps) for d in rec.decisions
+             if d.primitive in ("gram", "mixtrim")}
+    want = set()
+    for prim in ("gram", "mixtrim"):
+        for d in (2600, 300):
+            w = min(kdispatch.pick_block_d(n, d), d)
+            want.add((prim, w, -(-d // w)))
+    assert tiles == want, rec.describe()
+    assert "W=2600 steps=1" in rec.describe()
+    x = jnp.ones((n, 2600), jnp.float32)
+    kdispatch.open_record(requested="pallas", backend="pallas", rule="cwtm",
+                          pre=None)
+    kdispatch.dispatch_gram(x, backend="pallas", block_d=1024)
+    kdispatch.dispatch_gram(x, backend="xla")
+    got = [(d.used.split("-")[0], d.block_d, d.grid_steps)
+           for d in kdispatch.last_dispatch().decisions]
+    assert got == [("pallas", 1024, 3), ("xla", None, None)]
 
 
 # ---------------------------------------------------------------------------
@@ -305,8 +356,9 @@ def test_fused_mixtrim_eliminates_mixed_stack():
 def test_single_device_pallas_streams_leaves_in_place():
     """backend="pallas" runs the kernels on each leaf's (n, d_i) view: no
     concatenated (n, D) copy of the stack (at full model width that copy
-    does not fit one chip), each primitive recorded once, and the result
-    equal to xla's.  The mesh backends still stream one flat buffer."""
+    does not fit one chip), each primitive recorded once per leaf tile
+    (here each leaf is one tile of its own width), and the result equal to
+    xla's.  The mesh backends still stream one flat buffer."""
     tree = _tree(19)
     width = 37 + 15 + 1
     for rule in ("cwtm", "gm"):
@@ -315,8 +367,11 @@ def test_single_device_pallas_streams_leaves_in_place():
             lambda t: robust_lib.robust_aggregate(t, spec), tree,
             n=16, width=width, primitives=("concatenate",)) == 0
         got = robust_lib.robust_aggregate(tree, spec)
-        prims = [d.primitive for d in kdispatch.last_dispatch().decisions]
-        assert sorted(prims) == sorted(set(prims)), prims
+        decs = kdispatch.last_dispatch().decisions
+        keys = [(d.primitive, d.block_d, d.grid_steps) for d in decs]
+        assert sorted(keys, key=str) == sorted(set(keys), key=str), keys
+        assert {d.block_d for d in decs if d.primitive == "gram"} \
+            == {37, 15, 1}, keys
         ref = robust_lib.robust_aggregate(
             tree, AggregatorSpec(rule=rule, f=3, pre="nnm", backend="xla"))
         _assert_trees_close(got, ref, rtol=1e-5, atol=1e-5, err_msg=rule)

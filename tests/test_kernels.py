@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import tiling
 from repro.kernels.combine import combine, combine_ref
 from repro.kernels.gram import gram, gram_batched, gram_batched_ref, gram_ref
 from repro.kernels.mixtrim import (
@@ -132,6 +133,69 @@ def test_mixtrim_dyn_bitexact_vs_ref():
         got = np.asarray(mixtrim_dyn(x, m, jnp.int32(f), block_d=128))
         want = np.asarray(mixtrim_dyn_ref(x, m, jnp.int32(f)))
         np.testing.assert_array_equal(got, want)
+
+
+# ---------------------------------------------------------------------------
+# Wide grid tiles: several 512-lane chunks per tile, a ragged last chunk.
+# ---------------------------------------------------------------------------
+
+#: d = 1300 in tiles of 1024 (two grid steps, the last holding one ragged
+#: chunk) or of the picked width (one 1408-wide step: two whole chunks and
+#: a ragged 384-lane tail).
+WIDE_D = 1300
+WIDE_TILES = [None, 1024]
+
+
+def _wide_stack(n):
+    x = jax.random.normal(jax.random.PRNGKey(30 + n), (n, WIDE_D))
+    m = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(31), (n, n)),
+                       axis=-1)
+    return x, m, max(1, (n - 1) // 4)
+
+
+@pytest.mark.parametrize("n", [4, 17])
+@pytest.mark.parametrize("block_d", WIDE_TILES)
+def test_wide_tile_gram_keeps_chunk_order(n, block_d):
+    """A wide tile sums the same 512-lane chunks in the same order as a
+    grid of 512-lane tiles, so the Gram is bit for bit the narrow one;
+    every lane of the batched kernel is bit for bit the solo kernel."""
+    assert tiling.CHUNK == 512
+    x, _, _ = _wide_stack(n)
+    got = np.asarray(gram(x, block_d=block_d))
+    np.testing.assert_array_equal(got, np.asarray(gram(x, block_d=512)))
+    np.testing.assert_allclose(got, np.asarray(gram_ref(x)),
+                               rtol=1e-4, atol=1e-3)
+    lanes = jnp.stack([x, 2 * x, -x])
+    got_b = np.asarray(gram_batched(lanes, block_d=block_d))
+    np.testing.assert_array_equal(
+        got_b, np.asarray(gram_batched(lanes, block_d=512)))
+    np.testing.assert_array_equal(got_b[0], got)
+
+
+@pytest.mark.parametrize("n", [4, 17])
+@pytest.mark.parametrize("block_d", WIDE_TILES)
+def test_wide_tile_combine_bitexact(n, block_d):
+    x, m, _ = _wide_stack(n)
+    got = np.asarray(combine(x, m[0], block_d=block_d))
+    np.testing.assert_array_equal(got, np.asarray(combine_ref(x, m[0])))
+
+
+@pytest.mark.parametrize("n", [4, 17])
+@pytest.mark.parametrize("block_d", WIDE_TILES)
+@pytest.mark.parametrize("mode", ["trim", "med"])
+@pytest.mark.parametrize("mix", [True, False])
+def test_wide_tile_mixtrim_bitexact(n, block_d, mode, mix):
+    """Static and dynamic f, with and without the mix dot: per-column
+    math, so any tile is bit for bit the oracle."""
+    x, m, f = _wide_stack(n)
+    mm = m if mix else None
+    got = np.asarray(mixtrim(x, mm, f=f, mode=mode, block_d=block_d))
+    np.testing.assert_array_equal(got,
+                                  np.asarray(mixtrim_ref(x, mm, f, mode)))
+    got_dyn = np.asarray(mixtrim_dyn(x, mm, jnp.int32(f), mode=mode,
+                                     block_d=block_d))
+    np.testing.assert_array_equal(
+        got_dyn, np.asarray(mixtrim_dyn_ref(x, mm, jnp.int32(f), mode)))
 
 
 # ---------------------------------------------------------------------------

@@ -1,8 +1,10 @@
 """The aggregation kernels compile for a TPU v5e (no chip needed).
 
 Each case lowers a kernel of the hot path with ``interpret=False`` for a
-described ``v5e:2x2`` topology at a width of millions of coordinates, so
-Mosaic refuses here what it would refuse on the chip (unsupported
+described ``v5e:2x2`` topology at a width of millions of coordinates,
+through the grid tile the kernels pick for that shape when given no
+``block_d`` (``tiling.pick_block_d``: tens of thousands of lanes at n=4),
+so Mosaic refuses here what it would refuse on the chip (unsupported
 primitives, unaligned slices, too much VMEM), and checks that the compiled
 program holds the kernel (``tpu_custom_call``).  Nothing runs.
 
@@ -22,6 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import shard as shardlib
+from repro.kernels import tiling
 from repro.kernels.bucketgram import bucket_means_gram
 from repro.kernels.combine import combine
 from repro.kernels.gram import gram, gram_batched
@@ -75,6 +78,8 @@ KERNELS = {
 @pytest.mark.parametrize("n", [4, 17, 256])
 @pytest.mark.parametrize("kernel", sorted(KERNELS))
 def test_kernel_compiles_for_v5e(one_chip, kernel, n):
+    # the picked tile, several chunks wide, is what compiles below
+    assert tiling.pick_block_d(n, D) > tiling.CHUNK
     f = (n - 1) // 4
     x = jax.ShapeDtypeStruct((n, D), jnp.float32, sharding=one_chip)
     m = jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=one_chip)
