@@ -16,6 +16,7 @@ _ARCH_MODULES = {
     "zamba2-2.7b": "repro.configs.zamba2_2p7b",
     "whisper-base": "repro.configs.whisper_base",
     "rwkv6-3b": "repro.configs.rwkv6_3b",
+    "mellum2-12b-a2.5b": "repro.configs.mellum2_12b_a2p5b",
 }
 
 ARCH_IDS = tuple(_ARCH_MODULES)
@@ -27,9 +28,20 @@ def get_config(arch: str) -> ModelConfig:
     return importlib.import_module(_ARCH_MODULES[arch]).CONFIG
 
 
+def chip_config(arch: str) -> ModelConfig:
+    """The config one chip runs at published widths: the arch's own, or,
+    where the module states one (``CHIP_SHARE``), one chip's share of a
+    deployment that splits the model over several."""
+    cfg = get_config(arch)
+    return getattr(importlib.import_module(_ARCH_MODULES[arch]),
+                   "CHIP_SHARE", cfg)
+
+
 def reduced_config(arch: str) -> ModelConfig:
-    """CPU-smoke variant of the same family: <=2 layers, d_model<=512,
-    <=4 experts, tiny vocab.  Exercises every code path of the full arch."""
+    """CPU-smoke variant of the same family: <=2 layers (one period where
+    layer types differ), d_model<=512, <=4 experts (2 held where the arch
+    holds a share), tiny vocab.  Exercises every code path of the full
+    arch."""
     cfg = get_config(arch)
     kw = dict(
         num_layers=2,
@@ -44,6 +56,11 @@ def reduced_config(arch: str) -> ModelConfig:
         kw.update(num_experts=4, moe_dense_ff=64 if cfg.moe_dense_ff else 0)
     if cfg.sliding_window:
         kw.update(sliding_window=32)
+    if cfg.layer_types:
+        # One period; a window shorter than the smoke tests' 32-token rows.
+        kw.update(num_layers=len(cfg.layer_types), sliding_window=8)
+    if chip_config(arch).experts_held:
+        kw.update(experts_per_token=2, experts_held=2)
     if cfg.family in ("ssm", "hybrid"):
         kw.update(ssm_heads=4, ssm_head_dim=16, ssm_state=16, ssm_chunk=16)
     if cfg.family == "hybrid":
@@ -57,5 +74,5 @@ def reduced_config(arch: str) -> ModelConfig:
     return cfg.replace(**kw)
 
 
-__all__ = ["ARCH_IDS", "get_config", "reduced_config", "InputShape",
+__all__ = ["ARCH_IDS", "get_config", "chip_config", "reduced_config", "InputShape",
            "ModelConfig", "SHAPES"]

@@ -27,11 +27,22 @@ class ModelConfig:
     sliding_window: Optional[int] = None
     tie_embeddings: bool = False
     # --- MoE ---
-    num_experts: int = 0
+    num_experts: int = 0             # experts the router scores
     experts_per_token: int = 2
+    # Experts 0 .. experts_held - 1 are held and computed here (one
+    # device's share under expert parallelism); 0 = all num_experts.
+    experts_held: int = 0
     moe_dense_ff: int = 0            # parallel dense residual FFN (arctic)
-    capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # --- layer types (mellum2) ---
+    # A period of attention kinds, "sliding" (causal within
+    # sliding_window) or "full", repeated over the depth; () = every layer
+    # alike (sliding_window, if set, on all of them).
+    layer_types: tuple = ()
+    # YaRN rotary scaling on "full" layers (transformers' rope_type
+    # "yarn"): the factor over the original context; 0 = none.
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 0
     # --- SSM (mamba2 / rwkv6) ---
     ssm_state: int = 0               # N (mamba2 state) or unused for rwkv
     ssm_heads: int = 0
@@ -60,6 +71,18 @@ class ModelConfig:
                                      # XLA cost analysis counts while-loop
                                      # bodies once — see launch/dryrun.py)
     source: str = ""                 # citation bracket from the assignment
+
+    def __post_init__(self):
+        # A configuration file gives layer_types as a JSON list.
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.layer_types:
+            assert set(self.layer_types) <= {"sliding", "full"}, self.layer_types
+            assert self.num_layers % len(self.layer_types) == 0, \
+                (self.num_layers, self.layer_types)
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)

@@ -172,8 +172,7 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
                cost_probe: bool = True, verbose: bool = True,
                transport: str | None = None, sketch: int = 0,
                pad_kv: bool = False, gqa_einsum: bool = False,
-               kappa_hat: bool = True, capacity: float | None = None,
-               variant: str = "baseline") -> dict:
+               kappa_hat: bool = True, variant: str = "baseline") -> dict:
     reason = lc.skip_reason(arch, shape_name)
     if reason:
         return {"arch": arch, "shape": shape_name,
@@ -183,8 +182,6 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     cfg = lc.launch_config(arch, shape_name)
     if gqa_einsum:
         cfg = cfg.replace(gqa_einsum=True)
-    if capacity is not None:
-        cfg = cfg.replace(capacity_factor=capacity)
     if seq_par is None:
         # §Perf finding: sequence-parallel residual stream helps only the
         # FSDP giants (saved-activation pressure); it costs ~+10% memory

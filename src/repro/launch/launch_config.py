@@ -18,7 +18,7 @@ LONG_CONTEXT_WINDOW = 4096
 def expert_param_count(cfg: ModelConfig) -> float:
     if not cfg.num_experts:
         return 0.0
-    return 3.0 * cfg.num_experts * cfg.d_model * cfg.d_ff * cfg.num_layers
+    return 3.0 * cfg.held_experts * cfg.d_model * cfg.d_ff * cfg.num_layers
 
 
 def wants_fsdp_experts(cfg: ModelConfig) -> bool:

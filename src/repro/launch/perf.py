@@ -56,11 +56,6 @@ PAIRS = {
         ("bf16+sketch512",
          "drop the gram pass (sketch) + bf16 the mix pass",
          dict(transport="bf16", sketch=512, kappa_hat=False)),
-        ("capacity1.0",
-         "expert dispatch buffers / all-to-all bytes scale with the "
-         "capacity factor; 1.25 -> 1.0 trims 20% of the MoE path at the "
-         "cost of more token dropping (predicted collective ~ -10%)",
-         dict(capacity=1.0, kappa_hat=False)),
     ]),
     # Worst memory-term decode: replicated kv heads force the model axis to
     # shard the cache SEQ dim; the ring-slot scatter then triggers XLA's

@@ -3,13 +3,18 @@
 CPU-scale (default): trains a reduced variant of any assigned arch with the
 full robust pipeline (Dirichlet-heterogeneous synthetic LM data, D-SHB +
 NNM+agg, Byzantine attack simulation, checkpointing, kappa-hat tracking).
-``--full`` runs the published widths and needs a TPU.
+``--full`` runs the published widths and needs a TPU: the whole model, or,
+for an arch whose config module states one chip's share of a deployment
+(``repro.configs.chip_config``), that share.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.train --arch smollm-360m \
       --steps 200 --workers 8 --byz 2 --attack alie --agg nnm+cwtm
   PYTHONPATH=src python -m repro.launch.train --arch smollm-360m --full \
       --workers 4 --byz 1 --steps 3     # published widths, one TPU chip
+  PYTHONPATH=src python -m repro.launch.train --arch mellum2-12b-a2.5b \
+      --full --workers 4 --byz 1 --batch 1 --seq 512 --steps 3
+                                        # one chip's share of 8-way EP
 """
 from __future__ import annotations
 
@@ -21,8 +26,9 @@ from typing import Any, Iterator, Optional, Sequence
 import jax
 import numpy as np
 
+from repro import obs
 from repro.checkpoint import save_checkpoint
-from repro.configs import ARCH_IDS, get_config, reduced_config
+from repro.configs import ARCH_IDS, chip_config, reduced_config
 from repro.configs.base import ModelConfig
 from repro.core.types import AggregatorSpec
 from repro.data import build_heterogeneous, make_lm_corpus, worker_batches
@@ -43,7 +49,8 @@ def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m", choices=ARCH_IDS)
     ap.add_argument("--full", action="store_true",
-                    help="use the full-scale config (needs a TPU)")
+                    help="use the full-scale config, or one chip's share of "
+                         "it where the arch states one (needs a TPU)")
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--workers", type=int, default=8)
     ap.add_argument("--byz", type=int, default=2)
@@ -95,7 +102,7 @@ def setup(args: argparse.Namespace, *, backend: str = "auto") -> Run:
             f"--full runs {args.arch} at its published widths: a TPU is "
             f"required (found {jax.default_backend()}); drop --full for the "
             f"reduced CPU config")
-    cfg = get_config(args.arch) if args.full else reduced_config(args.arch)
+    cfg = chip_config(args.arch) if args.full else reduced_config(args.arch)
 
     # Heterogeneous LM data: Dirichlet over topics.
     seqs, topics = make_lm_corpus(n_tokens=400_000, vocab=cfg.vocab_size,
@@ -133,6 +140,22 @@ def setup(args: argparse.Namespace, *, backend: str = "auto") -> Run:
                batches=batches())
 
 
+def count_routing(metrics: dict) -> None:
+    """Add one step's routing counts (expert layers only) to the
+    ``obs.runtime`` counters: ``moe.routed_pairs`` sums the (token, held
+    expert) pairs over steps; ``moe.expert_load_max`` / ``_min`` follow
+    the largest and smallest load one held expert took in any step."""
+    if "routed_pairs" not in metrics:
+        return
+    obs.inc("moe.routed_pairs", float(metrics["routed_pairs"]))
+    seen = obs.counters()
+    for name, pick in (("expert_load_max", max), ("expert_load_min", min)):
+        key, value = f"moe.{name}", float(metrics[name])
+        if key in seen:
+            value = pick(value, seen[key])
+        obs.inc(key, value - seen.get(key, 0.0))
+
+
 def main(argv: Optional[Sequence[str]] = None) -> dict:
     """Train; returns the run's record: config, parameter count, compile
     seconds, the compiled step's memory analysis, per-step metrics and
@@ -161,6 +184,7 @@ def main(argv: Optional[Sequence[str]] = None) -> dict:
         t0 = time.perf_counter()
         state, metrics = step_fn(state, batch, sub)
         metrics = jax.device_get(jax.block_until_ready((state, metrics))[1])
+        count_routing(metrics)
         row = {"step": t + 1, "seconds": time.perf_counter() - t0,
                **{k: float(v) for k, v in metrics.items()
                   if np.ndim(v) == 0}}

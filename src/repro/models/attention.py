@@ -70,14 +70,29 @@ def _repeat_kv(k: Array, hq: int) -> Array:
     return jnp.repeat(k, hq // hkv, axis=-2)
 
 
+def layer_window(cfg: ModelConfig, kind: Optional[str]) -> Optional[int]:
+    """The causal window of a layer of type ``kind`` (None: untyped, the
+    config's ``sliding_window``); None for no window."""
+    return None if kind == "full" else cfg.sliding_window
+
+
+def _rope(x: Array, positions: Array, cfg: ModelConfig,
+          kind: Optional[str]) -> Array:
+    freqs, scale = common.rope_table(cfg, kind)
+    return apply_rope(x, positions, cfg.rope_theta, freqs=freqs, scale=scale)
+
+
 def attention(p: dict, x: Array, cfg: ModelConfig, *,
               causal: bool = True, positions: Optional[Array] = None,
               use_rope: bool = True,
-              kv_override: Optional[tuple[Array, Array]] = None) -> Array:
+              kv_override: Optional[tuple[Array, Array]] = None,
+              kind: Optional[str] = None) -> Array:
     """Full-sequence attention.  x: (B, S, d) -> (B, S, d).
 
     ``kv_override`` supplies external (k, v) head tensors for cross
     attention (whisper decoder); causal/sliding masks then do not apply.
+    ``kind`` is the layer's type in ``cfg.layer_types`` ("sliding" or
+    "full"): its window and its rotary table.
     """
     b, s, _ = x.shape
     hq, _ = resolved_heads(cfg)
@@ -90,8 +105,8 @@ def attention(p: dict, x: Array, cfg: ModelConfig, *,
     if cross:
         k, v = kv_override
     elif use_rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q = _rope(q, positions, cfg, kind)
+        k = _rope(k, positions, cfg, kind)
     k = _repeat_kv(k, hq)
     v = _repeat_kv(v, hq)
     q = constrain(q, "batch", None, "heads", None)
@@ -104,8 +119,9 @@ def attention(p: dict, x: Array, cfg: ModelConfig, *,
         qi = jnp.arange(s)[:, None]
         kj = jnp.arange(s)[None, :]
         mask = qi >= kj if causal else jnp.ones((s, s), bool)
-        if cfg.sliding_window and causal:
-            mask = mask & (qi - kj < cfg.sliding_window)
+        window = layer_window(cfg, kind)
+        if window and causal:
+            mask = mask & (qi - kj < window)
         logits = jnp.where(mask[None, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -127,11 +143,13 @@ def cache_desc(cfg: ModelConfig, layers: int, batch: int, max_seq: int) -> dict:
       seq with partial-reduce collectives);
     * batch == 1 long-context decode additionally spreads seq over the
       data axes (its only use for a single request).
-    Sliding-window archs cache only the window (ring buffer).
+    Sliding-window archs cache only the window (ring buffer); where layer
+    types differ, every layer caches the whole span.
     """
     ctx = common.get_mesh_axes()
     kv_sharded = bool(ctx and ctx.shard_kv and ctx.model_par > 1)
-    span = min(max_seq, cfg.sliding_window) if cfg.sliding_window else max_seq
+    span = min(max_seq, cfg.sliding_window) \
+        if cfg.sliding_window and not cfg.layer_types else max_seq
     if batch == 1:
         b_axis = None
         seq_axis = "seq_shard" if kv_sharded else "seq_both"
@@ -157,9 +175,12 @@ def hkv_of(cfg: ModelConfig) -> int:
 def decode_attention(p: dict, x: Array, cache_k: Array, cache_v: Array,
                      pos: Array, cfg: ModelConfig, *,
                      use_rope: bool = True,
-                     kv_override: Optional[tuple[Array, Array]] = None):
+                     kv_override: Optional[tuple[Array, Array]] = None,
+                     kind: Optional[str] = None):
     """Single-token decode.  x: (B, 1, d); cache_{k,v}: (B, span, hkv, hd);
     pos: scalar current position.  Returns (out (B,1,d), new_k, new_v).
+    A typed layer (``kind``) indexes its cache by position and masks its
+    own window.
     """
     b = x.shape[0]
     hq, hkv = resolved_heads(cfg)
@@ -174,17 +195,21 @@ def decode_attention(p: dict, x: Array, cache_k: Array, cache_v: Array,
     else:
         if use_rope:
             posb = jnp.broadcast_to(pos, (b, 1))
-            q = apply_rope(q, posb, cfg.rope_theta)
-            k = apply_rope(k, posb, cfg.rope_theta)
+            q = _rope(q, posb, cfg, kind)
+            k = _rope(k, posb, cfg, kind)
         # Sliding-window caches are rings; full caches index by position.
-        slot = pos % span if cfg.sliding_window else pos
+        ring = cfg.sliding_window and kind is None
+        window = layer_window(cfg, kind)
+        slot = pos % span if ring else pos
         cache_k = cache_k.at[:, slot].set(k[:, 0])
         cache_v = cache_v.at[:, slot].set(v[:, 0])
         ck, cv = cache_k, cache_v
         idx = jnp.arange(span)
         valid = idx <= slot
-        if cfg.sliding_window:
+        if ring:
             valid = valid | (pos >= span)   # ring full: every slot is live
+        elif window:
+            valid = valid & (pos - idx < window)
 
     scale = hd ** -0.5
     if cfg.gqa_einsum and ck.shape[-2] != hq:

@@ -227,13 +227,56 @@ def rope_freqs(head_dim: int, theta: float = 1e4) -> Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: Array, positions: Array, theta: float = 1e4) -> Array:
-    """Rotary embedding.  x: (..., seq, heads, head_dim); positions: (..., seq)."""
+#: YaRN's correction range, in rotations over the original context
+#: (transformers' defaults, and Mellum2's published values).
+YARN_BETA_FAST, YARN_BETA_SLOW = 32.0, 1.0
+
+
+def yarn_freqs(head_dim: int, theta: float, factor: float,
+               original: int) -> Array:
+    """YaRN's rotary frequencies (transformers' ``_compute_yarn_parameters``):
+    interpolated by ``factor`` below the correction range, extrapolated
+    above it, and a linear ramp between, over the ``original`` context."""
+    def corr_dim(rotations):
+        return (head_dim * math.log(original / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(corr_dim(YARN_BETA_FAST)), 0)
+    high = min(math.ceil(corr_dim(YARN_BETA_SLOW)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    extra = 1 - ramp                       # share kept unscaled
+    pos = theta ** (np.arange(0, head_dim, 2, dtype=np.float32) / head_dim)
+    freqs = (1 / (factor * pos)) * (1 - extra) + (1 / pos) * extra
+    return jnp.asarray(freqs, jnp.float32)
+
+
+def rope_table(cfg, kind: Optional[str]) -> tuple[Optional[Array], float]:
+    """(frequencies, cos/sin scale) of a layer of type ``kind``: YaRN's
+    table and attention factor 0.1 ln(factor) + 1 on "full" layers of a
+    config that sets ``yarn_factor``, else (None, 1.0), the plain table of
+    ``rope_theta``."""
+    if kind != "full" or not cfg.yarn_factor:
+        return None, 1.0
+    freqs = yarn_freqs(cfg.head_dim, cfg.rope_theta, cfg.yarn_factor,
+                       cfg.yarn_original_max_position)
+    return freqs, 0.1 * math.log(cfg.yarn_factor) + 1
+
+
+def apply_rope(x: Array, positions: Array, theta: float = 1e4, *,
+               freqs: Optional[Array] = None, scale: float = 1.0) -> Array:
+    """Rotary embedding.  x: (..., seq, heads, head_dim); positions: (..., seq).
+    ``freqs`` replaces the table of ``theta``; ``scale`` multiplies cos
+    and sin (YaRN's attention factor)."""
     head_dim = x.shape[-1]
-    freqs = rope_freqs(head_dim, theta)
+    if freqs is None:
+        freqs = rope_freqs(head_dim, theta)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., seq, hd/2)
     cos = jnp.cos(angles)[..., None, :]
     sin = jnp.sin(angles)[..., None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

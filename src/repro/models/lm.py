@@ -23,6 +23,12 @@ def _padded_vocab(cfg: ModelConfig) -> int:
     return pad_to(cfg.vocab_size, 128)
 
 
+def _by_period(tree: PyTree, period: int) -> PyTree:
+    """Layer-stacked leaves (L, ...) -> (L / period, period, ...)."""
+    return jax.tree_util.tree_map(
+        lambda a: a.reshape((a.shape[0] // period, period) + a.shape[1:]), tree)
+
+
 def _norm_desc(cfg: ModelConfig, layers: int, n: int = 1):
     L = (layers,) if layers else ()
     lax = ("layers",) if layers else ()
@@ -119,32 +125,58 @@ class DecoderLM:
     def _maybe_remat(self, fn):
         return jax.checkpoint(fn) if self.cfg.remat else fn
 
-    def _run_blocks(self, params, x: Array) -> tuple[Array, Array]:
+    def _run_blocks(self, params, x: Array) -> tuple[Array, Array, dict]:
+        """(hidden states, aux loss, the expert layers' routing counts:
+        empty where the family has none)."""
         cfg = self.cfg
         fam = cfg.family
         aux0 = jnp.zeros((), jnp.float32)
 
         if fam in ("dense", "moe", "vlm"):
-            def block(h, p):
-                h = self._sp(h)
-                a = attention.attention(p["attn"], rms_norm(h, p["ln0"], cfg.norm_eps), cfg)
-                h = h + a
-                h = self._sp(h)
-                if fam == "moe":
-                    f, aux_l = moe.moe_block(p["moe"], rms_norm(h, p["ln1"], cfg.norm_eps), cfg)
-                else:
-                    f = mlp.swiglu(p["mlp"], rms_norm(h, p["ln1"], cfg.norm_eps))
-                    aux_l = jnp.zeros((), jnp.float32)
-                return self._sp(h + f), aux_l
-            block = self._maybe_remat(block)
+            def make_block(kind):
+                def block(h, p):
+                    h = self._sp(h)
+                    a = attention.attention(
+                        p["attn"], rms_norm(h, p["ln0"], cfg.norm_eps), cfg,
+                        kind=kind)
+                    h = h + a
+                    h = self._sp(h)
+                    if fam == "moe":
+                        f, aux_l, stats = moe.moe_block(
+                            p["moe"], rms_norm(h, p["ln1"], cfg.norm_eps), cfg)
+                    else:
+                        f = mlp.swiglu(p["mlp"], rms_norm(h, p["ln1"], cfg.norm_eps))
+                        aux_l, stats = jnp.zeros((), jnp.float32), {}
+                    return self._sp(h + f), aux_l, stats
+                return self._maybe_remat(block)
 
-            def body(carry, p):
-                h, aux = carry
-                h, aux_l = block(h, p)
-                return (h, aux + aux_l), None
-            (x, aux), _ = jax.lax.scan(body, (x, aux0), params["blocks"],
-                                       unroll=cfg.scan_unroll)
-            return x, aux
+            def apply(carry, block, p):
+                h, aux, stats = carry
+                h, aux_l, stats_l = block(h, p)
+                if stats:
+                    stats_l = moe.merge_stats(stats, stats_l)
+                return h, aux + aux_l, stats_l
+
+            stats0 = moe.zero_stats() if fam == "moe" else {}
+            if cfg.layer_types:
+                # Scan over periods; each period's layers unrolled with
+                # their own window and rotary table.
+                blocks = [make_block(kind) for kind in cfg.layer_types]
+                xs = _by_period(params["blocks"], len(blocks))
+
+                def body(carry, pg):
+                    for i, block in enumerate(blocks):
+                        carry = apply(carry, block,
+                                      jax.tree_util.tree_map(lambda a: a[i], pg))
+                    return carry, None
+            else:
+                block, xs = make_block(None), params["blocks"]
+
+                def body(carry, p):
+                    return apply(carry, block, p), None
+            (x, aux, stats), _ = jax.lax.scan(body, (x, aux0, stats0), xs,
+                                              unroll=cfg.scan_unroll)
+            return x, aux, stats
 
         if fam == "ssm":
             def block(h, p):
@@ -160,7 +192,7 @@ class DecoderLM:
                 return (block(h, p), aux), None
             (x, aux), _ = jax.lax.scan(body, (x, aux0), params["blocks"],
                                        unroll=cfg.scan_unroll)
-            return x, aux
+            return x, aux, {}
 
         if fam == "hybrid":
             k = cfg.attn_every
@@ -194,21 +226,22 @@ class DecoderLM:
 
             (x, aux), _ = jax.lax.scan(outer, (x, aux0), stacked,
                                        unroll=cfg.scan_unroll)
-            return x, aux
+            return x, aux, {}
 
         raise ValueError(fam)
 
     def forward(self, params, batch: dict) -> Array:
         """Full-sequence logits (prefill path)."""
         x = self._embed(params, batch)
-        x, _ = self._run_blocks(params, x)
+        x, _, _ = self._run_blocks(params, x)
         return self._logits(params, x)
 
     def loss(self, params, batch: dict) -> tuple[Array, dict]:
-        """Next-token CE on text positions (+ MoE aux)."""
+        """Next-token CE on text positions (+ MoE aux); the metrics carry
+        the expert layers' routing counts (``moe.moe_block``)."""
         cfg = self.cfg
         x = self._embed(params, batch)
-        x, aux = self._run_blocks(params, x)
+        x, aux, stats = self._run_blocks(params, x)
         if cfg.family == "vlm":
             x = x[:, cfg.num_patches:]          # text positions only
         logits = self._logits(params, x)
@@ -218,7 +251,7 @@ class DecoderLM:
         mask = (labels >= 0).astype(jnp.float32)
         ce = -(ll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
         total = ce + aux
-        return total, {"ce": ce, "aux": aux}
+        return total, {"ce": ce, "aux": aux, **stats}
 
     # -- decode -------------------------------------------------------------
 
@@ -250,17 +283,31 @@ class DecoderLM:
         x = self._embed_tokens(params, tokens)
 
         if fam in ("dense", "moe", "vlm"):
-            def body(h, inp):
+            def layer(h, inp, kind=None):
                 p, ck, cv = inp
                 a, ck2, cv2 = attention.decode_attention(
-                    p["attn"], rms_norm(h, p["ln0"], cfg.norm_eps), ck, cv, pos, cfg)
+                    p["attn"], rms_norm(h, p["ln0"], cfg.norm_eps), ck, cv, pos, cfg,
+                    kind=kind)
                 h = h + a
                 if fam == "moe":
-                    f, _ = moe.moe_block(p["moe"], rms_norm(h, p["ln1"], cfg.norm_eps), cfg)
+                    f, _, _ = moe.moe_block(p["moe"], rms_norm(h, p["ln1"], cfg.norm_eps), cfg)
                 else:
                     f = mlp.swiglu(p["mlp"], rms_norm(h, p["ln1"], cfg.norm_eps))
                 return h + f, (ck2, cv2)
-            x, (k2, v2) = jax.lax.scan(body, x, (params["blocks"], cache["k"], cache["v"]))
+            xs = (params["blocks"], cache["k"], cache["v"])
+            if not cfg.layer_types:
+                x, (k2, v2) = jax.lax.scan(layer, x, xs)
+                return self._logits(params, x), {"k": k2, "v": v2}
+
+            def body(h, inp):
+                out = []
+                for i, kind in enumerate(cfg.layer_types):
+                    h, kv = layer(h, jax.tree_util.tree_map(lambda a: a[i], inp),
+                                  kind)
+                    out.append(kv)
+                return h, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *out)
+            x, (k2, v2) = jax.lax.scan(body, x, _by_period(xs, len(cfg.layer_types)))
+            k2, v2 = (a.reshape((-1,) + a.shape[2:]) for a in (k2, v2))
             return self._logits(params, x), {"k": k2, "v": v2}
 
         if fam == "ssm":

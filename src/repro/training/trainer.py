@@ -70,6 +70,11 @@ class TrainerConfig:
 # TrainState is a plain dict pytree: params / momentum / opt_state / step.
 TrainState = dict
 
+#: Routing counts a loss may return (``repro.models.moe``), and how the
+#: step folds them over workers into its metrics.
+ROUTING_COUNTS = {"routed_pairs": jnp.sum, "expert_load_max": jnp.max,
+                  "expert_load_min": jnp.min}
+
 
 def _split_info(params: PyTree, fsdp_keys: tuple[str, ...]):
     """Flattens params into (robust leaves, fsdp leaves) index lists."""
@@ -161,11 +166,12 @@ def build_train_step(loss_fn: Callable, optimizer: Optimizer,
         def grad_a(rp, fp, wbatch):
             (l, m), g = jax.value_and_grad(loss_of, argnums=0, has_aux=True)(
                 rp, fp, wbatch)
-            return l, g
+            return l, g, {k: v for k, v in m.items() if k in ROUTING_COUNTS}
 
         with stages.stage("backward"):
-            losses, grads = jax.vmap(grad_a, in_axes=(None, None, 0),
-                                     **vmap_kw)(robust_p, fsdp_p, batch)
+            losses, grads, counts = jax.vmap(
+                grad_a, in_axes=(None, None, 0), **vmap_kw)(
+                    robust_p, fsdp_p, batch)
         n_workers = losses.shape[0]
         n_honest = n_workers - cfg.byz.f
 
@@ -225,6 +231,7 @@ def build_train_step(loss_fn: Callable, optimizer: Optimizer,
                 "loss": losses[:n_honest].mean(),
                 "lr": lr,
                 "direction_norm": global_norm(direction),
+                **{k: ROUTING_COUNTS[k](v) for k, v in counts.items()},
             }
         if cfg.track_kappa_hat:
             # The honest rows of `stack` are those of `attacked`; reading

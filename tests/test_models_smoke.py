@@ -44,6 +44,7 @@ def test_full_config_matches_assignment(arch):
         "zamba2-2.7b": (54, 2560, 32, 32, 10240, 32000),
         "whisper-base": (6, 512, 8, 8, 2048, 51865),
         "rwkv6-3b": (32, 2560, 40, 40, 8960, 65536),
+        "mellum2-12b-a2.5b": (28, 2304, 32, 4, 896, 98304),
     }[arch]
     got = (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
            cfg.d_ff, cfg.vocab_size)
@@ -57,12 +58,15 @@ def test_full_config_matches_assignment(arch):
         assert cfg.ssm_state == 64 and cfg.attn_every > 0
     if arch == "rwkv6-3b":
         assert cfg.family == "ssm"
+    if arch == "mellum2-12b-a2.5b":
+        assert cfg.num_experts == 64 and cfg.experts_per_token == 8
+        assert cfg.sliding_window == 1024 and cfg.yarn_factor == 16
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
 def test_reduced_forward_and_loss(arch):
     cfg = reduced_config(arch)
-    assert cfg.num_layers <= 2 and cfg.d_model <= 512
+    assert cfg.num_layers <= max(2, len(cfg.layer_types)) and cfg.d_model <= 512
     if cfg.num_experts:
         assert cfg.num_experts <= 4
     model = build_model(cfg)
@@ -106,12 +110,12 @@ def test_reduced_robust_train_step(arch):
 
 
 @pytest.mark.parametrize("arch", ["qwen2-7b", "rwkv6-3b", "zamba2-2.7b",
-                                  "whisper-base", "internvl2-2b"])
+                                  "whisper-base", "internvl2-2b",
+                                  "mellum2-12b-a2.5b"])
 def test_decode_matches_prefill(arch):
-    """Incremental cached decode == full forward, per family."""
+    """Incremental cached decode == full forward, per family (mellum2: the
+    expert layer, a window shorter than the row and YaRN, per layer type)."""
     cfg = reduced_config(arch)
-    if cfg.num_experts:
-        cfg = cfg.replace(capacity_factor=8.0)   # avoid capacity drops
     model = build_model(cfg)
     key = jax.random.PRNGKey(2)
     params = model.init(key)

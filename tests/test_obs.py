@@ -465,6 +465,9 @@ def test_fleet_drain_export_end_to_end(tmp_path):
 #: SmolLM's architecture at toy widths.
 _TINY_LM = dict(num_layers=2, d_model=64, num_heads=4, num_kv_heads=2,
                 d_ff=128, vocab_size=256, head_dim=16)
+#: Mellum2's expert layer and layer types at toy widths.
+_TINY_MOE = dict(_TINY_LM, num_layers=4, d_ff=32, num_experts=8,
+                 experts_per_token=2, experts_held=2, sliding_window=8)
 _STEP_STAGES = ("backward", "momentum", "attack", "aggregate", "kappa",
                 "optimizer")
 #: What a compiled program's text says beside its instructions.
@@ -472,14 +475,15 @@ _ANNOTATION = re.compile(
     r",? ?(metadata|frontend_attributes)=\{[^{}]*(\{[^{}]*\}[^{}]*)*\}")
 
 
-def _tiny_step(backend):
-    """The D-SHB step (n=4, f=1 ALIE, NNM+CWTM) of a tiny SmolLM, jitted
-    with the state donated, and its argument shapes."""
+def _tiny_step(backend, arch="smollm-360m", sizes=_TINY_LM):
+    """The D-SHB step (n=4, f=1 ALIE, NNM+CWTM) of a tiny SmolLM (or of
+    ``arch`` at ``sizes``), jitted with the state donated, and its
+    argument shapes."""
     from repro.configs import get_config
     from repro.models import build_model
     from repro.training import build_train_step, init_state
 
-    model = build_model(get_config("smollm-360m").replace(**_TINY_LM))
+    model = build_model(get_config(arch).replace(**sizes))
     n, f = 4, 1
     cfg = TrainerConfig(
         algorithm="dshb", beta=0.9,
@@ -496,8 +500,9 @@ def _tiny_step(backend):
 
 
 @functools.lru_cache(maxsize=None)
-def _compiled_step_text(backend):
-    step, args = _tiny_step(backend)
+def _compiled_step_text(backend, arch="smollm-360m"):
+    sizes = _TINY_MOE if arch == "mellum2-12b-a2.5b" else _TINY_LM
+    step, args = _tiny_step(backend, arch, sizes)
     return step.lower(*args).compile().as_text()
 
 
@@ -543,6 +548,64 @@ def test_stage_tags_change_no_instruction(backend, monkeypatch):
     plain = step.lower(*args).compile().as_text()
     assert "robust_stage" not in plain
     assert _instructions(tagged) == _instructions(plain)
+
+
+def test_expert_ops_carry_moe_part_forward_and_backward(monkeypatch):
+    """Lowered for a TPU, the tiny Mellum2 step's grouped products carry
+    ``moe_part="experts"`` forward (row outputs) and backward (the weight
+    gradients' group outputs) beside their ``robust_stage``.  Compiled,
+    the dispatch gathers and the combine's scatters, and their
+    transposes in the backward, carry ``moe_part="route"``."""
+    from repro.kernels import target
+
+    monkeypatch.setattr(target, "on_tpu", lambda: True)
+    step, args = _tiny_step("xla", "mellum2-12b-a2.5b", _TINY_MOE)
+    text = step.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    dots = [l for l in text.splitlines() if "ragged_dot" in l]
+    fwd = [l for l in dots if re.search(r"-> tensor<\d+x\d+xbf16>", l)]
+    bwd = [l for l in dots if re.search(r"-> tensor<\d+x\d+x\d+xbf16>", l)]
+    assert fwd and bwd and len(fwd) + len(bwd) == len(dots)
+    for line in dots:
+        assert 'moe_part = "experts"' in line, line
+        assert 'robust_stage = "backward"' in line, line
+    assert any("@argsort" in l and 'moe_part = "route"' in l
+               for l in text.splitlines())
+    compiled = _compiled_step_text("xla", "mellum2-12b-a2.5b")
+    for op in ("gather", "scatter"):
+        tagged = [l for l in compiled.splitlines()
+                  if f" {op}(" in l and 'moe_part="route"' in l]
+        # Forward and backward: one of each per layer at the least.
+        assert len(tagged) >= 2 * _TINY_MOE["num_layers"], op
+
+
+def test_moe_tags_change_no_instruction(monkeypatch):
+    from repro.models import moe
+
+    tagged = _compiled_step_text("xla", "mellum2-12b-a2.5b")
+    assert 'moe_part="experts"' in tagged and 'moe_part="route"' in tagged
+    monkeypatch.setattr(moe, "moe_part",
+                        lambda name: contextlib.nullcontext())
+    jax.clear_caches()      # the grouped products' traces are cached
+    step, args = _tiny_step("xla", "mellum2-12b-a2.5b", _TINY_MOE)
+    plain = step.lower(*args).compile().as_text()
+    jax.clear_caches()      # no later trace reuses the untagged ones
+    assert "moe_part" not in plain
+    # The scopes shift the numbers in instruction names: compare with every
+    # name renumbered in order of first appearance.
+    assert _renamed(_instructions(tagged)) == _renamed(_instructions(plain))
+
+
+def _renamed(text):
+    names = {}
+    return re.sub(r"%[\w.-]+",
+                  lambda m: names.setdefault(m.group(0), f"%v{len(names)}"),
+                  text)
+
+
+def test_untagged_config_carries_no_moe_part():
+    """A config without an expert layer (SmolLM) gets no expert-layer tag:
+    its step is the one the stage tests above strip and compare."""
+    assert "moe_part" not in _compiled_step_text("xla")
 
 
 def test_stage_names_are_closed_and_nested_stage_wins():
