@@ -21,10 +21,11 @@ Execution is backend-routed (``AggregatorSpec.backend`` through
 :mod:`repro.kernels.dispatch`): the "xla" backend emits the leaf-streamed
 jnp forms below (what the GSPMD distributed path lowers); the "pallas"
 backend runs the blocked ``gram``, streamed ``combine`` and fused
-``mixtrim`` kernels over each leaf's (n, d_i) view in place, so neither
-a concatenated copy of the stack nor the NNM-mixed stack ``Y = M @ X``
-materializes in HBM; "pallas_sharded" runs them over ONE contiguous
-(n, D) buffer shard_map'd along D over a mesh axis (per-shard gram +
+``mixtrim`` kernels over each leaf's (n, R, C) leaf view, read in the
+leaf's own tiled layout, so neither a concatenated or relaid-out copy of
+the stack nor the NNM-mixed stack ``Y = M @ X`` materializes in HBM;
+"pallas_sharded" runs them over ONE contiguous (n, D) buffer
+shard_map'd along D over a mesh axis (per-shard gram +
 psum'd (n, n) partials, replicated coefficients, shard-local
 combine/mixtrim — :mod:`repro.kernels.shard`).  "auto" =
 pallas on a single-device TPU, pallas_sharded on multi-device TPU, xla
@@ -222,11 +223,12 @@ def _hier_bucket_size(spec: AggregatorSpec, n: int, f, *, dyn: bool) -> int:
 _HIER_S1_NOTE = "s=1: singleton buckets, identity reduction (skipped)"
 
 
-def _hier_reduce(segs: list, spec: AggregatorSpec, f, *,
+def _hier_reduce(segs: list, layout, spec: AggregatorSpec, f, *,
                  key: Optional[Array], dyn: bool, backend: str,
                  mesh, worker_axis: Optional[str], axis: Optional[str]
                  ) -> tuple[list, Any, Optional[Array]]:
-    """The hierarchical pre-reduction of the (n, d) stack segments.
+    """The hierarchical pre-reduction of the stack's segments (leaf views
+    or one (n, D) buffer).
 
     Returns ([reduced stack (ceil(n/s), D)], adjusted f, reduced fp32 Gram
     or None).  The permutation rides inside the (n_b, n) assignment matrix
@@ -244,7 +246,9 @@ def _hier_reduce(segs: list, spec: AggregatorSpec, f, *,
         kdispatch.record_decision("bucketgram", backend, "skipped",
                                   _HIER_S1_NOTE)
         return segs, f, None
-    flat = segs[0] if len(segs) == 1 else jnp.concatenate(segs, axis=1)
+    # The mesh backends' one (n, D) buffer as it is; split views back in
+    # their leaves' order, in one buffer.
+    flat = kdispatch.flat_stack(segs, layout)
     n_b = _num_buckets(n, s)
     bmat = _bucket_matrix(key, n, s, dtype=jnp.float32)
     need_gram = spec.rule in GRAM_RULES or spec.pre == "nnm"
@@ -261,12 +265,13 @@ def _aggregate_flat(work: PyTree, spec: AggregatorSpec, f, *,
                     mesh_ctx: Optional[tuple] = None,
                     internals: Optional[dict] = None,
                     hier: bool = False) -> PyTree:
-    """Kernel-backend pipeline: pre-aggregated stack -> (n, d) segments ->
+    """Kernel-backend pipeline: pre-aggregated stack -> segments ->
     blocked gram -> coeff -> streamed combine / fused mixtrim ->
     aggregated pytree.  The mesh backends and the hierarchical stage
     stream ONE contiguous (n, D) buffer; single-device "pallas" streams
-    each leaf's (n, d_i) view in place and sums the per-leaf Grams, so
-    the stack is never copied (only its bucketed reduction concatenates).
+    each leaf's (n, R, C) leaf view in its own layout and sums the
+    per-leaf Grams, so the stack is neither copied nor relaid out (only
+    its bucketed reduction concatenates).
 
     ``backend`` is "pallas" (single device), "pallas_sharded" (the
     shard_map'd form; ``mesh_ctx`` is its resolved (mesh, axis) — the
@@ -296,8 +301,8 @@ def _aggregate_flat(work: PyTree, spec: AggregatorSpec, f, *,
         # consumer follows) its Gram in the same pass — the gram stage
         # below is skipped.
         segs, f, g = _hier_reduce(
-            segs, spec, f, key=key, dyn=dyn, backend=backend, mesh=mesh,
-            worker_axis=worker_axis, axis=axis)
+            segs, layout, spec, f, key=key, dyn=dyn, backend=backend,
+            mesh=mesh, worker_axis=worker_axis, axis=axis)
 
     mix_matrix = None
     if (spec.rule in GRAM_RULES or spec.pre == "nnm") and g is None:
@@ -310,9 +315,13 @@ def _aggregate_flat(work: PyTree, spec: AggregatorSpec, f, *,
                 "sketch_dim gram runs the leaf-streamed signed sketch")
             g = tree_sketch_gram(work, spec.sketch_dim, key)
         else:
+            # Leaf views smallest first: the order in which the compiled
+            # step holds the least HBM (PERF.md, section 6).
+            order = (sorted(segs, key=lambda x: x.size)
+                     if segs[0].ndim == 3 else segs)
             g = sum(kdispatch.dispatch_gram(x, backend=backend,
                                             mesh=mesh, axis=axis)
-                    for x in segs)
+                    for x in order)
 
     if spec.pre == "nnm":
         d2 = gramlib.pdist_sq_from_gram(g)
@@ -356,6 +365,13 @@ def _aggregate_flat(work: PyTree, spec: AggregatorSpec, f, *,
         # bf16-transport runs agree across backends.
         def mix_for(x):
             return None if mix_matrix is None else mix_matrix.astype(x.dtype)
+
+        if segs[0].ndim == 3:
+            # Start the coordinate kernels once every leaf view exists (with
+            # NNM, M already waits for all of them): left free to interleave
+            # them with the attack, XLA holds more of the stack's honest rows
+            # at once (PERF.md, section 6).
+            segs = list(jax.lax.optimization_barrier(tuple(segs)))
 
         if spec.rule == "meamed":
             # No fused kernel: mix (if any) + mean-around-median in jnp —

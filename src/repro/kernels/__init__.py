@@ -6,8 +6,9 @@ TPU VMEM/MXU tiling and are validated in interpret mode on CPU.
 
 Production code enters through :mod:`repro.kernels.dispatch`: the backend
 layer ``repro.core.robust`` routes through when
-``AggregatorSpec.backend`` resolves to "pallas" (per-leaf (n, d) views,
-blocked gram, streamed combine, fused mix+trim — see docs/perf.md).  The
+``AggregatorSpec.backend`` resolves to "pallas" (per-leaf (n, R, C) leaf
+views in the leaves' own layout, blocked gram, streamed combine, fused
+mix+trim — see docs/perf.md).  The
 "xla" backend and the distributed (GSPMD) path use the jnp oracles so the
 CPU dry-run lowers; off-TPU, "pallas" runs the kernel bodies in interpret
 mode.

@@ -3,24 +3,28 @@
 ``repro.core.robust`` is backend-polymorphic: every aggregation pipeline
 declares ``AggregatorSpec.backend`` ("xla" | "pallas" | "pallas_sharded" |
 "auto") and this module turns that request into concrete kernel calls over
-ONE contiguous ``(n, D)`` view of the worker-stacked pytree:
+views of the worker-stacked pytree:
 
 * **flatten** — :func:`flatten_worker_stack` concatenates every leaf's
   ``(n, ...)`` stack into a single ``(n, D)`` buffer plus static
   leaf-segment metadata, for the mesh backends that shard one buffer
   along D; on a single device :func:`split_worker_stack` instead hands
-  the kernels each leaf's ``(n, d_i)`` view in place, so the stack is
-  never copied (at SmolLM-360M width with n=4 that copy is 5.8 GB, more
-  than a 16 GB chip has left beside the train state);
+  the kernels each leaf as the 3-D leaf view ``(n, R, C)`` (C the minor
+  dim of the leaf's TPU layout, R the product of the others but n), which
+  the kernels read in the leaf's own tiled layout, so the stack is
+  neither copied nor relaid out (at SmolLM-360M width with n=4 the stack
+  is 5.8 GB);
 * **gram** — the blocked Pallas kernel (``kernels/gram``), one wide
-  (n, W) tile per grid step accumulating the tiny (n, n) Gram matrix
-  (W from :func:`pick_block_d`, recorded on each decision);
+  (n, W) tile or (n, r, w) leaf block per grid step accumulating the
+  tiny (n, n) Gram matrix (the block from :func:`pick_block_d` or
+  ``tiling.pick_leaf_block``, recorded on each decision with its view);
 * **combine** — the streamed coefficient kernel (``kernels/combine``)
   applying the gram-rule weights without re-materializing anything;
 * **mixtrim** — the fused NNM-mix + coordinate trim/median kernel
   (``kernels/mixtrim``), static-f or the dynamic-f rank-mask variant, so
-  the mixed stack ``Y = M @ X`` never exists in HBM (any n: the bitonic
-  sort pads to the next power of two with sentinel rows).
+  the mixed stack ``Y = M @ X`` never exists in HBM (any n: a leaf view
+  sorts its workers' slabs elementwise; the flat form's bitonic sort pads
+  to the next power of two with sentinel rows).
 
 Under a multi-device mesh, ``backend="pallas_sharded"`` runs the same
 pipeline shard_map'd along D (:mod:`repro.kernels.shard`): per-shard
@@ -61,6 +65,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 try:        # jaxpr types moved out of jax.core on newer jax releases
     from jax.extend import core as _jaxpr_core
@@ -76,7 +81,8 @@ from repro.kernels.mixtrim import mixtrim as _mixtrim_op
 from repro.kernels.mixtrim import mixtrim_dyn as _mixtrim_dyn_op
 from repro.kernels.target import on_tpu, resolve_interpret
 from repro.kernels.tiling import (  # noqa: F401  (pick_block_d: re-export)
-    block_width, grid_steps, pick_block_d, sort_height,
+    block_width, grid_steps, leaf_block, leaf_grid, leaf_shape,
+    leaf_view_fits, minor_swapped, pick_block_d, sort_height,
 )
 
 Array = jax.Array
@@ -154,10 +160,14 @@ class KernelDecision:
     requested: str          # backend asked for at this call site
     used: str               # "pallas[-sharded][-interpret]" | "xla"
     reason: str = ""        # why `used` differs from the pallas kernel path
-    #: Grid tile width W the kernel streamed with (per shard under the
-    #: sharded backends) and its number of grid steps; None off-kernel.
+    #: Coordinates of each worker one grid step streams (per shard under
+    #: the sharded backends): the tile width W of a flat view, r x w of a
+    #: leaf view's (r, w) block; its number of grid steps; None off-kernel.
     block_d: Optional[int] = None
     grid_steps: Optional[int] = None
+    #: Operand view: "leaf" (a (n, R, C) leaf in its own layout) or
+    #: "flat" (a (n, D) stack or shard); None off-kernel.
+    view: Optional[str] = None
 
     @property
     def fell_back(self) -> bool:
@@ -204,7 +214,7 @@ class DispatchRecord:
                  f"pre={self.pre or 'none'} dyn={self.dyn}{hier}{mesh}"]
         for d in self.decisions:
             why = f" ({d.reason})" if d.reason else ""
-            tile = (f" W={d.block_d} steps={d.grid_steps}"
+            tile = (f" {VIEWS[d.view]} W={d.block_d} steps={d.grid_steps}"
                     if d.block_d is not None else "")
             parts.append(f"  {d.primitive}: {d.used}{tile}{why}")
         return "\n".join(parts)
@@ -271,10 +281,11 @@ def record_decision(primitive: str, requested: str, used: str,
                     reason: str = "", tile: Optional[tuple] = None) -> None:
     """Append a decision to the open record (once: the per-leaf calls of a
     split stack repeat the same decision for leaves of one tile).
-    ``tile`` is the kernel's (grid tile width, grid steps)."""
+    ``tile`` is the kernel's (coordinates per grid step, grid steps,
+    view) from :func:`_tile`."""
     if _HISTORY:
         dec = KernelDecision(primitive, requested, used, reason,
-                             *(tile or (None, None)))
+                             *(tile or (None, None, None)))
         if dec not in _HISTORY[-1].decisions:
             _HISTORY[-1].decisions.append(dec)
 
@@ -286,21 +297,39 @@ def _pallas_used(interpret: bool, sharded: bool = False) -> tuple[str, str]:
     return base, ""
 
 
-def _tile(x: Array, block_d: Optional[int], mesh=None,
-          axis: Optional[str] = None) -> tuple[int, int]:
-    """(grid tile width, grid steps) a kernel streams the (..., n, d)
-    stack ``x`` with: ``block_d`` as given, else the widest that fits
-    VMEM; under a mesh, for one shard's columns."""
-    n, d = x.shape[-2:]
+#: How ``describe()`` prints a decision's operand view.
+VIEWS = {"leaf": "leaf (n,R,C)", "flat": "flat (n,D)"}
+
+
+def _tile(x, block_d: Optional[int], mesh=None,
+          axis: Optional[str] = None) -> tuple[int, int, str]:
+    """(coordinates per grid step, grid steps, view) a kernel streams the
+    stack ``x`` (an array or its shape and dtype) with: a (n, R, C) leaf
+    view in (r, w) blocks, else the (n, d) stack in ``block_d``-wide
+    tiles, the widest that fits VMEM if None; under a mesh, for one
+    shard's columns."""
+    if len(x.shape) == 3:
+        r, w = leaf_block(x.shape, block_d, x.dtype)
+        rs, ws = leaf_grid(x.shape, (r, w))
+        return r * w, rs * ws, "leaf"
+    n, d = x.shape
     if mesh is not None:
         d = shardlib.local_width(d, mesh, axis)
     w = block_width(d, block_d, n, x.dtype)
-    return w, grid_steps(d, w)
+    return w, grid_steps(d, w), "flat"
 
 
-def _pad_note(n: int) -> str:
-    """Observability note for the sentinel-padded bitonic sort."""
-    if sort_height(n) == n:
+def _block_arg(x: Array, block_d: Optional[int], tile: tuple):
+    """The ``block_d`` a kernel wrapper takes for ``x``: the flat tile
+    width just picked, or for a leaf view the rows as given (the wrapper
+    picks the same block again)."""
+    return tile[0] if x.ndim == 2 else block_d
+
+
+def _pad_note(n: int, view: str) -> str:
+    """Observability note for the sentinel-padded bitonic sort (the flat
+    view's; a leaf view sorts slabs and pads nothing)."""
+    if view == "leaf" or sort_height(n) == n:
         return ""
     return f"n={n} padded to {sort_height(n)} with sort sentinels"
 
@@ -318,19 +347,48 @@ class StackLayout:
     width: int              # total feature width D
 
 
-def split_worker_stack(tree: PyTree) -> tuple[list, StackLayout]:
-    """Every leaf's ``(n, d_i)`` view (a reshape: no copy) plus the same
-    layout :func:`flatten_worker_stack` gives, for kernels that run leaf
-    by leaf."""
+def _stack_layout(tree: PyTree) -> tuple[list, StackLayout]:
     leaves, treedef = jax.tree_util.tree_flatten(tree)
-    n = leaves[0].shape[0]
-    segs, flats, off = [], [], 0
+    segs, off = [], 0
     for leaf in leaves:
-        flat = jnp.reshape(leaf, (n, -1))
-        segs.append((off, flat.shape[1], tuple(leaf.shape[1:])))
-        flats.append(flat)
-        off += flat.shape[1]
-    return flats, StackLayout(treedef, tuple(segs), n, off)
+        size = int(np.prod(leaf.shape[1:], dtype=np.int64))
+        segs.append((off, size, tuple(leaf.shape[1:])))
+        off += size
+    return leaves, StackLayout(treedef, tuple(segs), leaves[0].shape[0], off)
+
+
+def leaf_view_of(leaf: Array) -> Array:
+    """A worker-stacked leaf as its (n, R, C) leaf view
+    (``tiling.leaf_shape``): its last two dims swapped first where the
+    TPU holds it minor-swapped, so the view keeps its memory order."""
+    if minor_swapped(leaf.shape):
+        leaf = jnp.swapaxes(leaf, -1, -2)
+    return jnp.reshape(leaf, leaf_shape(leaf.shape))
+
+
+def leaf_of_view(view: Array, shape: tuple) -> Array:
+    """Undo :func:`leaf_view_of`: ``view`` is the (n, R, C) leaf view of
+    a leaf of ``shape``, or its (R, C) aggregate (the worker axis reduced
+    away)."""
+    out = shape if view.ndim == 3 else shape[1:]
+    if not minor_swapped(shape):
+        return view.reshape(out)
+    return jnp.swapaxes(view.reshape((*out[:-2], out[-1], out[-2])), -1, -2)
+
+
+def split_worker_stack(tree: PyTree) -> tuple[list, StackLayout]:
+    """Every leaf's 3-D leaf view ``(n, R, C)`` (:func:`leaf_view_of`)
+    plus the same layout :func:`flatten_worker_stack` gives, for kernels
+    that run leaf by leaf.  The view keeps the order in which the TPU's
+    tiled layout holds the leaf, so it is a bitcast, no copy and no
+    relayout, when the dim above its minor one is a multiple of the
+    sublane tile (8 rows of fp32), as in every matrix leaf of the
+    benchmark's models; a 2-D leaf (n, C) becomes (n, 1, C), a small
+    relayout.  A stack of more workers than ``tiling.leaf_view_fits``
+    allows goes as flat (n, d_i) views instead (a relayout on a TPU)."""
+    leaves, layout = _stack_layout(tree)
+    return [leaf_view_of(leaf) if leaf_view_fits(layout.n, leaf.dtype)
+            else jnp.reshape(leaf, (layout.n, -1)) for leaf in leaves], layout
 
 
 def flatten_worker_stack(tree: PyTree) -> tuple[Array, StackLayout]:
@@ -340,23 +398,37 @@ def flatten_worker_stack(tree: PyTree) -> tuple[Array, StackLayout]:
     buffer the kernels can stream without per-leaf dispatch.  Mixed leaf
     dtypes promote under concatenation (uniform fp32 / bf16 stacks — the
     only cases the pipeline produces — keep their dtype)."""
-    flats, layout = split_worker_stack(tree)
-    buf = flats[0] if len(flats) == 1 else jnp.concatenate(flats, axis=1)
-    return buf, layout
+    leaves, layout = _stack_layout(tree)
+    return flat_stack(leaves), layout
+
+
+def flat_stack(segs: list, layout: Optional[StackLayout] = None) -> Array:
+    """One (n, D) buffer of worker-stacked leaves in row-major order, or
+    of their split views (``layout`` given: each leaf view is put back in
+    its leaf's order first): a copy unless there is one 2-D segment."""
+    if layout is not None:
+        segs = [leaf_of_view(v, (layout.n, *shape)) if v.ndim == 3 else v
+                for v, (_, _, shape) in zip(segs, layout.segments)]
+    flats = [v.reshape(v.shape[0], -1) for v in segs]
+    return flats[0] if len(flats) == 1 else jnp.concatenate(flats, axis=1)
 
 
 def unflatten_aggregate(vecs, layout: StackLayout) -> PyTree:
     """Rebuild the aggregated pytree (worker axis removed) from the (D,)
-    aggregate of a flattened stack, or from the list of per-leaf (d_i,)
-    aggregates of a split one."""
-    if isinstance(vecs, (list, tuple)) and len(vecs) == len(layout.segments):
-        leaves = [v.reshape(shape)
-                  for v, (_, _, shape) in zip(vecs, layout.segments)]
-    else:
-        vec = vecs[0] if isinstance(vecs, (list, tuple)) else vecs
+    aggregate of a flattened stack, or from the list of per-leaf
+    aggregates of a split one: (R, C) of a leaf view, (d_i,) of a flat
+    view.  The rank tells a leaf view's aggregate from a row-major one,
+    even in a tree of one leaf."""
+    vecs = list(vecs) if isinstance(vecs, (list, tuple)) else [vecs]
+    if len(vecs) == 1 and vecs[0].ndim == 1:
+        vec = vecs[0]
         leaves = [jax.lax.slice_in_dim(vec, off, off + size,
                                        axis=0).reshape(shape)
                   for off, size, shape in layout.segments]
+    else:
+        leaves = [leaf_of_view(v, (layout.n, *shape)) if v.ndim == 2
+                  else v.reshape(shape)
+                  for v, (_, _, shape) in zip(vecs, layout.segments)]
     return jax.tree_util.tree_unflatten(layout.treedef, leaves)
 
 
@@ -405,7 +477,8 @@ def count_wide_ops(fn, *example_args, n: int, width: int,
 def dispatch_gram(x: Array, *, backend: str, block_d: Optional[int] = None,
                   mesh: Optional[jax.sharding.Mesh] = None,
                   axis: Optional[str] = None) -> Array:
-    """(n, D) -> (n, n) fp32 Gram matrix through the chosen backend.
+    """(n, D) or a (n, R, C) leaf view -> (n, n) fp32 Gram matrix through
+    the chosen backend.
 
     ``backend="pallas_sharded"`` needs the resolved (mesh, axis): the
     blocked kernel runs per D-shard and the tiny partial Grams psum."""
@@ -421,7 +494,8 @@ def dispatch_gram(x: Array, *, backend: str, block_d: Optional[int] = None,
         used, why = _pallas_used(interpret)
         tile = _tile(x, block_d)
         record_decision("gram", "pallas", used, why, tile)
-        return _gram_op(x, block_d=tile[0], interpret=interpret)
+        return _gram_op(x, block_d=_block_arg(x, block_d, tile),
+                        interpret=interpret)
     record_decision("gram", backend, "xla")
     return _gram_op(x, use_pallas=False)
 
@@ -433,7 +507,7 @@ def dispatch_gram_batched(x: Array, *, backend: str,
     if backend == "pallas":
         interpret = resolve_interpret()
         used, why = _pallas_used(interpret)
-        tile = _tile(x, block_d)
+        tile = _tile(jax.ShapeDtypeStruct(x.shape[1:], x.dtype), block_d)
         record_decision("gram_batched", "pallas", used, why, tile)
         return _gram_batched_op(x, block_d=tile[0], interpret=interpret)
     record_decision("gram_batched", backend, "xla")
@@ -491,7 +565,8 @@ def dispatch_combine(x: Array, coeff: Array, *, backend: str,
                      block_d: Optional[int] = None,
                      mesh: Optional[jax.sharding.Mesh] = None,
                      axis: Optional[str] = None) -> Array:
-    """(n, D), (n,) -> (D,): streamed linear combination."""
+    """(n, D), (n,) -> (D,), or a (n, R, C) leaf view -> (R, C): streamed
+    linear combination."""
     if backend in _SHARDED_BACKENDS:
         interpret = resolve_interpret()
         used, why = _pallas_used(interpret, sharded=True)
@@ -504,7 +579,8 @@ def dispatch_combine(x: Array, coeff: Array, *, backend: str,
         used, why = _pallas_used(interpret)
         tile = _tile(x, block_d)
         record_decision("combine", "pallas", used, why, tile)
-        return _combine_op(x, coeff, block_d=tile[0], interpret=interpret)
+        return _combine_op(x, coeff, block_d=_block_arg(x, block_d, tile),
+                           interpret=interpret)
     record_decision("combine", backend, "xla")
     return _combine_op(x, coeff, use_pallas=False)
 
@@ -514,25 +590,27 @@ def dispatch_mixtrim(x: Array, m: Optional[Array], f, *, mode: str,
                      block_d: Optional[int] = None,
                      mesh: Optional[jax.sharding.Mesh] = None,
                      axis: Optional[str] = None) -> Array:
-    """(n, D) -> (D,): fused mix + coordinate trim/median.
+    """(n, D) -> (D,), or a (n, R, C) leaf view -> (R, C): fused mix +
+    coordinate trim/median.
 
     ``m=None`` elides the mix dot (plain CWTM/CWMed).  ``dyn=True`` takes
     a TRACED f through the rank-mask kernel variant (one compile per fleet
-    shape bucket).  Non-power-of-two n runs the fused kernel through the
-    sentinel-padded bitonic sort (recorded as a note, NOT a fallback —
-    the kernel body executes for every n).
+    shape bucket).  On a flat (n, D) view a non-power-of-two n runs the
+    fused kernel through the sentinel-padded bitonic sort (recorded as a
+    note, NOT a fallback — the kernel body executes for every n); a leaf
+    view sorts slabs and pads nothing.
     """
     n = x.shape[0]
 
-    def _note(why: str) -> str:
-        pad = _pad_note(n)
+    def _note(why: str, view: str) -> str:
+        pad = _pad_note(n, view)
         return f"{why}; {pad}" if why and pad else (pad or why)
 
     if backend in _SHARDED_BACKENDS:
         interpret = resolve_interpret()
         used, why = _pallas_used(interpret, sharded=True)
         tile = _tile(x, block_d, mesh, axis)
-        record_decision("mixtrim", backend, used, _note(why), tile)
+        record_decision("mixtrim", backend, used, _note(why, tile[2]), tile)
         return shardlib.sharded_mixtrim(x, m, f, mode=mode, mesh=mesh,
                                         axis=axis, dyn=dyn, block_d=tile[0],
                                         interpret=interpret)
@@ -540,8 +618,8 @@ def dispatch_mixtrim(x: Array, m: Optional[Array], f, *, mode: str,
         interpret = resolve_interpret()
         used, why = _pallas_used(interpret)
         tile = _tile(x, block_d)
-        record_decision("mixtrim", "pallas", used, _note(why), tile)
-        bd = tile[0]
+        record_decision("mixtrim", "pallas", used, _note(why, tile[2]), tile)
+        bd = _block_arg(x, block_d, tile)
         if dyn and mode == "trim":
             return _mixtrim_dyn_op(x, m, f, mode=mode, block_d=bd,
                                    interpret=interpret)
@@ -577,7 +655,7 @@ def dispatch_meamed(x: Array, m: Optional[Array], f, *, backend: str,
         _tree_coordinate_rule, _tree_coordinate_rule_dyn,
     )
     mixed = x if m is None else jnp.einsum(
-        "mn,nd->md", m, x, preferred_element_type=jnp.float32)
+        "mn,n...->m...", m, x, preferred_element_type=jnp.float32)
     sub = {"x": mixed}
     return (_tree_coordinate_rule_dyn(sub, "meamed", f) if dyn
             else _tree_coordinate_rule(sub, "meamed", f))["x"]

@@ -9,8 +9,9 @@ import jax.numpy as jnp
 
 
 def gram_ref(x):
-    """Gram matrix X X^T of a (n, d) stack, accumulated in fp32."""
-    xf = x.astype(jnp.float32)
+    """Gram matrix X X^T of a (n, d) stack (or any (n, ...) one, a leaf
+    view), accumulated in fp32."""
+    xf = x.reshape(x.shape[0], -1).astype(jnp.float32)
     return jax.lax.dot_general(xf, xf, (((1,), (1,)), ((), ())),
                                preferred_element_type=jnp.float32)
 

@@ -152,7 +152,8 @@ def test_dyn_pallas_one_compile_across_f():
 def test_nonpow2_mixtrim_runs_fused_padded_kernel():
     """n=17 (paper scale) on backend="pallas": the padded sentinel sort
     lets the fused kernel run — ZERO recorded fallbacks, the pad is noted
-    for observability, and the result matches the xla oracle."""
+    for observability, and the result matches the xla oracle.  (n=17 is
+    past ``tiling.leaf_view_fits``: its leaves go as flat views.)"""
     tree = _tree(12, n=17)
     spec = AggregatorSpec(rule="cwtm", f=4, pre="nnm", backend="pallas")
     got = robust_lib.robust_aggregate(tree, spec)
@@ -161,6 +162,7 @@ def test_nonpow2_mixtrim_runs_fused_padded_kernel():
     assert rec.fallbacks == [], rec.describe()
     assert any(d.primitive == "mixtrim" and "padded to 32" in d.reason
                for d in rec.decisions), rec.describe()
+    assert {d.view for d in rec.decisions} == {"flat"}, rec.describe()
     ref = robust_lib.robust_aggregate(
         tree, AggregatorSpec(rule="cwtm", f=4, pre="nnm", backend="xla"))
     _assert_trees_close(got, ref, rtol=1e-5, atol=1e-5)
@@ -321,15 +323,17 @@ def test_dispatch_history_records_tile_and_grid_steps():
             w = min(kdispatch.pick_block_d(n, d), d)
             want.add((prim, w, -(-d // w)))
     assert tiles == want, rec.describe()
-    assert "W=2600 steps=1" in rec.describe()
+    assert "leaf (n,R,C) W=2600 steps=1" in rec.describe()
+    assert {d.view for d in rec.decisions
+            if d.primitive in ("gram", "mixtrim")} == {"leaf"}
     x = jnp.ones((n, 2600), jnp.float32)
     kdispatch.open_record(requested="pallas", backend="pallas", rule="cwtm",
                           pre=None)
     kdispatch.dispatch_gram(x, backend="pallas", block_d=1024)
     kdispatch.dispatch_gram(x, backend="xla")
-    got = [(d.used.split("-")[0], d.block_d, d.grid_steps)
+    got = [(d.used.split("-")[0], d.block_d, d.grid_steps, d.view)
            for d in kdispatch.last_dispatch().decisions]
-    assert got == [("pallas", 1024, 3), ("xla", None, None)]
+    assert got == [("pallas", 1024, 3, "flat"), ("xla", None, None, None)]
 
 
 # ---------------------------------------------------------------------------
@@ -378,15 +382,50 @@ def test_single_device_pallas_streams_leaves_in_place():
 
 
 def test_split_worker_stack_views_every_leaf():
+    """Each leaf's (n, R, C) leaf view: C the minor dim of the leaf's TPU
+    layout (its last, or its second-to-last where the TPU holds the two
+    swapped), R the product of the others, a 2-D leaf (n, 1, C), a 1-D
+    one (n, 1, 1); the views go back to the leaves' order exactly."""
     tree = _tree(20, n=5)
+    tree["tall"] = jnp.asarray(
+        np.random.default_rng(3).normal(size=(5, 2, 3, 16, 7)), jnp.float32)
     segs, layout = kdispatch.split_worker_stack(tree)
     flat, flat_layout = kdispatch.flatten_worker_stack(tree)
     assert layout == flat_layout
-    assert [s.shape for s in segs] == [(5, size)
-                                       for _, size, _ in layout.segments]
-    np.testing.assert_array_equal(np.concatenate(segs, axis=1), flat)
+    views = {(37,): (5, 1, 37), (3, 5): (5, 3, 5), (): (5, 1, 1),
+             (2, 3, 16, 7): (5, 42, 16)}       # (16, 7) is held 16-minor
+    assert [s.shape for s in segs] == [
+        views[shape] for _, _, shape in layout.segments]
+    assert [s.shape[1] * s.shape[2] for s in segs] == [
+        size for _, size, _ in layout.segments]
+    np.testing.assert_array_equal(kdispatch.flat_stack(segs, layout), flat)
+    np.testing.assert_array_equal(
+        segs[sorted(tree).index("tall")],
+        np.swapaxes(np.asarray(tree["tall"]), -1, -2).reshape(5, 42, 16))
     # per-leaf aggregates rebuild the same tree as the flat one
     per_leaf = kdispatch.unflatten_aggregate([s[2] for s in segs], layout)
     _assert_trees_close(per_leaf,
                         kdispatch.unflatten_aggregate(flat[2], layout),
                         rtol=0, atol=0)
+
+
+@pytest.mark.parametrize("rule", ["cwtm", "multikrum"])
+def test_single_swapped_leaf_through_hier_stage(rule):
+    """A tree of ONE minor-swapped 3-D leaf under the hierarchical stage
+    (s > 1): the stage flattens the leaf views to one (n, D) buffer, so
+    the aggregate is one (D,) vector in row-major order — it must rebuild
+    the leaf as it is, not be taken for the leaf's own (R, C) aggregate."""
+    shape = (5, 200, 50)
+    assert tiling.minor_swapped(shape) and tiling.leaf_view_fits(5)
+    tree = {"w": jnp.asarray(
+        np.random.default_rng(7).normal(size=shape), jnp.float32)}
+    key = jax.random.PRNGKey(2)
+
+    def spec(backend):
+        return AggregatorSpec(rule=rule, f=1, pre="nnm", hier=True,
+                              bucket_size=2, backend=backend)
+
+    got = robust_lib.robust_aggregate(tree, spec("pallas"), key=key)
+    assert kdispatch.last_dispatch().backend == "pallas"
+    ref = robust_lib.robust_aggregate(tree, spec("xla"), key=key)
+    _assert_trees_close(got, ref, rtol=1e-5, atol=1e-5, err_msg=rule)

@@ -390,3 +390,177 @@ def test_kernels_sub_block_d():
     np.testing.assert_allclose(
         np.asarray(mixtrim(x, m, f=2, mode="trim")),
         np.asarray(mixtrim_ref(x, m, 2, "trim")), rtol=1e-6, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# Leaf views: a (n, R, C) leaf read in its own layout, slab by slab.
+# ---------------------------------------------------------------------------
+
+#: Rows R of a leaf view with the rows per grid step they run in: one row
+#: (a 2-D leaf), R a multiple of the row block, and a ragged last block.
+LEAF_ROWS = {"one": (1, None), "even": (16, 8), "ragged": (20, 8)}
+#: Minor dims C: SmolLM's k/v width and d_model (not multiples of 128) and
+#: a multiple of 128.
+LEAF_COLS = [320, 960, 256]
+
+
+def _leaf_stack(n, rows, cols, dtype=jnp.float32):
+    x = jax.random.normal(jax.random.PRNGKey(n * rows + cols),
+                          (n, rows, cols), dtype)
+    m = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(cols), (n, n)),
+                       axis=-1)
+    return x, m, max(1, (n - 1) // 4)
+
+
+@pytest.mark.parametrize("n", [4, 17])
+@pytest.mark.parametrize("cols", LEAF_COLS)
+@pytest.mark.parametrize("rows", sorted(LEAF_ROWS))
+def test_leaf_view_gram_matches_ref(n, cols, rows):
+    """The slab Gram of a leaf view equals the oracle's Gram of the same
+    values to fp32 rounding, whatever its row blocks."""
+    r, block = LEAF_ROWS[rows]
+    x, _, _ = _leaf_stack(n, r, cols)
+    got = np.asarray(gram(x, block_d=block))
+    want = np.asarray(gram_ref(x))
+    # fp32 rounding of sums of d products: relative to the diagonal's scale
+    scale = np.abs(want).max()
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6 * scale)
+    np.testing.assert_allclose(got, np.asarray(gram(x.reshape(n, -1))),
+                               rtol=1e-5, atol=1e-6 * scale)
+
+
+@pytest.mark.parametrize("n,cols", [(4, c) for c in LEAF_COLS] + [(17, 320)])
+@pytest.mark.parametrize("rows", sorted(LEAF_ROWS))
+def test_leaf_view_mixtrim_matches_ref(n, cols, rows):
+    """Trim and median of a leaf view, static and dynamic f: without the
+    mix bit for bit the oracles (a sort of whole slabs reorders nothing
+    but ranks), with it within fp32 rounding of the oracle's dot; the
+    aggregate keeps the leaf's (R, C) shape."""
+    r, block = LEAF_ROWS[rows]
+    x, m, f = _leaf_stack(n, r, cols)
+    for mode in ("trim", "med"):
+        for mm in (m, None):
+            got = np.asarray(mixtrim(x, mm, f=f, mode=mode, block_d=block))
+            want = np.asarray(mixtrim_ref(x, mm, f, mode))
+            assert got.shape == (r, cols)
+            got_dyn = np.asarray(mixtrim_dyn(x, mm, jnp.int32(f), mode=mode,
+                                             block_d=block))
+            want_dyn = np.asarray(mixtrim_dyn_ref(x, mm, jnp.int32(f), mode))
+            if mm is None:
+                np.testing.assert_array_equal(got, want)
+                np.testing.assert_array_equal(got_dyn, want_dyn)
+            else:
+                np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+                np.testing.assert_allclose(got_dyn, want_dyn, rtol=1e-5,
+                                           atol=1e-5)
+
+
+@pytest.mark.parametrize("n", [4, 17])
+def test_leaf_view_mixtrim_dyn_vmap_lane_batch(n):
+    """The fleet's lane vmap over leaf views: each lane's dynamic-f
+    aggregate is its solo oracle's."""
+    xs = jax.random.normal(jax.random.PRNGKey(40 + n), (3, n, 12, 320))
+    m = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(41), (n, n)),
+                       axis=-1)
+    fs = jnp.asarray([0, 1, (n - 1) // 2], jnp.int32)
+    out = jax.vmap(lambda x, f: mixtrim_dyn(x, m, f, block_d=8))(xs, fs)
+    for k in range(3):
+        np.testing.assert_allclose(
+            np.asarray(out[k]), np.asarray(mixtrim_dyn_ref(xs[k], m, fs[k])),
+            rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("n", [4, 17])
+@pytest.mark.parametrize("rows", sorted(LEAF_ROWS))
+def test_leaf_view_combine_matches_ref(n, rows):
+    r, block = LEAF_ROWS[rows]
+    x, m, _ = _leaf_stack(n, r, 960)
+    got = np.asarray(combine(x, m[0], block_d=block))
+    assert got.shape == (r, 960)
+    np.testing.assert_allclose(got, np.asarray(combine_ref(x, m[0])),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_leaf_view_splits_a_wide_minor_dim():
+    """A leaf whose row tile overflows VMEM at its full width is walked in
+    lane blocks too (the ragged last one masked in the Gram)."""
+    n, r, c = 17, 9, 8000
+    assert tiling.pick_leaf_block(n, r, c)[1] < c
+    x, _, f = _leaf_stack(n, r, c)
+    want = np.asarray(gram_ref(x))
+    np.testing.assert_allclose(np.asarray(gram(x)), want, rtol=1e-5,
+                               atol=1e-6 * np.abs(want).max())
+    np.testing.assert_array_equal(np.asarray(mixtrim(x, None, f=f)),
+                                  np.asarray(mixtrim_ref(x, None, f)))
+
+
+@pytest.mark.parametrize("n", [4, 17])
+def test_sort_pairs_sort_every_order(n):
+    """The pruned odd-even merge network sorts n values for every
+    permutation of distinct ranks it is tried on."""
+    from repro.kernels.mixtrim.kernel import sort_pairs
+    rng = np.random.default_rng(n)
+    for _ in range(200):
+        v = list(rng.permutation(n))
+        for a, b in sort_pairs(n):
+            v[a], v[b] = min(v[a], v[b]), max(v[a], v[b])
+        assert v == sorted(v)
+
+
+def test_tall_leaf_view_streams_flat():
+    """Past ``tiling.leaf_view_fits`` (the leaf Gram's live slabs outgrow
+    the vreg budget) the single-device stack goes to the kernels as flat
+    (n, d_i) views, and aggregates as the leaf views do below it."""
+    from repro.kernels import dispatch as kdispatch
+    assert tiling.leaf_view_fits(4) and tiling.leaf_view_fits(5)
+    assert not tiling.leaf_view_fits(6) and not tiling.leaf_view_fits(17)
+    assert not tiling.leaf_view_fits(4, jnp.bfloat16)
+    assert tiling.gram_vregs(5) <= tiling.SLAB_VREGS < tiling.gram_vregs(6)
+    for n, view in ((5, 3), (6, 2), (17, 2)):
+        x, _, f = _leaf_stack(n, 3, 50)
+        segs, layout = kdispatch.split_worker_stack({"w": x})
+        assert segs[0].ndim == view
+        got = kdispatch.unflatten_aggregate(
+            [mixtrim(s, None, f=f) for s in segs], layout)["w"]
+        assert got.shape == (3, 50)
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(mixtrim_ref(x, None, f)))
+
+
+def test_round_bf16_matches_the_conversion():
+    """The leaf mixtrim's on-chip operand rounding is bf16's round to
+    nearest even, specials included."""
+    from repro.kernels.mixtrim.kernel import one_pass_operand
+    rng = np.random.default_rng(5)
+    x = np.concatenate([rng.normal(size=4096) * 10.0 ** rng.integers(
+        -30, 30, 4096), [np.nan, np.inf, -np.inf, 0.0, -0.0, 1 / 3]])
+    x = jnp.asarray(x, jnp.float32)
+    want = np.asarray(x.astype(jnp.bfloat16).astype(jnp.float32))
+    np.testing.assert_array_equal(np.asarray(one_pass_operand(x)), want)
+    assert float(one_pass_operand(jnp.float32(1 / 3))) == 0.333984375
+
+
+@pytest.mark.parametrize("mode", ["trim", "med"])
+@pytest.mark.parametrize("n,rows,cols", [(4, 20, 320), (5, 9, 960)])
+def test_leaf_mixtrim_one_pass_matches_bf16_operand_oracle(n, rows, cols,
+                                                           mode):
+    """The mix the chip runs (both operands rounded to bf16, products
+    summed in fp32), exercised in the interpreter: it matches the oracle
+    fed bf16-rounded M and X, and not the exact fp32 oracle, from which
+    M's rounding alone (1/3 -> 0.333984375) moves it."""
+    from repro.kernels.mixtrim.kernel import (_mixtrim_leaf_call,
+                                              one_pass_operand)
+    x, _, f = _leaf_stack(n, rows, cols)
+    m = jnp.full((n, n), 1.0 / (n - f), jnp.float32)
+    m = m * (jnp.arange(n)[None, :] < n - f)
+    got = np.asarray(_mixtrim_leaf_call(x, m, f, mode=mode, block_rows=None,
+                                        interpret=True, one_pass=True))
+    want = mixtrim_ref(one_pass_operand(x), one_pass_operand(m), f, mode)
+    np.testing.assert_allclose(got, np.asarray(want), rtol=1e-6, atol=1e-6)
+    exact = np.asarray(mixtrim_ref(x, m, f, mode))
+    assert np.abs(got - exact).max() > 1e-4
+    np.testing.assert_array_equal(
+        np.asarray(_mixtrim_leaf_call(x, m, f, mode=mode, block_rows=None,
+                                      interpret=True)),
+        np.asarray(_mixtrim_leaf_call(x, m, f, mode=mode, block_rows=None,
+                                      interpret=True, one_pass=False)))

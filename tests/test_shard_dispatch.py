@@ -150,6 +150,18 @@ out["n17_padded_noted"] = any("padded to 32" in d.reason
 out["n17_err"] = max(float(np.abs(a - b).max())
                      for a, b in zip(leaves(got17), leaves(ref17)))
 
+# one minor-swapped 3-D leaf: the flat (D,) aggregate of the sharded
+# buffer is row-major and must rebuild the leaf as it is
+tsw = {"w": jnp.asarray(rng.normal(size=(8, 200, 50)), jnp.float32)}
+sw_spec = AggregatorSpec(rule="cwtm", f=1, pre="nnm",
+                         backend="pallas_sharded")
+got_sw = robust_lib.robust_aggregate(tsw, sw_spec)
+out["swapped_leaf_backend"] = kd.last_dispatch().backend
+ref_sw = robust_lib.robust_aggregate(
+    tsw, AggregatorSpec(rule="cwtm", f=1, pre="nnm", backend="xla"))
+out["swapped_leaf_err"] = max(float(np.abs(a - b).max())
+                              for a, b in zip(leaves(got_sw), leaves(ref_sw)))
+
 print(json.dumps(out))
 """
 
@@ -212,6 +224,14 @@ def test_sharded_nonpow2_runs_fused_mixtrim(shard_run):
     assert shard_run["n17_fallbacks"] == []
     assert shard_run["n17_padded_noted"]
     assert shard_run["n17_err"] < 1e-4
+
+
+@pytest.mark.slow
+def test_sharded_single_swapped_leaf_matches_xla(shard_run):
+    """A tree of one minor-swapped 3-D leaf under the sharded backend: its
+    one (D,) aggregate rebuilds the leaf in row-major order."""
+    assert shard_run["swapped_leaf_backend"] == "pallas_sharded"
+    assert shard_run["swapped_leaf_err"] < 1e-4
 
 
 # ---------------------------------------------------------------------------
