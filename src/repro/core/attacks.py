@@ -441,14 +441,6 @@ def apply_attack_dyn(attack_id: Array, tree, f: Array, *, eta: Array):
     return jax.tree_util.tree_unflatten(treedef, out_leaves)
 
 
-def apply_attack_batched(attack_ids: Array, tree, fs: Array, *, etas: Array):
-    """Lane-batched stack attack: leaves carry a leading LANE axis, and
-    (family, f, eta) are per-lane vectors — `vmap` of `apply_attack_dyn`."""
-    return jax.vmap(
-        lambda aid, t, f, eta: apply_attack_dyn(aid, t, f, eta=eta),
-        in_axes=(0, 0, 0, 0))(attack_ids, tree, fs, etas)
-
-
 def _tree_eta_search(base: str, tree, nh: int, f: int, agg_closure, eta_grid):
     """Pick eta maximizing || F(attacked) - honest mean ||^2 over the tree."""
     honest = _tree_honest(tree, nh)

@@ -39,8 +39,15 @@ def default_bucket_size(n: int, f: int) -> int:
     return max(1, n // (2 * f))
 
 
-def clamp_bucket_size(n: int, s: int | None, f: int) -> int:
-    """Resolve + clamp a bucket size to [1, n] (shared by every path)."""
+def clamp_bucket_size(n: int, s: int | None, f) -> int:
+    """Resolve + clamp a bucket size to [1, n] (shared by every path).
+
+    The floor(n / 2f) default is shape material, so a traced f needs an
+    explicit ``s``."""
+    if s is None and not isinstance(f, int):
+        raise ValueError(
+            "a traced f needs an explicit bucket_size (the floor(n/2f) "
+            "default is shape-level); set AggregatorSpec.bucket_size")
     s = s if s is not None else default_bucket_size(n, f)
     return max(1, min(int(s), n))
 
@@ -85,18 +92,16 @@ def bucket_matrix(key: Array, n: int, s: int,
     return b.astype(dtype)
 
 
-def adjusted_f(f: int, n_buckets: int) -> int:
-    """Downstream Byzantine budget after bucketing (static form).
+def adjusted_f(f, n_buckets: int):
+    """Downstream Byzantine budget after bucketing.
 
     Each Byzantine input contaminates at most one bucket, so f carries over
     unchanged — capped so the downstream rule still satisfies
-    f' < n_buckets / 2 (exactly the paper's Observation 2 trade-off)."""
-    return min(f, max(0, (n_buckets - 1) // 2)) if f else 0
-
-
-def adjusted_f_dyn(f: Array, n_buckets: int) -> Array:
-    """:func:`adjusted_f` for a TRACED int32 f (fleet lanes)."""
+    f' < n_buckets / 2 (exactly the paper's Observation 2 trade-off).  A
+    python int stays one; a traced f (fleet lanes) gives a traced int32."""
     cap = max(0, (n_buckets - 1) // 2)
+    if isinstance(f, int):
+        return min(f, cap) if f else 0
     return jnp.minimum(jnp.asarray(f, jnp.int32), cap)
 
 

@@ -42,9 +42,19 @@ def mixed_gram(g: Array, m: Array) -> Array:
 
 # ---------------------------------------------------------------------------
 # Neighbor selection / scoring (all O(n^2) replicated math).
+#
+# f is a python int or a TRACED int32 scalar (a fleet lane's budget, so one
+# compiled round serves every f).  Shapes stay static either way: an int
+# selects through static top_k slices, a tracer through rank masks.
 # ---------------------------------------------------------------------------
 
-def nnm_matrix(d2: Array, f: int) -> Array:
+def _row_ranks(d2: Array) -> Array:
+    """rank[i, j] = position of j in ascending order of row i (0 = nearest)."""
+    order = jnp.argsort(d2, axis=1)
+    return jnp.argsort(order, axis=1)
+
+
+def nnm_matrix(d2: Array, f) -> Array:
     """NNM mixing matrix from squared distances.
 
     Row i averages the n-f nearest neighbors of x_i (itself included, since
@@ -52,6 +62,9 @@ def nnm_matrix(d2: Array, f: int) -> Array:
     such that Y = M @ X is the NNM output (paper Alg. 2).
     """
     n = d2.shape[0]
+    if not isinstance(f, int):
+        mask = (_row_ranks(d2) < (n - f)).astype(jnp.float32)
+        return mask / (n - f).astype(jnp.float32)
     k = n - f
     # Indices of the k smallest distances per row.
     _, idx = jax.lax.top_k(-d2, k)
@@ -59,7 +72,18 @@ def nnm_matrix(d2: Array, f: int) -> Array:
     return mask / float(k)
 
 
-def krum_coeff(d2: Array, f: int) -> Array:
+def _krum_scores(d2: Array, f) -> Array:
+    """Sum of the n-f smallest distances per candidate row."""
+    n = d2.shape[0]
+    if not isinstance(f, int):
+        srt = jnp.sort(d2, axis=1)
+        keep = (jnp.arange(n)[None, :] < (n - f)).astype(jnp.float32)
+        return (srt * keep).sum(axis=1)
+    neigh, _ = jax.lax.top_k(-d2, n - f)   # negated distances, k smallest
+    return -neigh.sum(axis=1)
+
+
+def krum_coeff(d2: Array, f) -> Array:
     """One-hot selection vector for (our adaptation of) Krum.
 
     Scores each candidate j by the sum of squared distances to its n-f
@@ -67,18 +91,19 @@ def krum_coeff(d2: Array, f: int) -> Array:
     argmin.  Output c satisfies Krum(x) = c @ x.
     """
     n = d2.shape[0]
-    k = n - f
-    neigh, _ = jax.lax.top_k(-d2, k)   # negated distances, k smallest
-    scores = -neigh.sum(axis=1)
+    scores = _krum_scores(d2, f)
     return jax.nn.one_hot(jnp.argmin(scores), n, dtype=jnp.float32)
 
 
-def multikrum_coeff(d2: Array, f: int) -> Array:
+def multikrum_coeff(d2: Array, f) -> Array:
     """Multi-Krum: average of the n-f best Krum-scoring candidates."""
     n = d2.shape[0]
+    scores = _krum_scores(d2, f)
+    if not isinstance(f, int):
+        rank = jnp.argsort(jnp.argsort(scores))
+        sel = (rank < (n - f)).astype(jnp.float32)
+        return sel / (n - f).astype(jnp.float32)
     k = n - f
-    neigh, _ = jax.lax.top_k(-d2, k)
-    scores = -neigh.sum(axis=1)
     _, best = jax.lax.top_k(-scores, k)
     c = jax.nn.one_hot(best, n, dtype=jnp.float32).sum(axis=0)
     return c / float(k)
@@ -196,6 +221,9 @@ def mda_coeff(d2: Array, f: int) -> Array:
     f<=8); greedy diameter pruning beyond (iteratively drop the point with
     the largest max-distance) — documented in DESIGN.md.
     """
+    if not isinstance(f, int):
+        raise ValueError("mda has no traced-f form (subset enumeration is "
+                         "shape-level); use a python int f or another rule")
     n = d2.shape[0]
     import math
     if f == 0:
@@ -219,10 +247,12 @@ def mda_coeff(d2: Array, f: int) -> Array:
     return alive / alive.sum()
 
 
-def coeff_for_rule(rule: str, g: Array, f: int, *, gm_iters: int = 8,
+def coeff_for_rule(rule: str, g: Array, f, *, gm_iters: int = 8,
                    gm_eps: float = 1e-8, autogm_lamb: float = 1.0,
                    autogm_iters: int = 4) -> Array:
-    """Dispatch: Gram matrix -> linear-combination coefficients."""
+    """Dispatch: Gram matrix -> linear-combination coefficients.
+
+    GM and AutoGM never read f; MDA needs a python int."""
     n = g.shape[0]
     if rule == "average":
         return jnp.full((n,), 1.0 / n, dtype=jnp.float32)
@@ -238,81 +268,4 @@ def coeff_for_rule(rule: str, g: Array, f: int, *, gm_iters: int = 8,
         return multikrum_coeff(d2, f)
     if rule == "mda":
         return mda_coeff(d2, f)
-    raise ValueError(f"{rule!r} is not a gram-space rule")
-
-
-# ---------------------------------------------------------------------------
-# Dynamic-f variants (fleet engine): f is a TRACED int32 scalar, so one
-# compiled round serves lanes with different Byzantine budgets.  Shapes stay
-# static; selection happens through rank masks instead of top_k slices.
-# ---------------------------------------------------------------------------
-
-def _row_ranks(d2: Array) -> Array:
-    """rank[i, j] = position of j in ascending order of row i (0 = nearest)."""
-    order = jnp.argsort(d2, axis=1)
-    return jnp.argsort(order, axis=1)
-
-
-def nnm_matrix_dyn(d2: Array, f: Array) -> Array:
-    """`nnm_matrix` with a traced Byzantine count.
-
-    Row i averages the n-f nearest neighbors of x_i, selected by a rank
-    mask (rank < n-f) instead of a static top_k, so f may differ per jit
-    invocation / per vmapped lane without recompiling.
-    """
-    n = d2.shape[0]
-    k = (n - f).astype(jnp.float32)
-    mask = (_row_ranks(d2) < (n - f)).astype(jnp.float32)
-    return mask / k
-
-
-def _krum_scores_dyn(d2: Array, f: Array) -> Array:
-    """Sum of the n-f smallest distances per candidate row, traced f."""
-    n = d2.shape[0]
-    srt = jnp.sort(d2, axis=1)
-    keep = (jnp.arange(n)[None, :] < (n - f)).astype(jnp.float32)
-    return (srt * keep).sum(axis=1)
-
-
-def krum_coeff_dyn(d2: Array, f: Array) -> Array:
-    """`krum_coeff` with traced f (same argmin selection, masked scoring)."""
-    n = d2.shape[0]
-    scores = _krum_scores_dyn(d2, f)
-    return jax.nn.one_hot(jnp.argmin(scores), n, dtype=jnp.float32)
-
-
-def multikrum_coeff_dyn(d2: Array, f: Array) -> Array:
-    """`multikrum_coeff` with traced f: average the n-f best-scoring rows."""
-    n = d2.shape[0]
-    scores = _krum_scores_dyn(d2, f)
-    rank = jnp.argsort(jnp.argsort(scores))
-    sel = (rank < (n - f)).astype(jnp.float32)
-    return sel / (n - f).astype(jnp.float32)
-
-
-def coeff_for_rule_dyn(rule: str, g: Array, f: Array, *, gm_iters: int = 8,
-                       gm_eps: float = 1e-8, autogm_lamb: float = 1.0,
-                       autogm_iters: int = 4) -> Array:
-    """`coeff_for_rule` with a traced f (rule itself stays static).
-
-    MDA is excluded: its exact form enumerates (n-f)-subsets, whose count is
-    shape-level and cannot be traced.  GM and AutoGM never read f, so their
-    static solvers serve the dynamic path directly.
-    """
-    n = g.shape[0]
-    if rule == "average":
-        return jnp.full((n,), 1.0 / n, dtype=jnp.float32)
-    if rule == "gm":
-        return gm_coeff(g, 0, iters=gm_iters, eps=gm_eps)
-    if rule == "autogm":
-        return autogm_coeff(g, 0, lamb=autogm_lamb, outer_iters=autogm_iters,
-                            gm_iters=gm_iters, gm_eps=gm_eps)
-    d2 = pdist_sq_from_gram(g)
-    if rule == "krum":
-        return krum_coeff_dyn(d2, f)
-    if rule == "multikrum":
-        return multikrum_coeff_dyn(d2, f)
-    if rule == "mda":
-        raise ValueError("mda has no dynamic-f form (subset enumeration is "
-                         "shape-level); use the static path or another rule")
     raise ValueError(f"{rule!r} is not a gram-space rule")

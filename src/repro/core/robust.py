@@ -43,16 +43,15 @@ import jax.numpy as jnp
 
 from repro.core.bucketing import (
     adjusted_f as _adjusted_f,
-    adjusted_f_dyn as _adjusted_f_dyn,
     bucket_counts as _bucket_counts,
     bucket_matrix as _bucket_matrix,
     clamp_bucket_size as _clamp_bucket_size,
-    default_bucket_size as _default_bucket_size,
     num_buckets as _num_buckets,
 )
 from repro.core import gram as gramlib
 from repro.core.types import AggregatorSpec, COORDINATE_RULES, GRAM_RULES
 from repro.kernels import dispatch as kdispatch
+from repro.kernels.mixtrim.ref import coordinate_rule
 from repro.obs import stages
 
 Array = jax.Array
@@ -127,46 +126,15 @@ def tree_mix(tree: PyTree, m: Array) -> PyTree:
     return jax.tree_util.tree_map(mix, tree)
 
 
-def _tree_coordinate_rule(tree: PyTree, rule: str, f: int,
-                          internals: Optional[dict] = None) -> PyTree:
-    """Apply a coordinate-wise rule along the worker axis of every leaf.
-
-    ``internals`` (taps support, see :mod:`repro.obs.taps`): when a dict is
-    passed, cwtm stashes its per-leaf sorted stacks under
-    ``"sorted_leaves"`` (tree_leaves order) so diagnostics reuse the sort
-    instead of re-emitting it."""
-    def apply(leaf):
-        n = leaf.shape[0]
-        x = leaf.astype(jnp.float32)
-        if rule == "cwmed":
-            out = jnp.median(x, axis=0)
-        elif rule == "cwtm":
-            if f == 0:
-                out = x.mean(axis=0)
-            else:
-                xs = jnp.sort(x, axis=0)
-                if internals is not None:
-                    internals.setdefault("sorted_leaves", []).append(xs)
-                out = jax.lax.slice_in_dim(xs, f, n - f, axis=0).mean(axis=0)
-        elif rule == "meamed":
-            med = jnp.median(x, axis=0, keepdims=True)
-            order = jnp.argsort(jnp.abs(x - med), axis=0)
-            xs = jnp.take_along_axis(x, order, axis=0)
-            out = jax.lax.slice_in_dim(xs, 0, n - f, axis=0).mean(axis=0)
-        else:
-            raise ValueError(rule)
-        return out
-    return jax.tree_util.tree_map(apply, tree)
-
-
-def _tree_bucket(tree: PyTree, f: int, key: Array,
-                 bucket_size: Optional[int]) -> tuple[PyTree, int]:
+def _tree_bucket(tree: PyTree, f, key: Array,
+                 bucket_size: Optional[int]) -> tuple[PyTree, Any]:
     """Bucketing on pytrees: one shared permutation across all leaves.
 
     Ragged tails are handled exactly (paper: n=17, s=2 -> 9 buckets, one
     singleton): zero-pad and renormalize by true bucket occupancy.
     Dtype-preserving like :func:`repro.core.bucketing.bucketing`: means
-    accumulate in (at least) fp32 and cast back to each leaf's dtype."""
+    accumulate in (at least) fp32 and cast back to each leaf's dtype.
+    A traced f needs an explicit ``bucket_size``."""
     leaves = jax.tree_util.tree_leaves(tree)
     n = leaves[0].shape[0]
     s = _clamp_bucket_size(n, bucket_size, f)
@@ -208,23 +176,11 @@ def _validate_hier(spec: AggregatorSpec) -> None:
             "bucketgram kernel already removes the wide gram pass)")
 
 
-def _hier_bucket_size(spec: AggregatorSpec, n: int, f, *, dyn: bool) -> int:
-    """Resolve the hierarchical bucket size (static shape material)."""
-    if dyn:
-        if spec.bucket_size is None:
-            raise ValueError(
-                "dynamic-f hierarchical aggregation needs an explicit "
-                "bucket_size (the floor(n/2f) default is shape-level); set "
-                "AggregatorSpec.bucket_size")
-        return max(1, min(int(spec.bucket_size), n))
-    return _clamp_bucket_size(n, spec.bucket_size, f)
-
-
 _HIER_S1_NOTE = "s=1: singleton buckets, identity reduction (skipped)"
 
 
 def _hier_reduce(segs: list, layout, spec: AggregatorSpec, f, *,
-                 key: Optional[Array], dyn: bool, backend: str,
+                 key: Optional[Array], backend: str,
                  mesh, worker_axis: Optional[str], axis: Optional[str]
                  ) -> tuple[list, Any, Optional[Array]]:
     """The hierarchical pre-reduction of the stack's segments (leaf views
@@ -241,7 +197,7 @@ def _hier_reduce(segs: list, layout, spec: AggregatorSpec, f, *,
     n = segs[0].shape[0]
     if key is None:
         raise ValueError("hierarchical aggregation requires a PRNG key")
-    s = _hier_bucket_size(spec, n, f, dyn=dyn)
+    s = _clamp_bucket_size(n, spec.bucket_size, f)
     if s == 1:
         kdispatch.record_decision("bucketgram", backend, "skipped",
                                   _HIER_S1_NOTE)
@@ -255,13 +211,12 @@ def _hier_reduce(segs: list, layout, spec: AggregatorSpec, f, *,
     y, g = kdispatch.dispatch_bucketgram(
         flat, bmat, backend=backend, with_gram=need_gram, mesh=mesh,
         worker_axis=worker_axis, axis=axis)
-    f_adj = _adjusted_f_dyn(f, n_b) if dyn else _adjusted_f(f, n_b)
-    return [y], f_adj, g
+    return [y], _adjusted_f(f, n_b), g
 
 
 def _aggregate_flat(work: PyTree, spec: AggregatorSpec, f, *,
                     key: Optional[Array], return_coeff: bool,
-                    dyn: bool, backend: str = "pallas",
+                    backend: str = "pallas",
                     mesh_ctx: Optional[tuple] = None,
                     internals: Optional[dict] = None,
                     hier: bool = False) -> PyTree:
@@ -280,9 +235,9 @@ def _aggregate_flat(work: PyTree, spec: AggregatorSpec, f, *,
     "pallas_hier" (``mesh_ctx`` = (mesh, worker_axis | None, model_axis);
     the stack shards along workers x D and the fused bucketgram kernel
     reduces it before everything below runs on the ceil(n/s) population).
-    ``f`` is a python int when ``dyn=False`` and a traced int32 scalar
-    when ``dyn=True`` (the fleet path; rank-mask kernels keep one compile
-    per shape bucket).  Decisions land on ``kdispatch.last_dispatch()``.
+    ``f`` is a python int or a traced int32 scalar (the fleet path;
+    rank-mask kernels keep one compile per shape bucket).  Decisions land
+    on ``kdispatch.last_dispatch()``.
     """
     if backend == "pallas":
         segs, layout = kdispatch.split_worker_stack(work)
@@ -301,7 +256,7 @@ def _aggregate_flat(work: PyTree, spec: AggregatorSpec, f, *,
         # consumer follows) its Gram in the same pass — the gram stage
         # below is skipped.
         segs, f, g = _hier_reduce(
-            segs, layout, spec, f, key=key, dyn=dyn, backend=backend,
+            segs, layout, spec, f, key=key, backend=backend,
             mesh=mesh, worker_axis=worker_axis, axis=axis)
 
     mix_matrix = None
@@ -325,8 +280,7 @@ def _aggregate_flat(work: PyTree, spec: AggregatorSpec, f, *,
 
     if spec.pre == "nnm":
         d2 = gramlib.pdist_sq_from_gram(g)
-        mix_matrix = gramlib.nnm_matrix_dyn(d2, f) if dyn \
-            else gramlib.nnm_matrix(d2, f)
+        mix_matrix = gramlib.nnm_matrix(d2, f)
         if internals is not None:
             internals["mix_matrix"] = mix_matrix
         g = gramlib.mixed_gram(g, mix_matrix)
@@ -342,14 +296,9 @@ def _aggregate_flat(work: PyTree, spec: AggregatorSpec, f, *,
                 "autogm_coeff", backend, "xla",
                 "autogm adaptive-weight solve is replicated gram-space "
                 "math with no kernel form")
-        if dyn:
-            coeff = gramlib.coeff_for_rule_dyn(
-                spec.rule, g, f, gm_iters=spec.gm_iters, gm_eps=spec.gm_eps,
-                autogm_lamb=spec.autogm_lamb, autogm_iters=spec.autogm_iters)
-        else:
-            coeff = gramlib.coeff_for_rule(
-                spec.rule, g, f, gm_iters=spec.gm_iters, gm_eps=spec.gm_eps,
-                autogm_lamb=spec.autogm_lamb, autogm_iters=spec.autogm_iters)
+        coeff = gramlib.coeff_for_rule(
+            spec.rule, g, f, gm_iters=spec.gm_iters, gm_eps=spec.gm_eps,
+            autogm_lamb=spec.autogm_lamb, autogm_iters=spec.autogm_iters)
         if mix_matrix is not None:
             coeff = coeff @ mix_matrix   # R = c^T (M X) = (c^T M) X
         vecs = [kdispatch.dispatch_combine(x, coeff, backend=backend,
@@ -378,13 +327,13 @@ def _aggregate_flat(work: PyTree, spec: AggregatorSpec, f, *,
             # shard-local under the sharded backend, per segment
             # otherwise.  Recorded so kernel-path callers see it.
             vecs = [kdispatch.dispatch_meamed(x, mix_for(x), f,
-                                              backend=backend, dyn=dyn,
+                                              backend=backend,
                                               mesh=mesh, axis=axis)
                     for x in segs]
         else:
             mode = "med" if spec.rule == "cwmed" else "trim"
             vecs = [kdispatch.dispatch_mixtrim(x, mix_for(x), f, mode=mode,
-                                               backend=backend, dyn=dyn,
+                                               backend=backend,
                                                mesh=mesh, axis=axis)
                     for x in segs]
         out = kdispatch.unflatten_aggregate(vecs, layout)
@@ -393,11 +342,11 @@ def _aggregate_flat(work: PyTree, spec: AggregatorSpec, f, *,
     raise ValueError(f"unknown rule {spec.rule!r}")
 
 
-def _open_routed_record(spec: AggregatorSpec, *, dyn: bool
+def _open_routed_record(spec: AggregatorSpec, f
                         ) -> tuple[str, Optional[tuple]]:
-    """Resolve the backend (+ shard mesh), open the dispatch record, and
-    record a degrade when "pallas_sharded" / "pallas_hier" has no
-    multi-device mesh.
+    """Resolve the backend (+ shard mesh), open the dispatch record (its
+    ``dyn`` says whether ``f`` is traced), and record a degrade when
+    "pallas_sharded" / "pallas_hier" has no multi-device mesh.
 
     Returns (effective backend, mesh_ctx) where mesh_ctx is the resolved
     (mesh, axis) for the sharded backend, (mesh, worker_axis, model_axis)
@@ -430,11 +379,12 @@ def _open_routed_record(spec: AggregatorSpec, *, dyn: bool
     else:
         mesh_devices = kdispatch.shardlib.axis_size(*mesh_ctx)
         mesh_axis, worker_axis = mesh_ctx[1], None
-    kdispatch.open_record(
+    rec = kdispatch.open_record(
         requested=spec.backend, backend=backend, rule=spec.rule,
-        pre=spec.pre, dyn=dyn, mesh_devices=mesh_devices,
+        pre=spec.pre, mesh_devices=mesh_devices,
         mesh_axis=mesh_axis, hier=hier, bucket_size=spec.bucket_size,
         mesh_worker_axis=worker_axis)
+    rec.dyn = not isinstance(f, int)
     if degraded is not None:
         kdispatch.record_decision("pipeline", degraded[0], "xla",
                                   degraded[1])
@@ -452,12 +402,21 @@ def _aggregate_stage(fn):
 
 
 @_aggregate_stage
-def robust_aggregate(tree: PyTree, spec: AggregatorSpec, *,
+def robust_aggregate(tree: PyTree, spec: AggregatorSpec, *, f=None,
                      key: Optional[Array] = None,
                      return_coeff: bool = False,
                      internals: Optional[dict] = None) -> PyTree:
     """Full distributed pipeline: pre-aggregation + rule on a worker-stacked
     pytree.  Returns the aggregated pytree (worker axis removed).
+
+    ``f`` is the Byzantine count, ``spec.f`` when None: a python int, or a
+    TRACED int32 scalar (a fleet lane's budget, possibly a vmap tracer) so
+    that one compiled aggregation serves every budget.  It is given here,
+    never inside ``spec``, which is hashed as jit key material.  The
+    rule, pre-aggregation and bucket size stay static; with a traced f,
+    trimming and neighbor selection go through rank masks instead of
+    static slices, bucketing needs an explicit ``spec.bucket_size`` (the
+    floor(n/2f) default is shape-level), and MDA has no traced form.
 
     With ``return_coeff=True`` additionally returns the effective linear
     coefficient vector when one exists (gram rules), else None — used by the
@@ -476,7 +435,10 @@ def robust_aggregate(tree: PyTree, spec: AggregatorSpec, *,
     Execution routes through the kernel backend layer per
     ``spec.backend`` (see :mod:`repro.kernels.dispatch`).
     """
-    f = spec.f
+    if f is None:
+        f = spec.f
+    elif not isinstance(f, int):
+        f = jnp.asarray(f, jnp.int32)
     work = tree
     mix_matrix = None
     hier = _hier_active(spec)
@@ -494,10 +456,10 @@ def robust_aggregate(tree: PyTree, spec: AggregatorSpec, *,
         work = jax.tree_util.tree_map(
             lambda l: l.astype(jnp.bfloat16), work)
 
-    backend, mesh_ctx = _open_routed_record(spec, dyn=False)
+    backend, mesh_ctx = _open_routed_record(spec, f)
     if backend in ("pallas", "pallas_sharded", "pallas_hier"):
         return _aggregate_flat(work, spec, f, key=key,
-                               return_coeff=return_coeff, dyn=False,
+                               return_coeff=return_coeff,
                                backend=backend, mesh_ctx=mesh_ctx,
                                internals=internals, hier=hier)
     kdispatch.record_decision("pipeline", "xla", "xla",
@@ -509,7 +471,7 @@ def robust_aggregate(tree: PyTree, spec: AggregatorSpec, *,
         if key is None:
             raise ValueError("hierarchical aggregation requires a PRNG key")
         n = jax.tree_util.tree_leaves(work)[0].shape[0]
-        s = _hier_bucket_size(spec, n, f, dyn=False)
+        s = _clamp_bucket_size(n, spec.bucket_size, f)
         if s == 1:
             kdispatch.record_decision("bucketgram", "xla", "skipped",
                                       _HIER_S1_NOTE)
@@ -548,182 +510,15 @@ def robust_aggregate(tree: PyTree, spec: AggregatorSpec, *,
             work = tree_mix(work, mix_matrix)
             if internals is not None:
                 internals["mixed"] = work
-        out = _tree_coordinate_rule(work, spec.rule, f, internals=internals)
+        sorted_leaves = [] if internals is not None else None
+        out = jax.tree_util.tree_map(
+            lambda leaf: coordinate_rule(leaf, spec.rule, f, sorted_leaves),
+            work)
+        if sorted_leaves:
+            # cwtm's per-leaf sorted stacks, for the taps to reuse.
+            internals["sorted_leaves"] = sorted_leaves
         if return_coeff:
             return out, None
         return out
 
     raise ValueError(f"unknown rule {spec.rule!r}")
-
-
-# ---------------------------------------------------------------------------
-# Dynamic-f pipeline (fleet engine): `f` is a TRACED int32 scalar so one
-# compiled aggregation serves lanes with different Byzantine budgets.  The
-# rule / pre-aggregation / bucket size stay static (shape-bucket key
-# material); trimming and neighbor selection go through rank masks instead
-# of static slices.  `batched_robust_aggregate` vmaps this over a leading
-# lane axis.
-# ---------------------------------------------------------------------------
-
-def _tree_coordinate_rule_dyn(tree: PyTree, rule: str, f: Array,
-                              internals: Optional[dict] = None) -> PyTree:
-    """Coordinate-wise rules with a traced trim count."""
-    def apply(leaf):
-        n = leaf.shape[0]
-        x = leaf.astype(jnp.float32)
-        if rule == "cwmed":
-            return jnp.median(x, axis=0)
-        i = jnp.arange(n).reshape((-1,) + (1,) * (leaf.ndim - 1))
-        if rule == "cwtm":
-            xs = jnp.sort(x, axis=0)
-            if internals is not None:
-                internals.setdefault("sorted_leaves", []).append(xs)
-            keep = ((i >= f) & (i < n - f)).astype(jnp.float32)
-            return (xs * keep).sum(axis=0) / jnp.maximum(
-                (n - 2 * f).astype(jnp.float32), 1.0)
-        if rule == "meamed":
-            med = jnp.median(x, axis=0, keepdims=True)
-            order = jnp.argsort(jnp.abs(x - med), axis=0)
-            xs = jnp.take_along_axis(x, order, axis=0)
-            keep = (i < n - f).astype(jnp.float32)
-            return (xs * keep).sum(axis=0) / jnp.maximum(
-                (n - f).astype(jnp.float32), 1.0)
-        raise ValueError(rule)
-    return jax.tree_util.tree_map(apply, tree)
-
-
-def _tree_bucket_dyn(tree: PyTree, f: Array, key: Array,
-                     bucket_size: int) -> tuple[PyTree, Array]:
-    """`_tree_bucket` with a traced f.
-
-    The bucket size must be given explicitly: the paper default
-    floor(n / 2f) is shape-level and cannot depend on a traced f.
-    """
-    leaves = jax.tree_util.tree_leaves(tree)
-    n = leaves[0].shape[0]
-    s = max(1, min(int(bucket_size), n))
-    perm = jax.random.permutation(key, n)
-    n_buckets = _num_buckets(n, s)
-    pad = n_buckets * s - n
-    counts = _bucket_counts(n, s)
-
-    def bucket(leaf):
-        acc = jnp.promote_types(leaf.dtype, jnp.float32)
-        x = leaf[perm].astype(acc)
-        if pad:
-            x = jnp.concatenate(
-                [x, jnp.zeros((pad,) + leaf.shape[1:], acc)])
-        sums = x.reshape((n_buckets, s) + leaf.shape[1:]).sum(axis=1)
-        means = sums / counts.astype(acc).reshape(
-            (n_buckets,) + (1,) * (leaf.ndim - 1))
-        return means.astype(leaf.dtype)
-
-    return jax.tree_util.tree_map(bucket, tree), _adjusted_f_dyn(f, n_buckets)
-
-
-@_aggregate_stage
-def robust_aggregate_dyn(tree: PyTree, spec: AggregatorSpec, f: Array, *,
-                         key: Optional[Array] = None,
-                         internals: Optional[dict] = None) -> PyTree:
-    """`robust_aggregate` with a TRACED Byzantine count.
-
-    ``spec.f`` is ignored; ``f`` (an int32 scalar, possibly a vmap tracer)
-    takes its place.  ``spec.pre == "bucketing"`` requires an explicit
-    ``spec.bucket_size``.  MDA has no dynamic form (see
-    :func:`repro.core.gram.coeff_for_rule_dyn`).  ``internals`` as in
-    :func:`robust_aggregate`.
-    """
-    f = jnp.asarray(f, jnp.int32)
-    work = tree
-    mix_matrix = None
-    hier = _hier_active(spec)
-    if hier:
-        _validate_hier(spec)
-
-    if spec.pre == "bucketing":
-        if key is None:
-            raise ValueError("bucketing requires a PRNG key")
-        if spec.bucket_size is None:
-            raise ValueError(
-                "dynamic-f bucketing needs an explicit bucket_size (the "
-                "floor(n/2f) default is shape-level); set "
-                "AggregatorSpec.bucket_size")
-        work, f = _tree_bucket_dyn(work, f, key, spec.bucket_size)
-
-    if spec.transport_dtype == "bf16":
-        work = jax.tree_util.tree_map(
-            lambda l: l.astype(jnp.bfloat16), work)
-
-    backend, mesh_ctx = _open_routed_record(spec, dyn=True)
-    if backend in ("pallas", "pallas_sharded", "pallas_hier"):
-        return _aggregate_flat(work, spec, f, key=key, return_coeff=False,
-                               dyn=True, backend=backend, mesh_ctx=mesh_ctx,
-                               internals=internals, hier=hier)
-    kdispatch.record_decision("pipeline", "xla", "xla",
-                              "leaf-streamed jnp path (GSPMD-friendly)")
-
-    if hier:
-        if key is None:
-            raise ValueError("hierarchical aggregation requires a PRNG key")
-        n = jax.tree_util.tree_leaves(work)[0].shape[0]
-        s = _hier_bucket_size(spec, n, f, dyn=True)
-        if s == 1:
-            kdispatch.record_decision("bucketgram", "xla", "skipped",
-                                      _HIER_S1_NOTE)
-        else:
-            kdispatch.record_decision(
-                "bucketgram", "xla", "xla",
-                "dense leaf-streamed bucketing (gather form)")
-            work, f = _tree_bucket_dyn(work, f, key, s)
-
-    if spec.sketch_dim and key is not None:
-        g = tree_sketch_gram(work, spec.sketch_dim, key)
-    else:
-        g = tree_gram(work)
-
-    if spec.pre == "nnm":
-        d2 = gramlib.pdist_sq_from_gram(g)
-        mix_matrix = gramlib.nnm_matrix_dyn(d2, f)
-        if internals is not None:
-            internals["mix_matrix"] = mix_matrix
-        g = gramlib.mixed_gram(g, mix_matrix)
-
-    if spec.rule in GRAM_RULES:
-        coeff = gramlib.coeff_for_rule_dyn(spec.rule, g, f,
-                                           gm_iters=spec.gm_iters,
-                                           gm_eps=spec.gm_eps,
-                                           autogm_lamb=spec.autogm_lamb,
-                                           autogm_iters=spec.autogm_iters)
-        if mix_matrix is not None:
-            coeff = coeff @ mix_matrix
-        return tree_combine(work, coeff)
-
-    if spec.rule in COORDINATE_RULES:
-        if mix_matrix is not None:
-            work = tree_mix(work, mix_matrix)
-            if internals is not None:
-                internals["mixed"] = work
-        return _tree_coordinate_rule_dyn(work, spec.rule, f,
-                                         internals=internals)
-
-    raise ValueError(f"unknown rule {spec.rule!r}")
-
-
-def batched_robust_aggregate(tree: PyTree, spec: AggregatorSpec, fs: Array,
-                             *, keys: Optional[Array] = None) -> PyTree:
-    """Lane-batched aggregation: every leaf carries a leading lane axis and
-    ``fs`` is the per-lane Byzantine count — `vmap` of the dynamic path."""
-    if keys is None:
-        return jax.vmap(lambda t, f: robust_aggregate_dyn(t, spec, f),
-                        in_axes=(0, 0))(tree, fs)
-    return jax.vmap(
-        lambda t, f, k: robust_aggregate_dyn(t, spec, f, key=k),
-        in_axes=(0, 0, 0))(tree, fs, keys)
-
-
-def flatten_stack(tree: PyTree) -> Array:
-    """Debug/test helper: concatenate a worker-stacked pytree to (n, D)."""
-    leaves = jax.tree_util.tree_leaves(tree)
-    n = leaves[0].shape[0]
-    return jnp.concatenate([l.reshape(n, -1).astype(jnp.float32) for l in leaves],
-                           axis=1)

@@ -201,13 +201,17 @@ def resilience_lower_bound(n: int, f: int, g_sq: float) -> float:
     return f / (4.0 * (n - 2 * f)) * g_sq
 
 
-def tree_kappa_hat(agg, stack, n_honest: int, internals=None):
+def tree_kappa_hat(agg, stack, n_honest, internals=None):
     """Paper Eq. (26) over worker-stacked pytrees, leaf-streamed in fp32.
 
     ``stack`` leaves carry a leading worker axis; the first ``n_honest``
     rows are the honest workers.  This is the shared estimator of the
-    lockstep trainer and the fed server (both record it per round/step);
-    :func:`empirical_kappa_hat` below is the single-(n, d)-stack form.
+    lockstep trainer, the fed server and the fleet lanes (all record it
+    per round/step); :func:`empirical_kappa_hat` below is the
+    single-(n, d)-stack form.  A python-int ``n_honest`` slices the honest
+    rows; a TRACED one (a fleet lane's count) selects them by a mask
+    (row < n_honest), so lanes with different Byzantine budgets share one
+    compiled round.
 
     ``internals`` (taps support, see :mod:`repro.obs.taps`): when a dict
     is passed, the squared distance ``num`` and the per-leaf honest means
@@ -216,14 +220,29 @@ def tree_kappa_hat(agg, stack, n_honest: int, internals=None):
     """
     num = jnp.zeros((), jnp.float32)
     den = jnp.zeros((), jnp.float32)
+    static = isinstance(n_honest, int)
+    if not static:
+        cnt = jnp.maximum(n_honest.astype(jnp.float32), 1.0)
     for a, s in zip(jax.tree_util.tree_leaves(agg),
                     jax.tree_util.tree_leaves(stack)):
-        h = s[:n_honest].astype(jnp.float32)
-        mbar = h.mean(axis=0)
+        if static:
+            h = s[:n_honest].astype(jnp.float32)
+            mbar = h.mean(axis=0)
+        else:
+            x = s.astype(jnp.float32)
+            n = x.shape[0]
+            w = (jnp.arange(n) < n_honest).astype(jnp.float32)
+            wl = w.reshape((-1,) + (1,) * (x.ndim - 1))
+            mbar = (x * wl).sum(axis=0) / cnt
         if internals is not None:
             internals.setdefault("honest_mean_leaves", []).append(mbar)
         num += jnp.sum((a.astype(jnp.float32) - mbar) ** 2)
-        den += jnp.mean(jnp.sum((h - mbar).reshape(n_honest, -1) ** 2, axis=1))
+        if static:
+            den += jnp.mean(jnp.sum((h - mbar).reshape(n_honest, -1) ** 2,
+                                    axis=1))
+        else:
+            sq = jnp.sum(((x - mbar) ** 2).reshape(n, -1), axis=1)
+            den += (sq * w).sum() / cnt
     if internals is not None:
         internals["honest_sq_dist"] = num
     return jnp.sqrt(num / (den + 1e-20))

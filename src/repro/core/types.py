@@ -35,7 +35,7 @@ class AggregatorSpec:
         rule); mutually exclusive with ``pre="bucketing"`` (that IS a
         bucketing stage) and ``sketch_dim``.  s=1 is an exact no-op
         (singleton buckets; the permutation is skipped so the pipeline is
-        bitwise the dense one).  Requires a PRNG key; dynamic-f paths need
+        bitwise the dense one).  Requires a PRNG key; a traced f needs
         an explicit ``bucket_size``.  Static bucket-key material for the
         fleet engine — the per-lane permutation key stays a traced
         operand.

@@ -8,8 +8,8 @@ Division of labor with the rest of the repo:
   buckets, their states stacked along a leading lane axis, and a single
   vmapped round steps the whole bucket.  Per-lane (f, attack family, eta,
   beta, local_lr, server lr) are traced operands — one compile per shape
-  bucket, not per job — via the dynamic-f entry points in
-  ``repro.core.robust`` / ``repro.core.attacks``.
+  bucket, not per job — via a traced f into ``repro.core.robust`` and
+  ``repro.core.attacks.apply_attack_dyn``.
 
 A B=1 fleet is the sequential per-job loop; a lane inside a B-lane bucket
 produces bit-for-bit the same trajectory (tested), so batching is purely a

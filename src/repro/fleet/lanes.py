@@ -8,9 +8,9 @@ attack family.  The fleet engine instead stacks B independent scenario jobs
   keys all live in one stacked state pytree;
 * the attack FAMILY is a traced ``lax.switch`` index
   (:func:`repro.core.attacks.apply_attack_dyn`), eta / beta / local_lr /
-  server lr are traced scalars, and the Byzantine counts go through the
-  dynamic-f aggregation path
-  (:func:`repro.core.robust.robust_aggregate_dyn`);
+  server lr are traced scalars, and the Byzantine counts go into
+  :func:`repro.core.robust.robust_aggregate` as a traced ``f`` (rank
+  masks in place of static slices);
 * lanes whose job has finished are frozen by an ``active`` operand
   (``where(active, new, old)``) so shorter jobs ride along unchanged.
 
@@ -28,12 +28,13 @@ import jax.numpy as jnp
 
 from repro.core import robust as robust_lib
 from repro.core.attacks import apply_attack_dyn
+from repro.core.theory import tree_kappa_hat
 from repro.fed.clients import client_updates, gather_rows, scatter_rows
 from repro.fed.poison import poison_batch
 from repro.fed.server import FedConfig
 from repro.optim import Optimizer, global_norm
 from repro.robustness.guard import quarantine_stack
-from repro.training.trainer import _split_info, kappa_hat_masked, merge_params
+from repro.training.trainer import _split_info, merge_params
 
 Array = jax.Array
 
@@ -94,10 +95,9 @@ def build_lane_round(loss_fn: Callable, optimizer: Optimizer,
         if cfg.guard is not None:
             attacked, qinfo = quarantine_stack(attacked, cfg.guard)
         tap_internals = {} if cfg.taps else None
-        robust_dir = robust_lib.robust_aggregate_dyn(attacked, spec,
-                                                     ops["f_agg"],
-                                                     key=agg_key,
-                                                     internals=tap_internals)
+        robust_dir = robust_lib.robust_aggregate(attacked, spec,
+                                                 f=ops["f_agg"], key=agg_key,
+                                                 internals=tap_internals)
         direction = merge_params(robust_dir, [], treedef, is_fsdp)
 
         lr = ops["lr"]
@@ -119,16 +119,16 @@ def build_lane_round(loss_fn: Callable, optimizer: Optimizer,
         if qinfo is not None:
             metrics["quarantined_count"] = qinfo["count"]
         if cfg.track_kappa_hat:
-            metrics["kappa_hat"] = kappa_hat_masked(robust_dir, attacked,
-                                                    m_honest,
-                                                    internals=tap_internals)
+            metrics["kappa_hat"] = tree_kappa_hat(robust_dir, attacked,
+                                                  m_honest,
+                                                  internals=tap_internals)
         if cfg.taps:
             from repro.obs import health_taps
-            # Dynamic-f taps: f_agg / m_honest are traced per-lane scalars,
-            # same rank-mask selection as robust_aggregate_dyn.
+            # f_agg / m_honest are traced per-lane scalars: the same
+            # rank-mask selection as the aggregation.
             metrics["taps"] = health_taps(
                 attacked, robust_dir, n_honest=m_honest, f=ops["f_agg"],
-                rule=spec.rule, pre=spec.pre, dyn=True,
+                rule=spec.rule, pre=spec.pre,
                 internals=tap_internals, quarantine=qinfo)
 
         # Finished lanes ride along bit-identically frozen.

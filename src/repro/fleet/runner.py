@@ -98,7 +98,7 @@ class FleetJob:
             self.byz_identity = FixedByzantine(self.cfg.n_clients, self.cfg.f)
         if self.cfg.agg.rule == "mda":
             raise ValueError(
-                "mda has no dynamic-f form; fleet lanes cannot run it "
+                "mda has no traced-f form; fleet lanes cannot run it "
                 "(use the single-scenario engine instead)")
         for phase in self.schedule.phases:
             dyn_attack_id(phase.attack)   # raises for _opt / unknown
@@ -112,7 +112,7 @@ class FleetJob:
                 and self.cfg.agg.bucket_size is None):
             raise ValueError(
                 "hierarchical fleet lanes need an explicit bucket_size "
-                "(lanes run the dynamic-f path, whose floor(n/2f) default "
+                "(lanes pass a traced f, whose floor(n/2f) default "
                 "is shape-level); resolve it host-side, e.g. "
                 "default_bucket_size(m, f_round)")
 

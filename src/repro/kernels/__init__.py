@@ -16,9 +16,7 @@ mode.
 from repro.kernels.bucketgram import bucket_means_gram, bucket_means_gram_ref
 from repro.kernels.combine import combine, combine_ref
 from repro.kernels.gram import gram, gram_batched, gram_batched_ref, gram_ref
-from repro.kernels.mixtrim import (
-    mixtrim, mixtrim_dyn, mixtrim_dyn_ref, mixtrim_ref,
-)
+from repro.kernels.mixtrim import mixtrim, mixtrim_ref
 from repro.kernels import dispatch, shard
 
 __all__ = [
@@ -26,6 +24,6 @@ __all__ = [
     "combine", "combine_ref",
     "dispatch",
     "gram", "gram_batched", "gram_batched_ref", "gram_ref",
-    "mixtrim", "mixtrim_dyn", "mixtrim_dyn_ref", "mixtrim_ref",
+    "mixtrim", "mixtrim_ref",
     "shard",
 ]

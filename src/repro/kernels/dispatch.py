@@ -21,10 +21,10 @@ views of the worker-stacked pytree:
 * **combine** — the streamed coefficient kernel (``kernels/combine``)
   applying the gram-rule weights without re-materializing anything;
 * **mixtrim** — the fused NNM-mix + coordinate trim/median kernel
-  (``kernels/mixtrim``), static-f or the dynamic-f rank-mask variant, so
-  the mixed stack ``Y = M @ X`` never exists in HBM (any n: a leaf view
-  sorts its workers' slabs elementwise; the flat form's bitonic sort pads
-  to the next power of two with sentinel rows).
+  (``kernels/mixtrim``), a static f compiled in or a traced f as a rank
+  mask, so the mixed stack ``Y = M @ X`` never exists in HBM (any n: a
+  leaf view sorts its workers' slabs elementwise; the flat form's bitonic
+  sort pads to the next power of two with sentinel rows).
 
 Under a multi-device mesh, ``backend="pallas_sharded"`` runs the same
 pipeline shard_map'd along D (:mod:`repro.kernels.shard`): per-shard
@@ -78,7 +78,7 @@ from repro.kernels.combine import combine as _combine_op
 from repro.kernels.gram import gram as _gram_op
 from repro.kernels.gram import gram_batched as _gram_batched_op
 from repro.kernels.mixtrim import mixtrim as _mixtrim_op
-from repro.kernels.mixtrim import mixtrim_dyn as _mixtrim_dyn_op
+from repro.kernels.mixtrim.ref import coordinate_rule
 from repro.kernels.target import on_tpu, resolve_interpret
 from repro.kernels.tiling import (  # noqa: F401  (pick_block_d: re-export)
     block_width, grid_steps, leaf_block, leaf_grid, leaf_shape,
@@ -181,6 +181,8 @@ class DispatchRecord:
     backend: str            # resolved backend
     rule: str
     pre: Optional[str]
+    #: Whether f was traced (a fleet lane's budget) rather than a python
+    #: int; set by ``robust_aggregate`` after it opens the record.
     dyn: bool = False
     #: Mesh decision for the sharded backends: how many devices the
     #: aggregation actually sharded over (1 = unsharded — a
@@ -252,8 +254,7 @@ def dispatch_count() -> int:
 
 
 def open_record(*, requested: str, backend: str, rule: str,
-                pre: Optional[str], dyn: bool = False,
-                mesh_devices: int = 1,
+                pre: Optional[str], mesh_devices: int = 1,
                 mesh_axis: Optional[str] = None,
                 hier: bool = False,
                 bucket_size: Optional[int] = None,
@@ -262,7 +263,7 @@ def open_record(*, requested: str, backend: str, rule: str,
     this trace append to it."""
     global _OPENED
     rec = DispatchRecord(requested=requested, backend=backend, rule=rule,
-                         pre=pre, dyn=dyn, mesh_devices=mesh_devices,
+                         pre=pre, mesh_devices=mesh_devices,
                          mesh_axis=mesh_axis, hier=hier,
                          bucket_size=bucket_size,
                          mesh_worker_axis=mesh_worker_axis)
@@ -586,21 +587,22 @@ def dispatch_combine(x: Array, coeff: Array, *, backend: str,
 
 
 def dispatch_mixtrim(x: Array, m: Optional[Array], f, *, mode: str,
-                     backend: str, dyn: bool = False,
-                     block_d: Optional[int] = None,
+                     backend: str, block_d: Optional[int] = None,
                      mesh: Optional[jax.sharding.Mesh] = None,
                      axis: Optional[str] = None) -> Array:
     """(n, D) -> (D,), or a (n, R, C) leaf view -> (R, C): fused mix +
     coordinate trim/median.
 
-    ``m=None`` elides the mix dot (plain CWTM/CWMed).  ``dyn=True`` takes
-    a TRACED f through the rank-mask kernel variant (one compile per fleet
-    shape bucket).  On a flat (n, D) view a non-power-of-two n runs the
-    fused kernel through the sentinel-padded bitonic sort (recorded as a
-    note, NOT a fallback — the kernel body executes for every n); a leaf
-    view sorts slabs and pads nothing.
+    ``m=None`` elides the mix dot (plain CWTM/CWMed).  A TRACED f trims
+    through the kernel's rank mask (one compile per fleet shape bucket).
+    On a flat (n, D) view a non-power-of-two n runs the fused kernel
+    through the sentinel-padded bitonic sort (recorded as a note, NOT a
+    fallback — the kernel body executes for every n); a leaf view sorts
+    slabs and pads nothing.
     """
     n = x.shape[0]
+    # mode="med" ignores f, so a traced f shares the static kernel there.
+    f = 0 if mode == "med" else f
 
     def _note(why: str, view: str) -> str:
         pad = _pad_note(n, view)
@@ -612,50 +614,35 @@ def dispatch_mixtrim(x: Array, m: Optional[Array], f, *, mode: str,
         tile = _tile(x, block_d, mesh, axis)
         record_decision("mixtrim", backend, used, _note(why, tile[2]), tile)
         return shardlib.sharded_mixtrim(x, m, f, mode=mode, mesh=mesh,
-                                        axis=axis, dyn=dyn, block_d=tile[0],
+                                        axis=axis, block_d=tile[0],
                                         interpret=interpret)
     if backend == "pallas":
         interpret = resolve_interpret()
         used, why = _pallas_used(interpret)
         tile = _tile(x, block_d)
         record_decision("mixtrim", "pallas", used, _note(why, tile[2]), tile)
-        bd = _block_arg(x, block_d, tile)
-        if dyn and mode == "trim":
-            return _mixtrim_dyn_op(x, m, f, mode=mode, block_d=bd,
-                                   interpret=interpret)
-        # mode="med" ignores f entirely, so the dynamic path can share the
-        # static kernel (f participates only in the trim mask).
-        return _mixtrim_op(x, m, f=(0 if mode == "med" else int(f)),
-                           mode=mode, block_d=bd, interpret=interpret)
+        return _mixtrim_op(x, m, f, mode=mode,
+                           block_d=_block_arg(x, block_d, tile),
+                           interpret=interpret)
     record_decision("mixtrim", backend, "xla")
-    if dyn and mode == "trim":
-        return _mixtrim_dyn_op(x, m, f, mode=mode, use_pallas=False)
-    return _mixtrim_op(x, m, f=(0 if mode == "med" else int(f)), mode=mode,
-                       use_pallas=False)
+    return _mixtrim_op(x, m, f, mode=mode, use_pallas=False)
 
 
 def dispatch_meamed(x: Array, m: Optional[Array], f, *, backend: str,
-                    dyn: bool = False,
                     mesh: Optional[jax.sharding.Mesh] = None,
                     axis: Optional[str] = None) -> Array:
     """meamed on the flat buffer: no fused kernel exists, so the decision
-    is always a RECORDED fallback — but the jnp form (robust's own
-    coordinate-rule helpers, so the arithmetic can never drift across
+    is always a RECORDED fallback — but the jnp form (the xla backend's
+    own :func:`coordinate_rule`, so the arithmetic can never drift across
     backends) runs shard-locally under the sharded backend, keeping the
     wide intermediates at (n, D/k) per device.  ``m`` arrives pre-cast to
     the stack dtype (the bf16-parity contract of the caller)."""
     if backend in _SHARDED_BACKENDS:
         record_decision("mixtrim", backend, "xla",
                         "meamed has no fused kernel (shard-local jnp form)")
-        return shardlib.sharded_meamed(x, m, f, mesh=mesh, axis=axis,
-                                       dyn=dyn)
+        return shardlib.sharded_meamed(x, m, f, mesh=mesh, axis=axis)
     record_decision("mixtrim", "pallas", "xla",
                     "meamed has no fused kernel")
-    from repro.core.robust import (
-        _tree_coordinate_rule, _tree_coordinate_rule_dyn,
-    )
     mixed = x if m is None else jnp.einsum(
         "mn,n...->m...", m, x, preferred_element_type=jnp.float32)
-    sub = {"x": mixed}
-    return (_tree_coordinate_rule_dyn(sub, "meamed", f) if dyn
-            else _tree_coordinate_rule(sub, "meamed", f))["x"]
+    return coordinate_rule(mixed, "meamed", f)

@@ -1,4 +1,4 @@
-from repro.kernels.mixtrim.ops import mixtrim, mixtrim_dyn
-from repro.kernels.mixtrim.ref import mixtrim_dyn_ref, mixtrim_ref
+from repro.kernels.mixtrim.ops import mixtrim
+from repro.kernels.mixtrim.ref import mixtrim_ref
 
-__all__ = ["mixtrim", "mixtrim_dyn", "mixtrim_dyn_ref", "mixtrim_ref"]
+__all__ = ["mixtrim", "mixtrim_ref"]

@@ -131,9 +131,9 @@ def _reduce_ranks(rank, f, mode: str, n_real: int) -> jax.Array:
     Trimming sums the kept ranks one at a time, in rank order: the
     order of the oracles' column sums, and one that no tile width can
     change (a ``sum(axis=0)`` lets the compiler reassociate), so interpret
-    mode matches ``mixtrim_ref`` / ``mixtrim_dyn_ref`` bit for bit and a
+    mode matches ``mixtrim_ref`` bit for bit and a
     D-sharded run matches a single-device one.  A python-int ``f`` sums
-    ranks f..n_real-f-1; a traced scalar ``f`` (the dynamic kernel) weights
+    ranks f..n_real-f-1; a traced scalar ``f`` weights
     every real rank by ``f <= r < n_real - f``.  No sentinel row (rank >=
     n_real) is ever read."""
     if mode == "med":
@@ -194,8 +194,8 @@ def _sort_slabs(ys: list) -> list:
 def _make_kernel(mode: str, mix: bool, n_real: int, f, *, d: int,
                  width: int):
     """Kernel body.  ``f=None`` reads the trim count from a leading (1, 1)
-    int32 SMEM operand (the dynamic kernel: one compile serves every
-    Byzantine budget of a fleet shape bucket); ``mode="med"`` ignores f.
+    int32 SMEM operand (a traced f: one compile serves every Byzantine
+    budget of a fleet shape bucket); ``mode="med"`` ignores f.
     ``mix=False`` drops the M operand and the MXU dot entirely (plain
     CWTM/CWMed).  ``n_real`` is the true worker count; any pad rows of
     the sort become sentinels before the network runs.  The (n, width)
@@ -230,7 +230,7 @@ def _make_kernel(mode: str, mix: bool, n_real: int, f, *, d: int,
 
 def _mixtrim_call(x, m, f, *, mode: str, block_d: int | None,
                   interpret: bool):
-    """The pallas_call behind both entry points: grid over wide d tiles
+    """The pallas_call over a (n, d) stack: grid over wide d tiles
     (a ragged last tile is never padded in HBM); M, zero-row-padded to
     the sort height, is broadcast to every grid step; a traced ``f``
     rides in scalar memory."""
@@ -365,9 +365,27 @@ def _mixtrim_any(x, m, f, *, mode: str, block_d: int | None,
                          interpret=interpret)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("f", "mode", "block_d", "interpret"))
-def mixtrim_pallas(x: jax.Array, m: jax.Array, *, f: int, mode: str = "trim",
+def _jit_by_f(static_argnames: tuple):
+    """Decorator: jit ``fn(x, m, f, **kwargs)`` with ``static_argnames``
+    static, and f as well where it is a python int.  An int f is compiled
+    into the program; any other f goes in as a traced int32 operand, so
+    one program serves every f (and vmap batches it over fleet lanes).
+    Both programs take fn's name, which the device trace shows."""
+    def wrap(fn):
+        by_int = jax.jit(fn, static_argnames=("f", *static_argnames))
+        by_tracer = jax.jit(fn, static_argnames=static_argnames)
+
+        @functools.wraps(fn)
+        def call(x, m, f, **kwargs):
+            if isinstance(f, int):
+                return by_int(x, m, f, **kwargs)
+            return by_tracer(x, m, jnp.asarray(f, jnp.int32), **kwargs)
+        return call
+    return wrap
+
+
+@_jit_by_f(static_argnames=("mode", "block_d", "interpret"))
+def mixtrim_pallas(x: jax.Array, m: jax.Array, f, *, mode: str = "trim",
                    block_d: int | None = None, interpret: bool = False
                    ) -> jax.Array:
     """Fused (M @ X -> sort -> trim/median) over d tiles.
@@ -380,27 +398,14 @@ def mixtrim_pallas(x: jax.Array, m: jax.Array, *, f: int, mode: str = "trim",
         step, or None), reduced slab by slab to an (R, C) aggregate.
       m: (n, n) mixing matrix, or None for plain CWTM/CWMed (the mix dot
         is elided entirely — no identity matmul).
-      f: trim count (ignored for mode="med").
+      f: trim count (ignored for mode="med").  A python int is compiled
+        in; a traced int32 scalar rides along as a (1, 1) scalar-memory
+        operand (two dims, so its lane-batched form still lowers on a
+        TPU) and trims through a rank mask.  Under ``jax.vmap`` (the
+        fleet's lane axis) the pallas batching rule prepends a lane grid
+        dimension, so a whole shape bucket costs one compile.
       mode: "trim" or "med".
     Returns: (d,) fp32 aggregate; (R, C) for a leaf view.
     """
-    return _mixtrim_any(x, m, int(f), mode=mode, block_d=block_d,
+    return _mixtrim_any(x, m, f, mode=mode, block_d=block_d,
                         interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("mode", "block_d", "interpret"))
-def mixtrim_dyn_pallas(x: jax.Array, m: jax.Array, f: jax.Array, *,
-                       mode: str = "trim", block_d: int | None = None,
-                       interpret: bool = False) -> jax.Array:
-    """Fused mix+trim with a TRACED Byzantine count.
-
-    Same tiling as :func:`mixtrim_pallas` (including the padded sentinel
-    sort for non-power-of-two n); ``f`` rides along as a (1, 1) int32
-    scalar-memory operand (two dims, so its lane-batched form still lowers
-    on a TPU), and trimming goes through a rank mask.  Under
-    ``jax.vmap`` (the fleet's lane axis) the pallas batching rule prepends
-    a lane grid dimension, so a whole shape bucket still costs one
-    compile.
-    """
-    return _mixtrim_any(x, m, jnp.asarray(f, jnp.int32), mode=mode,
-                        block_d=block_d, interpret=interpret)

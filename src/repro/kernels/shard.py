@@ -32,7 +32,7 @@ from repro.kernels.bucketgram import pick_block_n as _pick_block_n
 from repro.kernels.combine import combine as _combine_op
 from repro.kernels.gram import gram as _gram_op
 from repro.kernels.mixtrim import mixtrim as _mixtrim_op
-from repro.kernels.mixtrim import mixtrim_dyn as _mixtrim_dyn_op
+from repro.kernels.mixtrim.ref import coordinate_rule
 from repro.kernels import tiling
 from repro.kernels.target import resolve_interpret
 
@@ -99,39 +99,43 @@ def sharded_combine(x: Array, coeff: Array, *, mesh: jax.sharding.Mesh,
     return fn(_pad_cols(x, pad), coeff)[:d]
 
 
+def _replicated_operands(x: Array, pad: int, axis: str, m: Optional[Array],
+                         f) -> tuple[list, list]:
+    """The shard_map operands of a coordinate rule and their specs: the
+    D-sharded stack, then ``m`` and a traced ``f`` replicated where
+    present (a python-int f stays in the body's closure)."""
+    operands: list = [_pad_cols(x, pad)]
+    in_specs: list = [P(None, axis)]
+    if m is not None:
+        operands.append(m)
+        in_specs.append(P())
+    if not isinstance(f, int):
+        operands.append(jnp.asarray(f, jnp.int32))
+        in_specs.append(P())
+    return operands, in_specs
+
+
 def sharded_mixtrim(x: Array, m: Optional[Array], f, *, mode: str,
-                    mesh: jax.sharding.Mesh, axis: str, dyn: bool = False,
+                    mesh: jax.sharding.Mesh, axis: str,
                     block_d: Optional[int] = None,
                     interpret: Optional[bool] = None) -> Array:
     """(n, D) -> (D,): fused mix + trim/median, shard-local per d-block.
 
-    ``m`` (replicated) and the traced ``f`` (dyn=True) ride into the
-    shard_map as replicated operands; the padded sentinel bitonic sort
-    inside the kernel handles any n.  The mixed stack only ever exists as
-    (n, W) VMEM tiles on each device."""
+    ``m`` and a traced ``f`` ride into the shard_map as replicated
+    operands; the padded sentinel bitonic sort inside the kernel handles
+    any n.  The mixed stack only ever exists as (n, W) VMEM tiles on each
+    device."""
     d = x.shape[1]
     pad, bd, interpret = _resolve(mesh, axis, x, block_d, interpret)
     has_m = m is not None
-    f_static = 0 if mode == "med" else (f if not dyn else None)
+    traced = not isinstance(f, int)
 
     def body(xl, *rest):
         ml = rest[0] if has_m else None
-        if dyn and mode == "trim":
-            return _mixtrim_dyn_op(xl, ml, rest[-1], mode=mode, block_d=bd,
-                                   interpret=interpret)
-        # mode="med" ignores f entirely, so the dynamic path shares the
-        # static kernel (f participates only in the trim mask).
-        return _mixtrim_op(xl, ml, f=int(f_static), mode=mode, block_d=bd,
-                           interpret=interpret)
+        return _mixtrim_op(xl, ml, rest[-1] if traced else f, mode=mode,
+                           block_d=bd, interpret=interpret)
 
-    operands: list = [_pad_cols(x, pad)]
-    in_specs: list = [P(None, axis)]
-    if has_m:
-        operands.append(m)
-        in_specs.append(P())
-    if dyn and mode == "trim":
-        operands.append(jnp.asarray(f, jnp.int32))
-        in_specs.append(P())
+    operands, in_specs = _replicated_operands(x, pad, axis, m, f)
     fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
                        out_specs=P(axis), check_vma=False)
     return fn(*operands)[:d]
@@ -219,41 +223,27 @@ def sharded_bucketgram(x: Array, bmat: Array, *, mesh: jax.sharding.Mesh,
 
 
 def sharded_meamed(x: Array, m: Optional[Array], f, *,
-                   mesh: jax.sharding.Mesh, axis: str,
-                   dyn: bool = False) -> Array:
+                   mesh: jax.sharding.Mesh, axis: str) -> Array:
     """(n, D) -> (D,): mean-around-median, shard-local jnp form.
 
     meamed has no fused kernel (recorded as a fallback by the dispatcher),
     but it IS coordinate-wise, so the jnp form still runs shard-locally —
-    the mixed stack and the sort stay (n, D/k) per device."""
-    # Lazy import (robust itself routes through this package): the body
-    # applies robust's OWN coordinate-rule helpers to the local columns,
-    # so parity with the other backends can never drift.
-    from repro.core.robust import (
-        _tree_coordinate_rule, _tree_coordinate_rule_dyn,
-    )
+    the mixed stack and the sort stay (n, D/k) per device.  The body
+    applies the xla backend's own :func:`coordinate_rule` to the local
+    columns, so parity with the other backends can never drift."""
     d = x.shape[1]
     k = axis_size(mesh, axis)
     pad = (-d) % k
     has_m = m is not None
+    traced = not isinstance(f, int)
 
     def body(xl, *rest):
         y = xl if not has_m else jnp.einsum(
             "mn,nd->md", rest[0].astype(xl.dtype), xl,
             preferred_element_type=jnp.float32)
-        sub = {"x": y}
-        if dyn:
-            return _tree_coordinate_rule_dyn(sub, "meamed", rest[-1])["x"]
-        return _tree_coordinate_rule(sub, "meamed", f)["x"]
+        return coordinate_rule(y, "meamed", rest[-1] if traced else f)
 
-    operands: list = [_pad_cols(x, pad)]
-    in_specs: list = [P(None, axis)]
-    if has_m:
-        operands.append(m)
-        in_specs.append(P())
-    if dyn:
-        operands.append(jnp.asarray(f, jnp.int32))
-        in_specs.append(P())
+    operands, in_specs = _replicated_operands(x, pad, axis, m, f)
     fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
                        out_specs=P(axis), check_vma=False)
     return fn(*operands)[:d]

@@ -42,9 +42,9 @@ leaf-streamed mix + sort pass (see docs/observability.md for the
 overhead model — the ≥0.9x rounds/sec CI gate keeps the XLA path
 honest).
 
-``dyn=True`` is the fleet-lane variant: ``f`` and ``n_honest`` are
-TRACED scalars (rank-mask NNM, gathered trim thresholds), so one
-compiled tapped round serves lanes with different Byzantine budgets.
+``f`` and ``n_honest`` are python ints, or TRACED scalars on the fleet
+lanes (rank-mask NNM, gathered trim thresholds), so one compiled tapped
+round serves lanes with different Byzantine budgets.
 """
 from __future__ import annotations
 
@@ -95,7 +95,6 @@ TAP_FIELDS = HealthTaps._fields
 
 def health_taps(stack: PyTree, aggregate: PyTree, *, n_honest, f,
                 rule: str, pre: Optional[str],
-                dyn: bool = False,
                 internals: Optional[dict] = None,
                 quarantine: Optional[dict] = None) -> HealthTaps:
     """Compute the taps for one round.
@@ -105,11 +104,10 @@ def health_taps(stack: PyTree, aggregate: PyTree, *, n_honest, f,
         aggregator consumed.
       aggregate: the robust output pytree (worker axis removed).
       n_honest: honest row count (rows are honest-first; Python int, or
-        traced int32 when ``dyn``).
-      f: the aggregator's Byzantine budget (int, or traced when ``dyn``).
+        traced int32).
+      f: the aggregator's Byzantine budget (Python int, or traced int32).
       rule / pre: the AggregatorSpec fields that decide which taps exist
         (static — tap structure is trace-time).
-      dyn: traced-f fleet path (rank-mask NNM, gathered trim thresholds).
       internals: the dict ``robust_aggregate`` filled when the caller
         passed one (``mix_matrix`` / ``mixed`` / ``sorted_leaves``) — the
         taps then reuse those intermediates outright and add only O(n^2 +
@@ -171,8 +169,7 @@ def health_taps(stack: PyTree, aggregate: PyTree, *, n_honest, f,
         if m is None:       # standalone: rebuild from the stack's gram
             g = robustlib.tree_gram(stack)
             d2 = gramlib.pdist_sq_from_gram(g)
-            m = gramlib.nnm_matrix_dyn(d2, f) if dyn \
-                else gramlib.nnm_matrix(d2, int(f))
+            m = gramlib.nnm_matrix(d2, f)
         taps["neighbor_count"] = (m > 0).astype(jnp.float32).sum(axis=0)
         col = m.sum(axis=0) / float(n)      # row-stochastic: sums to 1
         taps["mix_mass"] = col
@@ -180,7 +177,7 @@ def health_taps(stack: PyTree, aggregate: PyTree, *, n_honest, f,
         taps["honest_mix_mass"] = (col * w).sum()
 
     if rule == "cwtm" and pre in (None, "nnm"):
-        if not dyn and int(f) == 0:
+        if isinstance(f, int) and f == 0:
             # cwtm with f=0 is a plain mean: nothing is ever trimmed (and
             # the aggregation emitted no sort to reuse).
             taps["trim_frac"] = jnp.zeros((n,), jnp.float32)

@@ -24,7 +24,7 @@ and ``obs.runtime`` ``robustness.quarantine`` events — see
 docs/robustness.md.
 
 Everything is static-shape mask math (sorts with +/-inf sentinels, traced
-counts), so the guard runs unchanged on the static, dyn-f, and vmapped
+counts), so the guard runs unchanged on the static, traced-f, and vmapped
 fleet paths, and is a *bitwise no-op* on the stack when no row trips a
 screen.
 """

@@ -109,33 +109,6 @@ def init_state(params: PyTree, optimizer: Optimizer, n_workers: int,
     return state
 
 
-def kappa_hat_masked(agg: PyTree, stack: PyTree, n_honest: Array,
-                     internals: Optional[dict] = None) -> Array:
-    """Eq. (26) with a TRACED honest count (fleet engine): the honest rows
-    are selected by mask (row < n_honest) so per-lane Byzantine budgets can
-    differ inside one compiled round.  ``internals`` stashes the per-leaf
-    honest means + squared distance for the health taps, exactly as
-    :func:`repro.core.theory.tree_kappa_hat` does."""
-    num = jnp.zeros((), jnp.float32)
-    den = jnp.zeros((), jnp.float32)
-    cnt = jnp.maximum(n_honest.astype(jnp.float32), 1.0)
-    for a, s in zip(jax.tree_util.tree_leaves(agg),
-                    jax.tree_util.tree_leaves(stack)):
-        x = s.astype(jnp.float32)
-        n = x.shape[0]
-        w = (jnp.arange(n) < n_honest).astype(jnp.float32)
-        wl = w.reshape((-1,) + (1,) * (x.ndim - 1))
-        mbar = (x * wl).sum(axis=0) / cnt
-        if internals is not None:
-            internals.setdefault("honest_mean_leaves", []).append(mbar)
-        num += jnp.sum((a.astype(jnp.float32) - mbar) ** 2)
-        sq = jnp.sum(((x - mbar) ** 2).reshape(n, -1), axis=1)
-        den += (sq * w).sum() / cnt
-    if internals is not None:
-        internals["honest_sq_dist"] = num
-    return jnp.sqrt(num / (den + 1e-20))
-
-
 def build_train_step(loss_fn: Callable, optimizer: Optimizer,
                      cfg: TrainerConfig, lr_schedule: Callable
                      ) -> Callable:

@@ -4,13 +4,17 @@ The load-bearing acceptance tests:
 
 * ``backend="pallas"`` (interpret mode on CPU) matches ``backend="xla"``
   on ``robust_aggregate`` outputs for every rule x pre combination;
-* the dynamic-f pipeline holds the same parity with f traced, and one
-  compile serves every f (the fleet shape-bucket contract);
+* the same pipeline holds the same parity with f traced, a traced f
+  aggregates as the python int does, and one compile serves every f (the
+  fleet shape-bucket contract);
 * a requested-pallas run that silently fell back to the jnp oracle is
   DETECTABLE through ``last_dispatch()``;
 * the fused mixtrim path structurally eliminates the materialized
   (n, D) mixed stack (no full-width dot_general/sort in the jaxpr).
 """
+import ast
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -66,12 +70,51 @@ def test_backend_parity_dyn(rule, pre):
         def spec(backend):
             return AggregatorSpec(rule=rule, f=f, pre=pre, bucket_size=2,
                                   backend=backend)
-        ref = robust_lib.robust_aggregate_dyn(tree, spec("xla"),
-                                              jnp.int32(f), key=key)
-        got = robust_lib.robust_aggregate_dyn(tree, spec("pallas"),
-                                              jnp.int32(f), key=key)
+        ref = robust_lib.robust_aggregate(tree, spec("xla"),
+                                          f=jnp.int32(f), key=key)
+        got = robust_lib.robust_aggregate(tree, spec("pallas"),
+                                          f=jnp.int32(f), key=key)
         _assert_trees_close(got, ref, rtol=1e-5, atol=1e-5,
                             err_msg=f"{rule}/{pre}/f={f}")
+
+
+@pytest.mark.parametrize("rule", DYN_RULES)
+@pytest.mark.parametrize("pre", PRES)
+@pytest.mark.parametrize("backend", ("xla", "pallas"))
+def test_traced_f_matches_int_f(rule, pre, backend):
+    """One entry for both kinds of f: a traced f (rank masks) aggregates
+    as the python int (static slices) does, and the record says which."""
+    tree, key = _tree(12), jax.random.PRNGKey(13)
+    for f in (0, 2, 3):
+        spec = AggregatorSpec(rule=rule, f=0, pre=pre, bucket_size=2,
+                              backend=backend)
+        want = robust_lib.robust_aggregate(tree, spec, f=f, key=key)
+        assert not kdispatch.last_dispatch().dyn
+        got = robust_lib.robust_aggregate(tree, spec, f=jnp.int32(f),
+                                          key=key)
+        assert kdispatch.last_dispatch().dyn
+        _assert_trees_close(got, want, rtol=1e-5, atol=1e-5,
+                            err_msg=f"{rule}/{pre}/{backend}/f={f}")
+
+
+def test_kernels_import_nothing_from_core():
+    """The kernel layer sits below ``repro.core``: no module under
+    ``repro.kernels`` imports it, at top level or lazily."""
+    root = Path(kdispatch.__file__).parent
+    offenders = []
+    for path in sorted(root.rglob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom):
+                names = [node.module or ""]
+            else:
+                continue
+            offenders += [f"{path.relative_to(root)}: {name}"
+                          for name in names
+                          if name == "repro.core"
+                          or name.startswith("repro.core.")]
+    assert not offenders, offenders
 
 
 def test_batched_pallas_matches_per_lane_dyn():
@@ -80,11 +123,12 @@ def test_batched_pallas_matches_per_lane_dyn():
     bt = jax.tree_util.tree_map(
         lambda leaf: jnp.stack([leaf, 2 * leaf, leaf + 1]), tree)
     spec = AggregatorSpec(rule="cwtm", f=0, pre="nnm", backend="pallas")
-    out = robust_lib.batched_robust_aggregate(bt, spec, fs)
+    out = jax.vmap(lambda t, f: robust_lib.robust_aggregate(t, spec, f=f))(
+        bt, fs)
     for lane, f in enumerate((0, 2, 3)):
-        single = robust_lib.robust_aggregate_dyn(
+        single = robust_lib.robust_aggregate(
             jax.tree_util.tree_map(lambda leaf, k=lane: leaf[k], bt),
-            spec, jnp.int32(f))
+            spec, f=jnp.int32(f))
         _assert_trees_close(
             jax.tree_util.tree_map(lambda leaf, k=lane: leaf[k], out),
             single, rtol=1e-5, atol=1e-6)
@@ -133,13 +177,13 @@ def test_dyn_pallas_one_compile_across_f():
     @jax.jit
     def agg(t, f):
         traces.append(1)
-        return robust_lib.robust_aggregate_dyn(t, spec, f)
+        return robust_lib.robust_aggregate(t, spec, f=f)
 
     for f in (0, 1, 2, 3, 5, 7):
         got = agg(tree, jnp.int32(f))
-        ref = robust_lib.robust_aggregate_dyn(
+        ref = robust_lib.robust_aggregate(
             tree, AggregatorSpec(rule="cwtm", f=0, pre="nnm",
-                                 backend="xla"), jnp.int32(f))
+                                 backend="xla"), f=jnp.int32(f))
         _assert_trees_close(got, ref, rtol=1e-5, atol=1e-5,
                             err_msg=f"f={f}")
     assert len(traces) == 1, f"expected one trace, got {len(traces)}"

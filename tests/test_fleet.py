@@ -255,8 +255,8 @@ def test_dyn_aggregation_matches_static(stack_tree, rule, pre):
     for f in (0, 2, 3):
         spec = AggregatorSpec(rule=rule, f=f, pre=pre, bucket_size=2)
         stat = robust_lib.robust_aggregate(stack_tree, spec, key=key)
-        dyn = robust_lib.robust_aggregate_dyn(stack_tree, spec,
-                                              jnp.int32(f), key=key)
+        dyn = robust_lib.robust_aggregate(stack_tree, spec, f=jnp.int32(f),
+                                          key=key)
         for d, s in zip(jax.tree_util.tree_leaves(dyn),
                         jax.tree_util.tree_leaves(stat)):
             np.testing.assert_allclose(np.asarray(d), np.asarray(s),
@@ -283,11 +283,12 @@ def test_batched_aggregate_is_vmapped_dyn(stack_tree):
     bt = jax.tree_util.tree_map(
         lambda leaf: jnp.stack([leaf, 2 * leaf, leaf + 1]), stack_tree)
     spec = AggregatorSpec(rule="cwtm", f=0, pre="nnm")
-    out = robust_lib.batched_robust_aggregate(bt, spec, fs)
+    out = jax.vmap(lambda t, f: robust_lib.robust_aggregate(t, spec, f=f))(
+        bt, fs)
     for lane, f in enumerate((0, 2, 3)):
-        single = robust_lib.robust_aggregate_dyn(
+        single = robust_lib.robust_aggregate(
             jax.tree_util.tree_map(lambda leaf, k=lane: leaf[k], bt),
-            spec, jnp.int32(f))
+            spec, f=jnp.int32(f))
         for a, b in zip(jax.tree_util.tree_leaves(single),
                         jax.tree_util.tree_leaves(
                             jax.tree_util.tree_map(

@@ -215,8 +215,8 @@ def test_hier_dyn_matches_static():
     spec = AggregatorSpec(rule="cwtm", f=2, pre="nnm", hier=True,
                           bucket_size=3, backend="xla")
     got_s = robust_lib.robust_aggregate(tree, spec, key=KEY)
-    got_d = robust_lib.robust_aggregate_dyn(tree, spec,
-                                            jnp.int32(2), key=KEY)
+    got_d = robust_lib.robust_aggregate(tree, spec, f=jnp.int32(2),
+                                        key=KEY)
     for a, b in zip(jax.tree_util.tree_leaves(got_s),
                     jax.tree_util.tree_leaves(got_d)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -238,9 +238,9 @@ def test_hier_validation_errors():
             tree, AggregatorSpec(rule="cwtm", f=2, hier=True,
                                  bucket_size=2))
     with pytest.raises(ValueError, match="bucket_size"):
-        robust_lib.robust_aggregate_dyn(
+        robust_lib.robust_aggregate(
             tree, AggregatorSpec(rule="cwtm", f=2, hier=True),
-            jnp.int32(2), key=KEY)
+            f=jnp.int32(2), key=KEY)
 
 
 # ---------------------------------------------------------------------------

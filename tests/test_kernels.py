@@ -13,9 +13,13 @@ import pytest
 from repro.kernels import tiling
 from repro.kernels.combine import combine, combine_ref
 from repro.kernels.gram import gram, gram_batched, gram_batched_ref, gram_ref
-from repro.kernels.mixtrim import (
-    mixtrim, mixtrim_dyn, mixtrim_dyn_ref, mixtrim_ref,
-)
+from repro.kernels.mixtrim import mixtrim, mixtrim_ref
+
+def _f_kinds(f):
+    """The two kinds of trim count the kernel takes: a python int compiled
+    in, and a traced int32 scalar trimming through a rank mask."""
+    return (f, jnp.int32(f))
+
 
 try:                      # optional dev dep; property tests skip cleanly
     from hypothesis import given, settings, strategies as st
@@ -130,8 +134,8 @@ def test_mixtrim_dyn_bitexact_vs_ref():
     m = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(13), (16, 16)),
                        axis=-1)
     for f in (0, 1, 5, 7):
-        got = np.asarray(mixtrim_dyn(x, m, jnp.int32(f), block_d=128))
-        want = np.asarray(mixtrim_dyn_ref(x, m, jnp.int32(f)))
+        got = np.asarray(mixtrim(x, m, jnp.int32(f), block_d=128))
+        want = np.asarray(mixtrim_ref(x, m, jnp.int32(f)))
         np.testing.assert_array_equal(got, want)
 
 
@@ -185,17 +189,14 @@ def test_wide_tile_combine_bitexact(n, block_d):
 @pytest.mark.parametrize("mode", ["trim", "med"])
 @pytest.mark.parametrize("mix", [True, False])
 def test_wide_tile_mixtrim_bitexact(n, block_d, mode, mix):
-    """Static and dynamic f, with and without the mix dot: per-column
+    """Static and traced f, with and without the mix dot: per-column
     math, so any tile is bit for bit the oracle."""
     x, m, f = _wide_stack(n)
     mm = m if mix else None
-    got = np.asarray(mixtrim(x, mm, f=f, mode=mode, block_d=block_d))
-    np.testing.assert_array_equal(got,
-                                  np.asarray(mixtrim_ref(x, mm, f, mode)))
-    got_dyn = np.asarray(mixtrim_dyn(x, mm, jnp.int32(f), mode=mode,
-                                     block_d=block_d))
-    np.testing.assert_array_equal(
-        got_dyn, np.asarray(mixtrim_dyn_ref(x, mm, jnp.int32(f), mode)))
+    for fk in _f_kinds(f):
+        got = np.asarray(mixtrim(x, mm, fk, mode=mode, block_d=block_d))
+        np.testing.assert_array_equal(
+            got, np.asarray(mixtrim_ref(x, mm, fk, mode)))
 
 
 # ---------------------------------------------------------------------------
@@ -247,7 +248,7 @@ def test_mixtrim_dyn_matches_static_across_f_one_compile():
     @jax.jit
     def agg(x, m, f):
         traces.append(1)
-        return mixtrim_dyn(x, m, f, block_d=128)
+        return mixtrim(x, m, f, block_d=128)
 
     for f in (0, 1, 3, 5, 7):
         got = np.asarray(agg(x, m, jnp.int32(f)))
@@ -261,11 +262,11 @@ def test_mixtrim_dyn_vmap_lane_batch():
     xs = jax.random.normal(jax.random.PRNGKey(15), (4, 8, 128))
     m = jnp.eye(8, dtype=jnp.float32)
     fs = jnp.asarray([0, 1, 2, 3], jnp.int32)
-    out = jax.vmap(lambda x, f: mixtrim_dyn(x, m, f, block_d=128))(xs, fs)
+    out = jax.vmap(lambda x, f: mixtrim(x, m, f, block_d=128))(xs, fs)
     for k in range(4):
         np.testing.assert_allclose(
             np.asarray(out[k]),
-            np.asarray(mixtrim_dyn_ref(xs[k], m, fs[k])),
+            np.asarray(mixtrim_ref(xs[k], m, fs[k])),
             rtol=1e-6, atol=1e-6)
 
 
@@ -274,8 +275,8 @@ def test_mixtrim_dyn_nonpow2_runs_padded_kernel():
     above every real value, so their ranks never enter the keep mask."""
     x = jax.random.normal(jax.random.PRNGKey(16), (17, 100))
     m = jnp.eye(17)
-    got = np.asarray(mixtrim_dyn(x, m, jnp.int32(4)))
-    want = np.asarray(mixtrim_dyn_ref(x, m, jnp.int32(4)))
+    got = np.asarray(mixtrim(x, m, jnp.int32(4)))
+    want = np.asarray(mixtrim_ref(x, m, jnp.int32(4)))
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
 
 
@@ -288,22 +289,16 @@ def test_mixtrim_dyn_nonpow2_runs_padded_kernel():
 def test_mixtrim_padded_sort_vs_oracle(n, mode):
     """The federated worker counts the pow2 network used to reject (n=17 is
     the paper's own scale): f=0 and f one below breakdown, with and without
-    the mix dot, static and dynamic f — all through the padded kernel."""
+    the mix dot, static and traced f — all through the padded kernel."""
     x = jax.random.normal(jax.random.PRNGKey(n), (n, 130))
     m = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(n + 1), (n, n)),
                        axis=-1)
-    for f in (0, max(0, (n - 1) // 2)):
+    for f in (*_f_kinds(0), *_f_kinds(max(0, (n - 1) // 2))):
         for mm in (m, None):
-            got = np.asarray(mixtrim(x, mm, f=f, mode=mode, block_d=128))
+            got = np.asarray(mixtrim(x, mm, f, mode=mode, block_d=128))
             want = np.asarray(mixtrim_ref(x, mm, f, mode))
             np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6,
                                        err_msg=f"n={n} f={f} mode={mode}")
-            got_dyn = np.asarray(mixtrim_dyn(x, mm, jnp.int32(f), mode=mode,
-                                             block_d=128))
-            want_dyn = np.asarray(mixtrim_dyn_ref(x, mm, jnp.int32(f), mode))
-            np.testing.assert_allclose(got_dyn, want_dyn, rtol=1e-6,
-                                       atol=1e-6,
-                                       err_msg=f"dyn n={n} f={f} mode={mode}")
 
 
 def test_mixtrim_padded_sort_negative_and_tied_values():
@@ -326,11 +321,11 @@ def test_mixtrim_dyn_padded_vmap_lane_batch():
     xs = jax.random.normal(jax.random.PRNGKey(22), (3, 5, 128))
     m = jnp.eye(5, dtype=jnp.float32)
     fs = jnp.asarray([0, 1, 2], jnp.int32)
-    out = jax.vmap(lambda x, f: mixtrim_dyn(x, m, f, block_d=128))(xs, fs)
+    out = jax.vmap(lambda x, f: mixtrim(x, m, f, block_d=128))(xs, fs)
     for k in range(3):
         np.testing.assert_allclose(
             np.asarray(out[k]),
-            np.asarray(mixtrim_dyn_ref(xs[k], m, fs[k])),
+            np.asarray(mixtrim_ref(xs[k], m, fs[k])),
             rtol=1e-6, atol=1e-6)
 
 
@@ -342,18 +337,15 @@ def test_mixtrim_dyn_padded_vmap_lane_batch():
 def test_mixtrim_no_mix_elides_the_dot(mode):
     """m=None (plain CWTM/CWMed): no identity matmul — the kernel sorts x
     directly and must match both the m=None ref and the explicit-identity
-    call bit for bit."""
+    call bit for bit, for a static and a traced f."""
     x = jax.random.normal(jax.random.PRNGKey(21), (16, 256))
-    got = np.asarray(mixtrim(x, None, f=3, mode=mode, block_d=128))
-    np.testing.assert_array_equal(got,
-                                  np.asarray(mixtrim_ref(x, None, 3, mode)))
     eye = jnp.eye(16, dtype=jnp.float32)
-    np.testing.assert_array_equal(
-        got, np.asarray(mixtrim(x, eye, f=3, mode=mode, block_d=128)))
-    got_dyn = np.asarray(mixtrim_dyn(x, None, jnp.int32(3), mode=mode,
-                                     block_d=128))
-    np.testing.assert_array_equal(
-        got_dyn, np.asarray(mixtrim_dyn_ref(x, None, jnp.int32(3), mode)))
+    for f in _f_kinds(3):
+        got = np.asarray(mixtrim(x, None, f, mode=mode, block_d=128))
+        np.testing.assert_array_equal(
+            got, np.asarray(mixtrim_ref(x, None, f, mode)))
+        np.testing.assert_array_equal(
+            got, np.asarray(mixtrim(x, eye, f, mode=mode, block_d=128)))
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -432,27 +424,24 @@ def test_leaf_view_gram_matches_ref(n, cols, rows):
 @pytest.mark.parametrize("n,cols", [(4, c) for c in LEAF_COLS] + [(17, 320)])
 @pytest.mark.parametrize("rows", sorted(LEAF_ROWS))
 def test_leaf_view_mixtrim_matches_ref(n, cols, rows):
-    """Trim and median of a leaf view, static and dynamic f: without the
+    """Trim and median of a leaf view, static and traced f: without the
     mix bit for bit the oracles (a sort of whole slabs reorders nothing
     but ranks), with it within fp32 rounding of the oracle's dot; the
     aggregate keeps the leaf's (R, C) shape."""
     r, block = LEAF_ROWS[rows]
     x, m, f = _leaf_stack(n, r, cols)
-    for mode in ("trim", "med"):
-        for mm in (m, None):
-            got = np.asarray(mixtrim(x, mm, f=f, mode=mode, block_d=block))
-            want = np.asarray(mixtrim_ref(x, mm, f, mode))
-            assert got.shape == (r, cols)
-            got_dyn = np.asarray(mixtrim_dyn(x, mm, jnp.int32(f), mode=mode,
-                                             block_d=block))
-            want_dyn = np.asarray(mixtrim_dyn_ref(x, mm, jnp.int32(f), mode))
-            if mm is None:
-                np.testing.assert_array_equal(got, want)
-                np.testing.assert_array_equal(got_dyn, want_dyn)
-            else:
-                np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-                np.testing.assert_allclose(got_dyn, want_dyn, rtol=1e-5,
-                                           atol=1e-5)
+    for fk in _f_kinds(f):
+        for mode in ("trim", "med"):
+            for mm in (m, None):
+                got = np.asarray(mixtrim(x, mm, fk, mode=mode,
+                                         block_d=block))
+                want = np.asarray(mixtrim_ref(x, mm, fk, mode))
+                assert got.shape == (r, cols)
+                if mm is None:
+                    np.testing.assert_array_equal(got, want)
+                else:
+                    np.testing.assert_allclose(got, want, rtol=1e-5,
+                                               atol=1e-5)
 
 
 @pytest.mark.parametrize("n", [4, 17])
@@ -463,10 +452,10 @@ def test_leaf_view_mixtrim_dyn_vmap_lane_batch(n):
     m = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(41), (n, n)),
                        axis=-1)
     fs = jnp.asarray([0, 1, (n - 1) // 2], jnp.int32)
-    out = jax.vmap(lambda x, f: mixtrim_dyn(x, m, f, block_d=8))(xs, fs)
+    out = jax.vmap(lambda x, f: mixtrim(x, m, f, block_d=8))(xs, fs)
     for k in range(3):
         np.testing.assert_allclose(
-            np.asarray(out[k]), np.asarray(mixtrim_dyn_ref(xs[k], m, fs[k])),
+            np.asarray(out[k]), np.asarray(mixtrim_ref(xs[k], m, fs[k])),
             rtol=1e-5, atol=1e-5)
 
 

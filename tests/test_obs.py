@@ -142,7 +142,7 @@ def test_health_taps_dyn_matches_static():
     static = obs.health_taps(stack, agg, n_honest=n - f, f=f,
                              rule="cwtm", pre="nnm")
     dyn = obs.health_taps(stack, agg, n_honest=jnp.int32(n - f),
-                          f=jnp.int32(f), rule="cwtm", pre="nnm", dyn=True)
+                          f=jnp.int32(f), rule="cwtm", pre="nnm")
     for k, v in static.to_dict().items():
         np.testing.assert_allclose(np.asarray(v),
                                    np.asarray(dyn.to_dict()[k]),

@@ -142,15 +142,15 @@ def test_autogm_static_equals_dyn_and_batched():
     tree = _tree()
     spec = AggregatorSpec(rule="autogm", f=2, pre="nnm")
     static = robust_lib.robust_aggregate(tree, spec)
-    dyn = robust_lib.robust_aggregate_dyn(tree, spec, jnp.int32(2))
+    dyn = robust_lib.robust_aggregate(tree, spec, f=jnp.int32(2))
     for a, b in zip(jax.tree_util.tree_leaves(static),
                     jax.tree_util.tree_leaves(dyn)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5)
     # Lane-batched: 3 lanes, different traced f per lane.
     stacked = jax.tree_util.tree_map(lambda l: jnp.stack([l] * 3), tree)
-    out = robust_lib.batched_robust_aggregate(
-        stacked, spec, jnp.asarray([0, 2, 2], jnp.int32))
+    out = jax.vmap(lambda t, f: robust_lib.robust_aggregate(t, spec, f=f))(
+        stacked, jnp.asarray([0, 2, 2], jnp.int32))
     lane2 = jax.tree_util.tree_map(lambda l: l[2], out)
     for a, b in zip(jax.tree_util.tree_leaves(dyn),
                     jax.tree_util.tree_leaves(lane2)):
@@ -167,7 +167,7 @@ def test_autogm_one_compile_per_shape_in_scan():
     @jax.jit
     def round_fn(x, f):
         traces.append(1)
-        return robust_lib.robust_aggregate_dyn({"p": x}, spec, f)["p"]
+        return robust_lib.robust_aggregate({"p": x}, spec, f=f)["p"]
 
     rng = np.random.default_rng(0)
     for f in (1, 2, 3):

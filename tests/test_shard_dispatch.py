@@ -102,10 +102,10 @@ for rule in RULES:
 # dynamic-f parity (traced f; the fleet path)
 for rule in ("cwtm", "cwmed", "krum", "meamed"):
     for f in (0, 2, 3):
-        ref = robust_lib.robust_aggregate_dyn(
-            tree, spec(rule, "nnm", "xla", 0), jnp.int32(f))
-        got = robust_lib.robust_aggregate_dyn(
-            tree, spec(rule, "nnm", "pallas_sharded", 0), jnp.int32(f))
+        ref = robust_lib.robust_aggregate(
+            tree, spec(rule, "nnm", "xla", 0), f=jnp.int32(f))
+        got = robust_lib.robust_aggregate(
+            tree, spec(rule, "nnm", "pallas_sharded", 0), f=jnp.int32(f))
         out["dyn_parity"][f"{rule}/f{f}"] = max(
             float(np.abs(a - b).max())
             for a, b in zip(leaves(got), leaves(ref)))
@@ -115,12 +115,13 @@ fs = jnp.asarray([0, 2, 3], jnp.int32)
 bt = jax.tree_util.tree_map(
     lambda leaf: jnp.stack([leaf, 2 * leaf, leaf + 1]), tree)
 bspec = spec("cwtm", "nnm", "pallas_sharded", 0)
-batched = robust_lib.batched_robust_aggregate(bt, bspec, fs)
+batched = jax.vmap(
+    lambda t, f: robust_lib.robust_aggregate(t, bspec, f=f))(bt, fs)
 errs = []
 for lane, f in enumerate((0, 2, 3)):
-    single = robust_lib.robust_aggregate_dyn(
+    single = robust_lib.robust_aggregate(
         jax.tree_util.tree_map(lambda leaf, k=lane: leaf[k], bt),
-        bspec, jnp.int32(f))
+        bspec, f=jnp.int32(f))
     lane_out = jax.tree_util.tree_map(lambda leaf, k=lane: leaf[k], batched)
     errs.append(max(float(np.abs(a - b).max())
                     for a, b in zip(leaves(lane_out), leaves(single))))

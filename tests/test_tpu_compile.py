@@ -32,7 +32,7 @@ from repro.kernels import target, tiling
 from repro.kernels.bucketgram import bucket_means_gram
 from repro.kernels.combine import combine
 from repro.kernels.gram import gram, gram_batched
-from repro.kernels.mixtrim import mixtrim, mixtrim_dyn
+from repro.kernels.mixtrim import mixtrim
 
 #: Stack width: millions of coordinates, not a multiple of the tile width
 #: (the ragged last tile is part of what must compile).
@@ -61,12 +61,13 @@ def _compile(fn, *shapes) -> str:
 
 def _mixtrim_all(x, m, f):
     """Every static-f form: trim and median, with and without the mix."""
-    return [mixtrim(x, mm, f=f, mode=mode, interpret=False)
+    return [mixtrim(x, mm, f, mode=mode, interpret=False)
             for mode in ("trim", "med") for mm in (m, None)]
 
 
 def _mixtrim_dyn_all(x, m, f):
-    return [mixtrim_dyn(x, mm, f, interpret=False) for mm in (m, None)]
+    """The traced-f trim, with and without the mix."""
+    return [mixtrim(x, mm, f, interpret=False) for mm in (m, None)]
 
 
 KERNELS = {
@@ -128,13 +129,13 @@ def test_leaf_view_kernel_compiles_for_v5e(one_chip, kernel, shape):
 
 
 def test_leaf_view_mixtrim_dyn_vmap_compiles_for_v5e(one_chip):
-    """The fleet's lane vmap of the dynamic-f kernel over leaf views."""
+    """The fleet's lane vmap of the traced-f kernel over leaf views."""
     xs = jax.ShapeDtypeStruct((3, 4, 1024, 960), jnp.float32,
                               sharding=one_chip)
     m = jax.ShapeDtypeStruct((3, 4, 4), jnp.float32, sharding=one_chip)
     fs = jax.ShapeDtypeStruct((3,), jnp.int32, sharding=one_chip)
-    hlo = _compile(jax.vmap(lambda x, m, f: mixtrim_dyn(x, m, f,
-                                                        interpret=False)),
+    hlo = _compile(jax.vmap(lambda x, m, f: mixtrim(x, m, f,
+                                                    interpret=False)),
                    xs, m, fs)
     assert "tpu_custom_call" in hlo
 
